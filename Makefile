@@ -249,9 +249,9 @@ route-smoke:
 # proposal -> gossip-hop -> quorum -> commit height tree with hops
 # from >= 2 distinct origin nodes, serve /debug/fleet, and append the
 # perfdiff-gated height_latency_p95_4node + localnet_sustained_4node
-# rows to docs/data/perf_ledger.json (CMT_TPU_FLEET_LEDGER=1 targets
-# the real ledger; the bare tier-1 run writes a scratch copy so test
-# runs never dirty the tree).  Tier-1 runs the full
+# rows to the perf ledger (CMT_TPU_FLEET_LEDGER=1 targets the ledger
+# CMT_TPU_PERF_LEDGER names; the bare tier-1 run writes a scratch copy
+# so test runs never dirty the tree).  Tier-1 runs the full
 # tests/test_fleet.py suite too; `make test` gates on this target
 # alongside the other smokes
 fleet-smoke:
@@ -297,15 +297,18 @@ attr-smoke:
 perf-gate:
 	$(PY) tools/perfdiff.py --selftest
 
-# back-fill/refresh docs/data/perf_ledger.json from the historical
-# BENCH_*/MULTICHIP_*/kernel_ab files (bench.py / bench_all.py /
-# device_campaign.py append new points automatically)
+# back-fill/refresh the ledger CMT_TPU_PERF_LEDGER names from the
+# result files bench.py / bench_all.py write at the repo root (they
+# append new points themselves when the variable is set)
 perf-ledger:
 	$(PY) tools/perfledger.py --harvest
 
+# build the in-tree C++ libraries through their loaders, so the
+# artefacts carry the content key utils/native_build.py looks for
 native:
-	g++ -O3 -march=native -funroll-loops -shared -fPIC -std=c++17 \
-		native/bls/bls12381.cpp -o native/build/libcmtbls.so
+	$(PY) -c "from cometbft_tpu.crypto import bls_native, ed25519_native; \
+		print(ed25519_native._LIB.load() and ed25519_native._LIB.out); \
+		print(bls_native._NATIVE.load() and bls_native._NATIVE.out)"
 
 fuzz:
 	python tools/fuzz.py --time $${FUZZ_TIME:-60}

@@ -115,6 +115,28 @@ def decode_bs_message(data: bytes):
     raise ValueError("unknown blocksync message")
 
 
+def commit_prefetch_items(chain_id: str, vals, commit) -> list | None:
+    """The ``(pub_key, sign_bytes, signature)`` triples a replay
+    prefetches for one commit — its COMMIT-flag votes, which is what
+    ``verify_commit_light`` checks — or None when the commit does not
+    line up with ``vals`` (the set rotated: never guess).  Votes
+    covered by a commit-level BLS aggregate carry no per-signature
+    proof to prefetch and are skipped."""
+    if commit is None or commit.size() != len(vals):
+        return None
+    items = []
+    for i, cs in enumerate(commit.signatures):
+        if not cs.is_commit() or commit.is_aggregated(i):
+            continue
+        val = vals.get_by_index(i)
+        if val is None or val.address != cs.validator_address:
+            return None
+        items.append(
+            (val.pub_key, commit.vote_sign_bytes(chain_id, i), cs.signature)
+        )
+    return items
+
+
 class BlocksyncReactor(Reactor):
     """(internal/blocksync/reactor.go:55 Reactor)"""
 
@@ -441,31 +463,10 @@ class BlocksyncReactor(Reactor):
             height = blk.header.height
             if height <= self._prefetched_height:
                 continue
-            commit = nxt.last_commit
-            if commit is None or commit.size() != len(vals):
+            got = commit_prefetch_items(chain_id, vals, nxt.last_commit)
+            if got is None:
                 break  # validator set rotated: stop, never guess
-            mark = len(items)
-            rotated = False
-            for i, cs in enumerate(commit.signatures):
-                if not cs.is_commit():
-                    continue  # verify_commit_light checks commit votes
-                if commit.is_aggregated(i):
-                    # proven by the commit-level BLS aggregate (one
-                    # pairing at verify time) — there is no per-sig
-                    # signature to prefetch
-                    continue
-                val = vals.get_by_index(i)
-                if val is None or val.address != cs.validator_address:
-                    rotated = True
-                    break
-                items.append((
-                    val.pub_key,
-                    commit.vote_sign_bytes(chain_id, i),
-                    cs.signature,
-                ))
-            if rotated:
-                del items[mark:]  # drop this height's partial batch
-                break
+            items.extend(got)
             heights.append(height)
         if items and _vq.submit_prefetch(items):
             # watermark advances ONLY on a successful enqueue: a

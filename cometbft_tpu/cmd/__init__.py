@@ -39,8 +39,9 @@ def cmd_init(args) -> int:
     return 0
 
 
-def cmd_start(args) -> int:
-    """(commands/run_node.go:97 NewRunNodeCmd)"""
+def node_from_args(args):
+    """The Node ``start`` runs: the home's config with the command
+    line's overrides applied (not yet started)."""
     from cometbft_tpu.node import Node
 
     cfg = _load_config(args.home)
@@ -54,7 +55,12 @@ def cmd_start(args) -> int:
         cfg.p2p.persistent_peers = args.persistent_peers
     if args.block_sync is not None:
         cfg.base.block_sync = args.block_sync
-    node = Node(cfg)
+    return Node(cfg)
+
+
+def cmd_start(args) -> int:
+    """(commands/run_node.go:97 NewRunNodeCmd)"""
+    node = node_from_args(args)
     node.start()
     stop = {"done": False}
 

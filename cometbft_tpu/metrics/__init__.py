@@ -675,8 +675,8 @@ class CryptoMetrics:
         self.dispatch_decisions = reg.counter(
             s, "dispatch_decisions",
             "Device-vs-host routing decisions, by route and reason "
-            "(calibration | batch_size | keyed_warm | msg_too_large | "
-            "disabled | device_unavailable).",
+            "(cpu_backend | batch_size | keyed_warm | msg_too_large | "
+            "disabled | ladder_demoted).",
             labels=("route", "reason"),
         )
         self.dispatch_tier = reg.counter(
@@ -886,7 +886,7 @@ class HealthMetrics:
         self.device_hangs_total = reg.counter(
             s, "device_hangs_total",
             "Device launches that exceeded the launch watchdog budget "
-            "(CMT_TPU_LAUNCH_BUDGET_S) — a wedged tunnel becomes this "
+            "(CMT_TPU_LAUNCH_BUDGET_S) — a wedged launch becomes this "
             "counter + a flight-recorder event instead of a silent "
             "stall.",
         )

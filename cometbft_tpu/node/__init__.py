@@ -713,7 +713,18 @@ class Node(BaseService):
                 "scenario labeled — /debug/fleet will carry it",
                 scenario=_scenario,
             )
-        # verify-ahead queue FIRST: the reactors that feed it
+        # device plane FIRST: the JAX backend is initialised in THIS
+        # process, once, before anything can ask for a batch verifier
+        # — platform and device kind are logged and served on
+        # /debug/perf, and a backend that cannot come up fails the
+        # node here instead of quietly verifying on the host
+        if not flag_from_env("CMT_TPU_DISABLE_DEVICE_VERIFY"):
+            from cometbft_tpu.crypto.batch import init_device_plane
+
+            init_device_plane(
+                logger=self.logger.with_fields(module="device")
+            )
+        # verify-ahead queue next: the reactors that feed it
         # (consensus add_vote, blocksync prefetch) start below, and
         # every caller degrades to the synchronous path if this fails
         # — the queue is an accelerator, never a liveness dependency

@@ -26,19 +26,24 @@ from cometbft_tpu.utils.env import flag_from_env
 
 jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: the verify kernel's first compile is
-# ~90s; caching it across processes turns every later startup into a
-# few-second cache load. Opt out with CMT_TPU_NO_COMPILE_CACHE=1.
+# Persistent XLA compilation cache: a cold verify-kernel compile is
+# half a minute to a minute per program; a warm cache makes every later
+# process start in seconds.  ONE place decides the directory: when
+# JAX_COMPILATION_CACHE_DIR is set, JAX already reads it and nothing is
+# set here; otherwise the cache lives at the FIXED path
+# <checkout>/.xla_cache (git-ignored) — the path is part of the cache
+# key, so it must never move between runs.  Opt out with
+# CMT_TPU_NO_COMPILE_CACHE=1.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".xla_cache",
+)
+
+
 if not flag_from_env("CMT_TPU_NO_COMPILE_CACHE"):
-    try:
-        _cache_dir = os.environ.get(
-            "CMT_TPU_COMPILE_CACHE_DIR",  # env ok: free-form filesystem path — no parse to fail
-            os.path.join(
-                os.path.expanduser("~"), ".cache", "cometbft_tpu_xla"
-            ),
-        )
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 — older jax without these knobs
-        pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

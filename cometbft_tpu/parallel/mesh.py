@@ -15,9 +15,9 @@ routes each batch lane to the device owning its key's shard (rebasing
 ids to shard-local slots), and a ``shard_map``-wrapped jit with
 explicit ``in_shardings``/``out_shardings`` and ``donate_argnums`` on
 the packed tuple buffer runs the whole launch with ZERO collectives
-and no per-launch buffer copy.  Where ``shard_map`` is unavailable (or
-the mesh is a single device) the ladder falls back one tier to the
-single-device keyed path — see docs/device_kernel_perf.md §3.95.
+and no per-launch buffer copy.  On a single-device mesh the ladder
+never offers the tier and the single-device keyed path runs — see
+docs/device_kernel_perf.md §3.95.
 """
 
 from __future__ import annotations
@@ -29,11 +29,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # the pjit in/out-shardings + shard_map fallback seam needs it
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax: keyed_mesh tier off
-    _shard_map = None
 
 from cometbft_tpu.crypto import health as _health
 from cometbft_tpu.utils.env import flag_from_env
@@ -193,14 +188,15 @@ def _compiled_keyed_mesh(mesh: Mesh, bucket: int, window_bits: int,
         P(DATA_AXIS),
     )
     out_spec = P(DATA_AXIS)
-    body = _shard_map(
+    body = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     # the virtual-CPU test mesh cannot donate (XLA:CPU keeps the input
     # alive) and would warn per compile; real accelerator meshes reuse
     # the donated buffer's pages instead of copying them per launch
     donate = () if mesh.devices.flat[0].platform == "cpu" else (0,)
+    body.__name__ = f"verify_keyed_mesh_w{window_bits}_b{bucket}"
     fn = jax.jit(
         body,
         in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
@@ -263,7 +259,6 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
         fallback is now a tier the ladder simply never offers."""
         return (
             self._ndev > 1
-            and _shard_map is not None
             and not flag_from_env("CMT_TPU_DISABLE_SHARDED_KEYED")
         )
 

@@ -24,8 +24,7 @@ Design constraints, in order:
 
 Surfaces: the metrics HTTP server serves ``/trace`` next to
 ``/metrics``; the Inspector exposes a ``trace`` JSON-RPC route; and
-bench.py / tools/device_campaign.py dump the same JSON next to their
-results for provenance.
+bench.py dumps the same JSON next to its result for provenance.
 """
 
 from __future__ import annotations

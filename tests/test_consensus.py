@@ -179,6 +179,7 @@ class TestMultiValidator:
             idx, _ = val_set.get_by_address(pv.address)
             stub_idx[pv.address] = (pv, idx)
 
+        voted: set[tuple[int, int]] = set()
         deadline = time.time() + timeout
         while node.height() < n_blocks and time.time() < deadline:
             # stub proposer duties: if the round's proposer is a stub,
@@ -219,16 +220,21 @@ class TestMultiValidator:
                             ),
                             "stub-peer",
                         )
-            # stub voting: once a proposal completes, prevote+precommit it
+            # stub voting: once a proposal completes, prevote+precommit
+            # it.  The event only paces the loop; the round state is
+            # what is read, once per round — the node can complete its
+            # first proposal before this driver has subscribed, and
+            # without the stubs' votes it never leaves that round.
             try:
-                ev = sub_cp.next(timeout=0.05)
+                sub_cp.next(timeout=0.05)
             except TimeoutError:
-                continue
+                pass
             rs = cs.round_state()
-            if rs["proposal"] is None:
-                continue
-            block_id = rs["proposal"].block_id
             h, r = rs["height"], rs["round"]
+            if rs["proposal"] is None or (h, r) in voted:
+                continue
+            voted.add((h, r))
+            block_id = rs["proposal"].block_id
             for pv, idx in stub_idx.values():
                 for vt in (PREVOTE_TYPE, PRECOMMIT_TYPE):
                     vote = Vote(

@@ -981,13 +981,12 @@ class TestChaosLivenessNode:
         )
 
         # the forced-8-device CPU mesh stands in for the accelerator:
-        # init the backend and pin the probe state machine to ready so
-        # the factory hands out the sharded (keyed_mesh-capable)
-        # verifier deterministically
+        # the device plane comes up in-process (the node would do the
+        # same at start) and the factory hands out the sharded
+        # (keyed_mesh-capable) verifier deterministically
         ndev = len(jax.devices())
         assert ndev > 1
-        monkeypatch.setitem(cbatch._device_state, "status", "ready")
-        monkeypatch.setitem(cbatch._device_state, "ndev", ndev)
+        assert cbatch.init_device_plane()["ndev"] == ndev
         monkeypatch.setenv("CMT_TPU_DEVICE_MIN_BATCH", "1")
         pv = FilePV(ed.priv_key_from_secret(b"chaos-liveness-val"))
         # pre-warm the validator key's comb tables: the chaos window

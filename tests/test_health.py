@@ -271,7 +271,7 @@ class TestHealthProber:
 
         def flaky():
             if not state["ok"]:
-                raise RuntimeError("tunnel wedged")
+                raise RuntimeError("device wedged")
             return True
 
         prober = H.HealthProber(interval_s=60, tiers={"keyed": flaky})
@@ -284,7 +284,7 @@ class TestHealthProber:
         assert "crypto/tier_unhealthy" in flight_kinds(mark)
         snap = prober.snapshot()["tiers"]["keyed"]
         assert snap["consecutive_failures"] == 1
-        assert "tunnel wedged" in snap["error"]
+        assert "device wedged" in snap["error"]
         # recovery flips the gauge back and records the transition
         state["ok"] = True
         assert prober.probe_once() == {"keyed": True}
@@ -438,8 +438,9 @@ class TestHealthSmoke:
                 assert perf["utilization"]["launches"] >= 1
                 assert perf["ledger"]["tail"][-1]["config"] == "keyed"
                 assert perf["device"]["status"] in (
-                    "unknown", "probing", "ready", "failed"
+                    "uninitialized", "ready", "failed"
                 )
+                assert {"ndev", "platform", "kind"} <= set(perf["device"])
                 index = json.loads(
                     urllib.request.urlopen(
                         base + "/debug", timeout=5
@@ -570,29 +571,40 @@ class TestPerfLedger:
         assert entries[-1]["config"] == "verify_commit_150"
         assert entries[-1]["value"] == 40.0
 
-    def test_harvest_normalizes_the_real_files(self, tmp_path):
-        """Run the real harvest over the repo's committed BENCH files:
-        every entry has config/value/unit/source, the r04 keyed point
-        and the round-1 headline are both present, and re-harvesting
-        is idempotent."""
+    def test_harvest_normalizes_the_result_files(self, tmp_path):
+        """Harvest the result files the bench tools write (here: a
+        checkout holding a BENCH_ALL.json and a BENCH_MICRO.json):
+        every entry has config/value/unit/source with its provenance
+        carried through, and re-harvesting is idempotent."""
         pl = self._import()
-        entries = pl.harvest(REPO)
-        assert entries, "harvest found nothing"
+        repo = tmp_path / "checkout"
+        repo.mkdir()
+        (repo / "BENCH_ALL.json").write_text(json.dumps({"results": [
+            {"config": "verify_commit_150", "value": 2.5, "unit": "ms",
+             "dispatch_tier": "keyed", "platform": "tpu",
+             "measured": "d1"},
+            {"config": "blocksync_replay_1kval", "value": 90000.0,
+             "unit": "sigs/sec", "dispatch_tier": "keyed",
+             "measured": "d1"},
+        ]}))
+        (repo / "BENCH_MICRO.json").write_text(json.dumps({"results": [
+            {"bench": "wal_write", "ops_per_sec": 1000.0,
+             "ns_per_op": 1e6},
+        ]}))
+        entries = pl.harvest(str(repo))
+        assert len(entries) == 3
         for e in entries:
             assert e["config"] and e["source"]
-        by_cfg = {}
-        for e in entries:
-            by_cfg.setdefault(e["config"], []).append(e)
-        assert any(
-            e["value"] == 103453.0 for e in by_cfg.get("keyed_stack", [])
-        ), "r04 keyed point missing"
-        headline = by_cfg["ed25519_batch_verify_throughput"]
-        assert {e["round"] for e in headline} >= {1, 2}
+        by_cfg = {e["config"]: e for e in entries}
+        assert by_cfg["verify_commit_150"]["dispatch_tier"] == "keyed"
+        assert by_cfg["verify_commit_150"]["source"] == "BENCH_ALL.json"
+        assert by_cfg["wal_write"]["unit"] == "ops/sec"
         path = str(tmp_path / "ledger.json")
         pl.append(entries, path)
         n = len(pl.load(path)["entries"])
-        pl.append(pl.harvest(REPO), path)
+        pl.append(pl.harvest(str(repo)), path)
         assert len(pl.load(path)["entries"]) == n  # idempotent
+        assert pl.harvest(str(tmp_path / "empty")) == []
 
     def test_headline_entry_carries_provenance(self):
         pl = self._import()
@@ -620,6 +632,13 @@ class TestPerfLedger:
             "CMT_TPU_PERF_LEDGER", str(tmp_path / "missing.json")
         )
         assert H.perf_ledger_tail() == []  # absent ledger: empty, no raise
+        # no ledger configured: nothing is read — least of all a file
+        # shipped in the checkout — and the tools say so
+        monkeypatch.delenv("CMT_TPU_PERF_LEDGER")
+        assert H.perf_ledger_path() is None
+        assert H.perf_ledger_tail() == []
+        with pytest.raises(ValueError, match="CMT_TPU_PERF_LEDGER"):
+            self._import().default_path()
 
 
 class TestPerfDiff:

@@ -499,91 +499,99 @@ class TestPrecompute:
 
 
 class TestDispatchThreshold:
-    """Latency-correct device dispatch (VERDICT r3 #4): the crossover
-    accounts for the link RTT so small commits never take a slower
-    path (reference analog: types/validation.go shouldBatchVerify)."""
+    """The device dispatch threshold is a rule, not a calibration: a
+    constant on an accelerator, never on the XLA-on-CPU backend
+    (reference analog: types/validation.go shouldBatchVerify).  The
+    crossover formula, its two constants from another machine and its
+    file under $HOME are gone (PR 22)."""
 
     def _reset(self, monkeypatch):
         from cometbft_tpu.ops import ed25519_verify as EV
 
-        monkeypatch.setattr(EV, "_runtime_threshold", None)
         monkeypatch.delenv("CMT_TPU_DEVICE_MIN_BATCH", raising=False)
         return EV
 
     class _FakeDev:
         platform = "tpu"
+        device_kind = "TPU v5 lite"
 
     def _fake_accel(self, monkeypatch, EV):
         monkeypatch.setattr(
             EV.jax, "devices", lambda *a, **k: [self._FakeDev()]
         )
 
-    def test_calibrated_crossover_tunneled_link(self, tmp_path, monkeypatch):
+    def test_round_trip_is_not_consulted(self, monkeypatch):
+        """A 70 ms round trip used to push the threshold to 1024 and a
+        150-validator commit off the chip for the life of the process,
+        a 0.2 ms one dropped it to the floor; the round trip is
+        start-up evidence now (crypto/batch.init_device_plane), and
+        the threshold never asks for it."""
+        EV = self._reset(monkeypatch)
+        self._fake_accel(monkeypatch, EV)
+
+        def consulted():
+            raise AssertionError("the threshold measured a round trip")
+
+        monkeypatch.setattr(EV, "measure_link_rtt", consulted)
+        assert EV.runtime_device_min_batch() == 128
+        assert EV.DEVICE_MIN_BATCH == 64 < EV.ACCELERATOR_MIN_BATCH == 128
+
+    def test_no_file_under_home_is_read(self, tmp_path, monkeypatch):
+        """A calibration left under $HOME by an older build — on
+        another machine, for another backend — changes nothing."""
         import json as _json
 
         EV = self._reset(monkeypatch)
         self._fake_accel(monkeypatch, EV)
-        cal = tmp_path / "cal.json"
-        cal.write_text(
-            _json.dumps(
-                {
-                    "schema": 2,
-                    "t_cpu_per_sig": 100e-6,
-                    "t_dev_per_sig": 5e-6,
-                }
-            )
+        cal = tmp_path / ".cache" / "cometbft_tpu"
+        cal.mkdir(parents=True)
+        (cal / "device_calibration.json").write_text(
+            _json.dumps({"schema": 2, "t_cpu_per_sig": 100e-6,
+                         "t_dev_per_sig": 5e-6})
         )
-        monkeypatch.setattr(EV, "CALIBRATION_PATH", str(cal))
-        monkeypatch.setattr(EV, "_measure_link_rtt", lambda: 0.070)
-        # n* = 0.07 / 95e-6 ~= 737 -> next pow2 = 1024: a 150-validator
-        # commit stays on the CPU path on a 70 ms link
-        assert EV.runtime_device_min_batch() == 1024
-
-    def test_stale_pre_rlc_calibration_ignored(self, tmp_path, monkeypatch):
-        """A schema-1 calibration (pre native-RLC t_cpu, ~8x too slow)
-        must NOT be honored — it would route mid-size batches to a
-        high-RTT device where the host path now wins. The defaults
-        (t_cpu 15us, t_dev 5us) apply instead: n* = 0.07/10e-6 = 7000
-        -> 8192."""
-        import json as _json
-
-        EV = self._reset(monkeypatch)
-        self._fake_accel(monkeypatch, EV)
-        cal = tmp_path / "cal.json"
-        cal.write_text(
-            _json.dumps({"t_cpu_per_sig": 120e-6, "t_dev_per_sig": 5e-6})
-        )
-        monkeypatch.setattr(EV, "CALIBRATION_PATH", str(cal))
-        monkeypatch.setattr(EV, "_measure_link_rtt", lambda: 0.070)
-        assert EV.runtime_device_min_batch() == 8192
-
-    def test_direct_attached_link_uses_floor(self, tmp_path, monkeypatch):
-        EV = self._reset(monkeypatch)
-        self._fake_accel(monkeypatch, EV)
-        monkeypatch.setattr(EV, "CALIBRATION_PATH", str(tmp_path / "x"))
-        monkeypatch.setattr(EV, "_measure_link_rtt", lambda: 0.0002)
-        assert EV.runtime_device_min_batch() == EV.DEVICE_MIN_BATCH
+        monkeypatch.setenv("HOME", str(tmp_path))
+        monkeypatch.setenv("CMT_TPU_CALIBRATION", str(cal))
+        assert EV.runtime_device_min_batch() == EV.ACCELERATOR_MIN_BATCH
+        assert not hasattr(EV, "CALIBRATION_PATH")
 
     def test_cpu_backend_never_dispatches_to_xla_path(self, monkeypatch):
         """On a cpu jax backend the XLA kernel can't beat the host
         verifier; the threshold must push everything to the CPU path."""
         EV = self._reset(monkeypatch)
-        assert EV.runtime_device_min_batch() >= 1 << 29
+        assert EV.runtime_device_min_batch() == EV.NO_DEVICE_DISPATCH
+        assert EV.NO_DEVICE_DISPATCH >= 1 << 29
 
     def test_env_override_wins(self, monkeypatch):
         EV = self._reset(monkeypatch)
         monkeypatch.setenv("CMT_TPU_DEVICE_MIN_BATCH", "256")
         assert EV.runtime_device_min_batch() == 256
 
-    def test_dead_device_never_dispatches(self, tmp_path, monkeypatch):
+    def test_dead_device_is_loud(self, monkeypatch):
+        """A device that cannot answer the start-up round trip fails
+        the device plane: it is never turned into a quiet
+        everything-on-the-host threshold."""
+        from cometbft_tpu.crypto import batch as cbatch
+
         EV = self._reset(monkeypatch)
-        monkeypatch.setattr(EV, "CALIBRATION_PATH", str(tmp_path / "x"))
+        self._fake_accel(monkeypatch, EV)
+        monkeypatch.setattr(
+            cbatch, "_device_state",
+            {"status": "uninitialized", "ndev": 0, "platform": None,
+             "kind": None},
+        )
 
         def boom():
             raise RuntimeError("no backend")
 
-        monkeypatch.setattr(EV, "_measure_link_rtt", boom)
-        assert EV.runtime_device_min_batch() >= 1 << 29
+        monkeypatch.setattr(EV, "measure_link_rtt", boom)
+        with pytest.raises(RuntimeError, match="no backend"):
+            cbatch.init_device_plane()
+        assert cbatch.device_status()["status"] == "failed"
+        # a device that answers comes up with both facts on record
+        monkeypatch.setattr(EV, "measure_link_rtt", lambda: 0.001)
+        state = cbatch.init_device_plane()
+        assert state["link_rtt_s"] == 0.001
+        assert state["device_min_batch"] == EV.ACCELERATOR_MIN_BATCH
 
 
 def test_verify_stream_keyed_dispatch(rng):
@@ -637,8 +645,8 @@ def test_verify_stream_keyed_dispatch(rng):
 @pytest.mark.parametrize("impl", ["stack16", "pallas"])
 def test_keyed_kernel_under_alternate_field_cores(impl, monkeypatch):
     """The keyed (precomputed-table) kernel is correct under every
-    column-formation variant the device A/B campaign measures
-    (tools/device_campaign.py) — a device window must never be spent
+    column-formation variant a device A/B would measure
+    (tools/bench_kernel_ab.py) — chip time must never be spent
     discovering a correctness bug.  pallas runs in interpret mode,
     which re-executes every field op per trace (~10 min for the full
     keyed graph), so that variant runs in the slow lane

@@ -196,8 +196,15 @@ class TestEvidencePool:
         nodes, privs, gen = make_localnet(tmp_path, 4)
         for n in nodes:
             n.start()
-        connect_star(nodes)
-        wait_all_height(nodes, 2)
+        try:
+            connect_star(nodes)
+            wait_all_height(nodes, 2)
+        except BaseException:
+            # the caller never gets the nodes to stop: four nodes left
+            # running starve every later test on this worker
+            for n in nodes:
+                n.stop()
+            raise
         if halt:
             # freeze the chain so the pool can be driven deterministically:
             # with consensus running, node0's own proposer scoops pending

@@ -20,12 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     import jax
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    jax.config.update(
-        "jax_compilation_cache_dir", os.path.join(repo, ".xla_cache")
-    )
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    # the compile cache is the one cometbft_tpu/ops/__init__.py sets
 
     from cometbft_tpu.crypto import ed25519 as ed
     from cometbft_tpu.ops import precompute as PR
@@ -90,8 +85,7 @@ def main():
         f"{nsigs / best:,.0f} sigs/s device-side ({best * 1e3:.1f} ms/launch)",
         flush=True,
     )
-    # provenance line device_campaign.py scrapes into the step entry:
-    # the warmup compile count per seam (steady trials above should
+    # provenance line: the warmup compile count per seam (steady trials above should
     # have added none — docs/device_contracts.md)
     import json
 

@@ -78,9 +78,6 @@ def main() -> int:
     steps = [int(s) for s in args.steps.split(",")]
 
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
-    from cometbft_tpu.utils.device_env import scrub_plugin_env
-
-    scrub_plugin_env(env)
     server = subprocess.Popen(
         [sys.executable, "-c", SERVER_SNIPPET.format(repo=REPO)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,

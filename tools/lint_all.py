@@ -54,6 +54,8 @@ def _record_wall(wall: float) -> None:
     try:
         from tools import perfledger
 
+        if not perfledger.configured():
+            return
         perfledger.append([
             perfledger.make_entry(
                 "lint_wall_seconds", round(wall, 3), "seconds",

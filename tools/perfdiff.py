@@ -18,7 +18,7 @@ noise-aware relative threshold:
   measured regressions that mattered were 3-5x, not 1.1x;
 - values <= 0 on either side are skipped (a 0 means "the device was
   down", which the availability entries record separately — gating on
-  it would page on every tunnel outage instead of every code change).
+  it would page on every device outage instead of every code change).
 
 Exit status: 0 clean, 1 when any compared config regressed past the
 threshold, 2 on usage errors.  ``--selftest`` (what ``make perf-gate``

@@ -16,10 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-jax.config.update("jax_compilation_cache_dir", os.path.join(repo, ".xla_cache"))
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+# the compile cache is the one cometbft_tpu/ops/__init__.py sets
 mark(f"jax imported; devices: {jax.devices()}")
 
 import numpy as np  # noqa: E402

@@ -84,9 +84,6 @@ def _node_env() -> dict:
         JAX_PLATFORMS="cpu",
         CMT_TPU_DISABLE_DEVICE_VERIFY="1",
     )
-    from cometbft_tpu.utils.device_env import scrub_plugin_env
-
-    scrub_plugin_env(env)
     return env
 
 
