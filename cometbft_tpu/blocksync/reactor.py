@@ -26,6 +26,7 @@ from cometbft_tpu.utils import trustguard
 from cometbft_tpu.utils.flight import FLIGHT
 from cometbft_tpu.utils.log import Logger, default_logger
 from cometbft_tpu.utils.protoio import ProtoReader, ProtoWriter
+from cometbft_tpu.utils.trace import TRACER
 from cometbft_tpu.types.codec import as_bytes as _bz, as_int as _iv
 
 BLOCKSYNC_CHANNEL = 0x40
@@ -124,17 +125,21 @@ def commit_prefetch_items(chain_id: str, vals, commit) -> list | None:
     proof to prefetch and are skipped."""
     if commit is None or commit.size() != len(vals):
         return None
-    items = []
-    for i, cs in enumerate(commit.signatures):
-        if not cs.is_commit() or commit.is_aggregated(i):
-            continue
-        val = vals.get_by_index(i)
-        if val is None or val.address != cs.validator_address:
-            return None
-        items.append(
-            (val.pub_key, commit.vote_sign_bytes(chain_id, i), cs.signature)
-        )
-    return items
+    with TRACER.span(
+        "blocksync/prefetch_items", cat="blocksync", height=commit.height,
+    ):
+        items = []
+        for i, cs in enumerate(commit.signatures):
+            if not cs.is_commit() or commit.is_aggregated(i):
+                continue
+            val = vals.get_by_index(i)
+            if val is None or val.address != cs.validator_address:
+                return None
+            items.append((
+                val.pub_key, commit.vote_sign_bytes(chain_id, i),
+                cs.signature,
+            ))
+        return items
 
 
 class BlocksyncReactor(Reactor):

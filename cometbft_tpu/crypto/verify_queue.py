@@ -87,7 +87,10 @@ node's own replay, but they outrank admission.
 Observability: ``crypto_verify_queue_*`` metrics (CryptoMetrics),
 ``verify_queue/prepare`` + ``verify_queue/launch`` spans (the overlap
 is visible as prepare-of-N+1 nesting inside launch-of-N wall time —
-docs/observability.md "reading an overlap trace"), and the launcher
+docs/observability.md "reading an overlap trace") with their stages
+inside (``verify_queue/submit`` on the caller, ``/prehash`` in prepare,
+``/resolve`` in launch, and the two handoffs ``/pending_wait`` and
+``/prepared_wait`` recorded at the pops), and the launcher
 feeds ``crypto_host_device_overlap_ratio`` with the share of each
 launch wall covered by concurrent host prep.
 """
@@ -301,10 +304,12 @@ class _Request:
         self.sig = sig
         self.future = VerifyFuture()
         self.key: bytes | None = None  # prehash, set by the collector
-        #: arrival time (monotonic) — the ingest micro-batcher's
-        #: accumulation deadline is measured from the OLDEST pending
-        #: request, so a half-full batch never waits past the bound
-        self.t = time.monotonic()
+        #: arrival time (perf_counter, the span ring's clock), stamped
+        #: by submit_many when the request is enqueued — the ingest
+        #: micro-batcher's accumulation deadline is measured from the
+        #: OLDEST pending request, so a half-full batch never waits
+        #: past the bound; verify_queue/pending_wait starts here
+        self.t = 0.0
 
 
 class _LaneBatcher:
@@ -332,7 +337,7 @@ class _LaneBatcher:
             return False
         if draining or len(lane) >= self.batch_target:
             return True
-        now = time.monotonic() if now is None else now
+        now = time.perf_counter() if now is None else now
         return now - lane[0].t >= self.wait_s
 
     def deadline_wait(
@@ -344,7 +349,7 @@ class _LaneBatcher:
         so the wait bounds stay real."""
         if not lane:
             return None
-        now = time.monotonic() if now is None else now
+        now = time.perf_counter() if now is None else now
         return max(0.001, self.wait_s - (now - lane[0].t))
 
 
@@ -352,7 +357,7 @@ class _Prepared:
     """One prepared buffer: requests grouped per key type with their
     host-phase artifacts, ready for the launcher."""
 
-    __slots__ = ("priority", "reqs", "groups", "prep_seconds")
+    __slots__ = ("priority", "reqs", "groups", "prep_seconds", "t_ready")
 
     def __init__(self, priority: str) -> None:
         self.priority = priority
@@ -361,6 +366,9 @@ class _Prepared:
         #: means per-signature host verification in the launcher
         self.groups: list[tuple] = []
         self.prep_seconds = 0.0
+        #: perf_counter when the collector parked this buffer;
+        #: verify_queue/prepared_wait runs from here to the launcher's pop
+        self.t_ready = 0.0
 
 
 @cmtsync.guarded
@@ -521,18 +529,27 @@ class VerifyQueue(BaseService):
         per item.  Raises QueueUnavailable when stopped/draining."""
         if priority not in _PRIORITIES:
             raise ValueError(f"unknown priority {priority!r}")
-        reqs = [_Request(pk, bytes(m), bytes(s)) for pk, m, s in items]
-        with self._qmtx:
-            if self._draining or not self.is_running():
-                raise QueueUnavailable("verify queue is not accepting")
-            self._pending[priority].extend(reqs)
-            self._stats["submitted"][priority] += len(reqs)
-            depth = len(self._pending[priority])
-        cm = _crypto_metrics()
-        cm.verify_queue_submitted.labels(priority=priority).inc(len(reqs))
-        cm.verify_queue_depth.labels(priority=priority).set(depth)
-        self._collector_wake.set()
-        return [r.future for r in reqs]
+        with _tracer.span(
+            "verify_queue/submit", cat="crypto", priority=priority,
+        ) as sp:
+            reqs = [_Request(pk, bytes(m), bytes(s)) for pk, m, s in items]
+            sp.set(batch=len(reqs))
+            arrived = time.perf_counter()
+            for r in reqs:
+                r.t = arrived
+            with self._qmtx:
+                if self._draining or not self.is_running():
+                    raise QueueUnavailable("verify queue is not accepting")
+                self._pending[priority].extend(reqs)
+                self._stats["submitted"][priority] += len(reqs)
+                depth = len(self._pending[priority])
+            cm = _crypto_metrics()
+            cm.verify_queue_submitted.labels(priority=priority).inc(
+                len(reqs)
+            )
+            cm.verify_queue_depth.labels(priority=priority).set(depth)
+            self._collector_wake.set()
+            return [r.future for r in reqs]
 
     def submit(self, pub_key, msg, sig,
                priority: str = PRIORITY_CONSENSUS) -> VerifyFuture:
@@ -583,7 +600,7 @@ class VerifyQueue(BaseService):
         accumulation deadline across the batched lanes expires (holds
         no lock — called from the collector's idle loop only)."""
         wait = 0.05
-        now = time.monotonic()
+        now = time.perf_counter()
         with self._qmtx:
             for p, gate in self._batchers.items():
                 remaining = gate.deadline_wait(self._pending[p], now)
@@ -639,6 +656,12 @@ class VerifyQueue(BaseService):
                 self._collector_wake.wait(self._batcher_deadline_wait())
                 self._collector_wake.clear()
                 continue
+            # handoff: the oldest request's arrival to this pop
+            _tracer.add_complete(
+                "verify_queue/pending_wait", reqs[0].t,
+                time.perf_counter() - reqs[0].t, cat="crypto",
+                args={"priority": priority, "batch": len(reqs)},
+            )
             try:
                 try:
                     prep = self._prepare(reqs, priority)
@@ -651,6 +674,7 @@ class VerifyQueue(BaseService):
                     continue
                 if not prep.reqs:
                     continue  # every request was a cache hit
+                prep.t_ready = time.perf_counter()
                 with self._qmtx:
                     self._prepared[priority].append(prep)
                     self._stats["prepared_batches"] += 1
@@ -683,23 +707,24 @@ class VerifyQueue(BaseService):
                 priority=priority,
             ) as prep_span:
                 work: list[_Request] = []
-                for r in reqs:
-                    r.key = cache_key(r.pub_key.bytes(), r.msg, r.sig)
-                    cached = (
-                        self.cache.lookup(r.key)
-                        if self.cache is not None else None
-                    )
-                    if cached is not None:
-                        cm.verify_queue_spec_cache.labels(
-                            result="hit"
-                        ).inc()
-                        r.future._resolve(cached)
-                        continue
-                    if self.cache is not None:
-                        cm.verify_queue_spec_cache.labels(
-                            result="miss"
-                        ).inc()
-                    work.append(r)
+                with _tracer.span("verify_queue/prehash", cat="crypto"):
+                    for r in reqs:
+                        r.key = cache_key(r.pub_key.bytes(), r.msg, r.sig)
+                        cached = (
+                            self.cache.lookup(r.key)
+                            if self.cache is not None else None
+                        )
+                        if cached is not None:
+                            cm.verify_queue_spec_cache.labels(
+                                result="hit"
+                            ).inc()
+                            r.future._resolve(cached)
+                            continue
+                        if self.cache is not None:
+                            cm.verify_queue_spec_cache.labels(
+                                result="miss"
+                            ).inc()
+                        work.append(r)
                 if work:
                     with self._qmtx:
                         self._stats["cache_resolved"] += (
@@ -815,6 +840,12 @@ class VerifyQueue(BaseService):
                 self._launcher_wake.wait(0.05)
                 self._launcher_wake.clear()
                 continue
+            # handoff: the collector's append to this pop
+            _tracer.add_complete(
+                "verify_queue/prepared_wait", prep.t_ready,
+                time.perf_counter() - prep.t_ready, cat="crypto",
+                args={"priority": prep.priority},
+            )
             self._collector_wake.set()  # slot freed: prep buffer N+1
             self._execute(prep)
 
@@ -926,11 +957,12 @@ class VerifyQueue(BaseService):
             for r in reqs:
                 r.future._fail(exc)
             return
-        for r, bit in zip(reqs, results):
-            bit = bool(bit)
-            if self.cache is not None and r.key is not None:
-                self.cache.store(r.key, bit)
-            r.future._resolve(bit)
+        with _tracer.span("verify_queue/resolve", cat="crypto"):
+            for r, bit in zip(reqs, results):
+                bit = bool(bit)
+                if self.cache is not None and r.key is not None:
+                    self.cache.store(r.key, bit)
+                r.future._resolve(bit)
 
     # -- introspection ---------------------------------------------------
 

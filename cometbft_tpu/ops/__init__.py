@@ -23,8 +23,15 @@ import os
 import jax
 
 from cometbft_tpu.utils.env import flag_from_env
+from cometbft_tpu.utils.trace import TRACER
 
 jax.config.update("jax_enable_x64", True)
+
+# One clock: every TRACER span open during a jax.profiler session also
+# stands in the trace's host plane under its own name, beside the
+# device programs (outside a session the annotation is a no-op in the
+# runtime).
+TRACER.set_annotator(jax.profiler.TraceAnnotation)
 
 # Persistent XLA compilation cache: a cold verify-kernel compile is
 # half a minute to a minute per program; a warm cache makes every later

@@ -308,7 +308,8 @@ def pack_inputs(
 
 
 def _dispatch(pub, sig, msgs, start, end):
-    packed, bucket = pack_inputs(pub, sig, msgs, start, end)
+    with _tracer.span("verify/pack", cat="device", batch=end - start):
+        packed, bucket = pack_inputs(pub, sig, msgs, start, end)
     fn = _compiled(packed.shape[-1], bucket)
     cm = _crypto_metrics()
     cm.batch_verify_launches.labels(kernel="generic").inc()
@@ -364,11 +365,12 @@ def verify_arrays_keyed_async(entry, key_ids, pub, sig, msgs):
     every key id in ``key_ids``.  Same contract as
     verify_arrays_async."""
     n = len(msgs)
-    packed, bucket = pack_inputs(pub, sig, msgs, key_ids=key_ids)
-    batch = packed.shape[-1]
-    if batch > MAX_LAUNCH and batch % MAX_LAUNCH:
-        pad = MAX_LAUNCH - batch % MAX_LAUNCH
-        packed = np.pad(packed, [(0, 0), (0, pad)])
+    with _tracer.span("verify/pack", cat="device", batch=n):
+        packed, bucket = pack_inputs(pub, sig, msgs, key_ids=key_ids)
+        batch = packed.shape[-1]
+        if batch > MAX_LAUNCH and batch % MAX_LAUNCH:
+            pad = MAX_LAUNCH - batch % MAX_LAUNCH
+            packed = np.pad(packed, [(0, 0), (0, pad)])
     fn = _compiled_keyed(bucket, entry.window_bits, MAX_LAUNCH)
     cm = _crypto_metrics()
     cm.batch_verify_launches.labels(kernel="keyed").inc()
@@ -413,12 +415,13 @@ def verify_arrays_async(pub: np.ndarray, sig: np.ndarray, msgs: list[bytes]):
         )
         homogeneous = bucket_all is not None and bucket_all == smallest
     if homogeneous:
-        packed, bucket = pack_inputs(pub, sig, msgs)
-        batch = packed.shape[-1]
-        if batch % MAX_LAUNCH:  # pad columns to a whole chunk count
-            pad = MAX_LAUNCH - batch % MAX_LAUNCH
-            packed = np.pad(packed, [(0, 0), (0, pad)])
-            batch += pad
+        with _tracer.span("verify/pack", cat="device", batch=n):
+            packed, bucket = pack_inputs(pub, sig, msgs)
+            batch = packed.shape[-1]
+            if batch % MAX_LAUNCH:  # pad columns to a whole chunk count
+                pad = MAX_LAUNCH - batch % MAX_LAUNCH
+                packed = np.pad(packed, [(0, 0), (0, pad)])
+                batch += pad
         fn = _compiled_chunked(batch, bucket, MAX_LAUNCH)
         cm = _crypto_metrics()
         cm.batch_verify_launches.labels(kernel="generic").inc()
@@ -447,14 +450,20 @@ def _finish(parts) -> np.ndarray:
     if len(parts) == 1:
         p, k = parts[0]
         # timed_fetch: the blocking-fetch seconds feed the host/device
-        # overlap ratio (crypto/health.py DeviceUsage)
-        with _health.USAGE.timed_fetch():
+        # overlap ratio (crypto/health.py DeviceUsage); the device_fetch
+        # span is the same wait in a profiler session's host plane,
+        # beside the program it waits for
+        with _tracer.span(
+            "device_fetch", cat="device", batch=k,
+        ), _health.USAGE.timed_fetch():
             out = jax.device_get(p)  # host sync: the one audited per-batch result fetch
         _crypto_metrics().bytes_transferred.labels(
             direction="d2h"
         ).inc(out.nbytes)
         return out[:k]
-    with _health.USAGE.timed_fetch():
+    with _tracer.span(
+        "device_fetch", cat="device", batch=sum(k for _, k in parts),
+    ), _health.USAGE.timed_fetch():
         combined = jax.device_get(  # host sync: single combined fetch for all parts
             jnp.concatenate([p for p, _ in parts])
         )
@@ -656,6 +665,14 @@ class TpuBatchVerifier(BatchVerifier):
         everything that happens BEFORE the device launch.  Safe to run
         on the verify queue's collector thread while another batch's
         :meth:`execute` launch is in flight."""
+        with _tracer.span(
+            "verify/plan", cat="crypto", batch=len(self._pubs),
+        ) as sp:
+            plan = self._plan()
+            sp.set(route=plan.route)
+            return plan
+
+    def _plan(self) -> _VerifyPlan:
         plan = _VerifyPlan()
         plan.t_plan = time.perf_counter()
         n = plan.n = len(self._pubs)

@@ -315,20 +315,30 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
             pack_inputs,
         )
 
-        packed, bucket = pack_inputs(pub, sig, msgs)
+        n = len(msgs)
         # per-device slices must respect the same >MAX_LAUNCH working-
         # set cliff the single-device paths chunk for
         chunk = MAX_LAUNCH * self._ndev
-        packed = self._pad_cols(packed, chunk=chunk)
+        with _tracer.span("verify/pack", cat="device", batch=n):
+            packed, bucket = pack_inputs(pub, sig, msgs)
+            packed = self._pad_cols(packed, chunk=chunk)
         batch = packed.shape[-1]
         if batch > chunk:
             fn = _compiled_chunked(batch, bucket, chunk)
         else:
             fn = _compiled(batch, bucket)
-        out = fn(jax.device_put(packed, self._sharding(None, DATA_AXIS)))
-        with _health.USAGE.timed_fetch():
+        with _tracer.span(
+            "device_launch", cat="device", kernel="generic_mesh",
+            batch=batch, bucket=bucket, ndev=self._ndev,
+        ):
+            out = fn(
+                jax.device_put(packed, self._sharding(None, DATA_AXIS))
+            )
+        with _tracer.span(
+            "device_fetch", cat="device", batch=n,
+        ), _health.USAGE.timed_fetch():
             res = jax.device_get(out)  # host sync: single per-batch result gather off the mesh
-        return res[: len(msgs)]
+        return res[:n]
 
     def _run_keyed_mesh(self, entry, key_ids, pub, sig, msgs) -> np.ndarray:
         from cometbft_tpu.ops.ed25519_verify import (
@@ -353,33 +363,34 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
         # (pow2 of the fullest shard, padded lanes are discarded on
         # unscatter) so the sharded batch stays rectangular
         n = len(msgs)
-        owner = key_ids % ndev
-        local_ids = (key_ids // ndev).astype(np.int32)
-        counts = np.bincount(owner, minlength=ndev)
-        w = _next_pow2(int(counts.max()))
-        chunk = MAX_LAUNCH
-        if w > chunk and w % chunk:
-            w += chunk - w % chunk
-        order = np.argsort(owner, kind="stable")
-        offs = np.concatenate([[0], np.cumsum(counts)])[:-1]
-        dest = np.empty(n, dtype=np.int64)
-        dest[order] = owner[order] * w + (
-            np.arange(n) - offs[owner[order]]
-        )
-        batch = ndev * w
-        pub_r = np.zeros((batch, 32), dtype=np.uint8)
-        sig_r = np.zeros((batch, 64), dtype=np.uint8)
-        ids_r = np.zeros(batch, dtype=np.int32)
-        msgs_r = [b""] * batch
-        pub_r[dest] = pub
-        sig_r[dest] = sig
-        ids_r[dest] = local_ids
-        for i, d in enumerate(dest):
-            msgs_r[d] = msgs[i]
-        packed, bucket = pack_inputs(pub_r, sig_r, msgs_r, key_ids=ids_r)
-        # pack_inputs pow2-pads past ndev*w on non-pow2 meshes; the
-        # shard boundaries live at multiples of w, so slice back
-        packed = packed[:, :batch]
+        with _tracer.span("verify/pack", cat="device", batch=n):
+            owner = key_ids % ndev
+            local_ids = (key_ids // ndev).astype(np.int32)
+            counts = np.bincount(owner, minlength=ndev)
+            w = _next_pow2(int(counts.max()))
+            chunk = MAX_LAUNCH
+            if w > chunk and w % chunk:
+                w += chunk - w % chunk
+            order = np.argsort(owner, kind="stable")
+            offs = np.concatenate([[0], np.cumsum(counts)])[:-1]
+            dest = np.empty(n, dtype=np.int64)
+            dest[order] = owner[order] * w + (
+                np.arange(n) - offs[owner[order]]
+            )
+            batch = ndev * w
+            pub_r = np.zeros((batch, 32), dtype=np.uint8)
+            sig_r = np.zeros((batch, 64), dtype=np.uint8)
+            ids_r = np.zeros(batch, dtype=np.int32)
+            msgs_r = [b""] * batch
+            pub_r[dest] = pub
+            sig_r[dest] = sig
+            ids_r[dest] = local_ids
+            for i, d in enumerate(dest):
+                msgs_r[d] = msgs[i]
+            packed, bucket = pack_inputs(pub_r, sig_r, msgs_r, key_ids=ids_r)
+            # pack_inputs pow2-pads past ndev*w on non-pow2 meshes; the
+            # shard boundaries live at multiples of w, so slice back
+            packed = packed[:, :batch]
         fn = _compiled_keyed_mesh(
             self._mesh, bucket, entry.window_bits, chunk
         )
@@ -396,7 +407,9 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
                 table,
                 valid,
             )
-        with _health.USAGE.timed_fetch():
+        with _tracer.span(
+            "device_fetch", cat="device", batch=n,
+        ), _health.USAGE.timed_fetch():
             res = jax.device_get(out)  # host sync: single per-batch result gather off the mesh
         cm.bytes_transferred.labels(direction="d2h").inc(res.nbytes)
         return res[dest]  # unscatter to original lane order

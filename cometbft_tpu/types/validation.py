@@ -96,6 +96,7 @@ def _verify(
     count_sig,
     count_all: bool,
     lookup_by_address: bool,
+    mode: str,
     signer_vals: ValidatorSet | None = None,
 ) -> None:
     """Shared engine for the three verification modes
@@ -104,7 +105,8 @@ def _verify(
     count_sig(cs) decides which signatures are cryptographically checked;
     tallied power only ever counts BlockIDFlagCommit votes. count_all
     keeps verifying past the threshold (VerifyCommit) or stops early
-    (the Light variants).
+    (the Light variants).  ``mode`` (full/light/trusting) names the
+    caller on the ``verify_commit`` span, which covers all of this.
 
     When the commit carries ``agg_signature`` (types/block.py), the
     covered COMMIT-flag votes are proven by ONE BLS pairing-product
@@ -116,6 +118,34 @@ def _verify(
     pubkeys; signature validity comes from the aggregate, tallied
     power still counts only validators matched in ``vals``.
     """
+    with _tracer.span(
+        "verify_commit", cat="crypto", height=commit.height, mode=mode,
+    ) as root:
+        with _tracer.span("verify_commit/collect", cat="crypto"):
+            entries, agg_pubs = _collect_entries(
+                vals, commit, voting_power_needed, count_sig, count_all,
+                lookup_by_address, signer_vals,
+            )
+            groups = _batch_groups(entries, vals)
+        _crypto_pass(chain_id, vals, commit, entries, agg_pubs, groups, root)
+        tallied = sum(e.power for e in entries if e.counts)
+        if tallied <= voting_power_needed:
+            raise NotEnoughVotingPower(
+                f"tallied {tallied} <= needed {voting_power_needed}"
+            )
+
+
+def _collect_entries(
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int,
+    count_sig,
+    count_all: bool,
+    lookup_by_address: bool,
+    signer_vals: ValidatorSet | None,
+) -> tuple[list[_Entry], list]:
+    """The signatures ``_verify`` will check, in commit order, and
+    every signer of the commit-level aggregate equation."""
     if not lookup_by_address and len(vals) != commit.size():
         raise InvalidCommitSignatures(
             f"validator set size {len(vals)} != commit size {commit.size()}"
@@ -124,7 +154,6 @@ def _verify(
     has_agg = bool(commit.agg_signature)
     entries: list[_Entry] = []
     agg_pubs: list = []  # every signer in the aggregate equation
-    tallied = 0
     counted_power = 0
     seen_addrs: set[bytes] = set()
     for idx, cs in enumerate(commit.signatures):
@@ -185,7 +214,17 @@ def _verify(
             and counted_power > voting_power_needed
         ):
             break
+    return entries, agg_pubs
 
+
+def _crypto_pass(
+    chain_id: str, vals: ValidatorSet, commit: Commit,
+    entries: list[_Entry], agg_pubs: list, groups: list[list[_Entry]],
+    root,
+) -> None:
+    """Every signature of ``entries`` checked, or
+    InvalidCommitSignatures; ``root`` is the ``verify_commit`` span."""
+    has_agg = bool(commit.agg_signature)
     # crypto pass — one batch launch per key type in the commit; with
     # multiple key types the groups run CONCURRENTLY (the TPU kernel
     # waits on device compute and the native BLS library releases the
@@ -251,7 +290,10 @@ def _verify(
 
     def _verify_group(group) -> None:
         pks = [vals.get_by_index(e.val_idx).pub_key for e in group]
-        sbs = [commit.vote_sign_bytes(chain_id, e.idx) for e in group]
+        with _tracer.span(
+            "verify_commit/sign_bytes", cat="crypto", sigs=len(group),
+        ):
+            sbs = [commit.vote_sign_bytes(chain_id, e.idx) for e in group]
         pending = list(range(len(group)))
         keys: list[bytes] | None = None
         if _vq.speculation_active():
@@ -262,24 +304,27 @@ def _verify(
             # prehash is computed ONCE per signature and reused by the
             # record_result below — on a cold 10k-sig commit the
             # consult-then-record shape would otherwise hash twice.
-            keys = [
-                _vq.cache_key(
-                    pks[i].bytes(), sbs[i],
-                    commit.signatures[e.idx].signature,
-                )
-                for i, e in enumerate(group)
-            ]
-            pending = []
-            hits = 0
-            for i, e in enumerate(group):
-                if _vq.cached_result(
-                    pks[i].bytes(), sbs[i],
-                    commit.signatures[e.idx].signature,
-                    key=keys[i],
-                ) is True:
-                    hits += 1
-                else:
-                    pending.append(i)
+            with _tracer.span(
+                "verify_commit/spec_lookup", cat="crypto", sigs=len(group),
+            ):
+                keys = [
+                    _vq.cache_key(
+                        pks[i].bytes(), sbs[i],
+                        commit.signatures[e.idx].signature,
+                    )
+                    for i, e in enumerate(group)
+                ]
+                pending = []
+                hits = 0
+                for i, e in enumerate(group):
+                    if _vq.cached_result(
+                        pks[i].bytes(), sbs[i],
+                        commit.signatures[e.idx].signature,
+                        key=keys[i],
+                    ) is True:
+                        hits += 1
+                    else:
+                        pending.append(i)
             with spec_mtx:
                 spec["hits"] += hits
                 spec["misses"] += len(pending)
@@ -329,13 +374,17 @@ def _verify(
             if _vq.speculation_active():
                 # repeat verifications of this commit (evidence
                 # re-checks, light-client retries) become cache hits
-                for i, r in zip(pending, results):
-                    _vq.record_result(
-                        pks[i].bytes(), sbs[i],
-                        commit.signatures[group[i].idx].signature,
-                        bool(r),
-                        key=keys[i] if keys is not None else None,
-                    )
+                with _tracer.span(
+                    "verify_commit/record", cat="crypto",
+                    sigs=len(pending),
+                ):
+                    for i, r in zip(pending, results):
+                        _vq.record_result(
+                            pks[i].bytes(), sbs[i],
+                            commit.signatures[group[i].idx].signature,
+                            bool(r),
+                            key=keys[i] if keys is not None else None,
+                        )
             if not ok:
                 bad = next(j for j, r in enumerate(results) if not r)
                 raise InvalidCommitSignatures(
@@ -370,7 +419,6 @@ def _verify(
                         f"wrong signature (#{group[i].idx})"
                     )
 
-    groups = _batch_groups(entries, vals)
     # one task per key-type group + (when the commit carries it) the
     # aggregate check — with several, they run CONCURRENTLY: the TPU
     # kernel waits on device compute and the native BLS library
@@ -383,52 +431,42 @@ def _verify(
         raise InvalidCommitSignatures(
             "aggregate signature with no aggregated signatures"
         )
-    with _tracer.span(
-        "verify_commit", cat="crypto",
-        height=commit.height,
+    root.set(
         sigs=len(entries) + max(0, len(agg_pubs) - sum(
             1 for e in entries if e.aggregated
         )),
         groups=len(tasks),
-    ) as sp:
-        speculating = _vq.speculation_active()
-        try:
-            if len(tasks) <= 1:
-                for task in tasks:
-                    task()
-            else:
-                import concurrent.futures as _futures
+    )
+    speculating = _vq.speculation_active()
+    try:
+        if len(tasks) <= 1:
+            for task in tasks:
+                task()
+        else:
+            import concurrent.futures as _futures
 
-                with _futures.ThreadPoolExecutor(len(tasks)) as pool:
-                    futs = [pool.submit(t) for t in tasks]
-                    for f in futs:
-                        f.result()  # re-raises InvalidCommitSignatures
-        finally:
-            if speculating:
-                # tier tells the flight tail whether a slow commit came
-                # from a cold queue (misses ran on a real tier) or a
-                # warm one (all hits -> "speculative", no launch)
-                tier = (
-                    "speculative" if spec["misses"] == 0
-                    else (spec["tier"] or "host")
-                )
-                sp.set(
-                    spec_hits=spec["hits"], spec_misses=spec["misses"],
-                    tier=tier,
-                )
-                FLIGHT.record(
-                    "consensus/speculative_verify",
-                    height=commit.height, sigs=len(entries),
-                    hits=spec["hits"], misses=spec["misses"], tier=tier,
-                )
-
-    for e in entries:
-        if e.counts:
-            tallied += e.power
-    if tallied <= voting_power_needed:
-        raise NotEnoughVotingPower(
-            f"tallied {tallied} <= needed {voting_power_needed}"
-        )
+            with _futures.ThreadPoolExecutor(len(tasks)) as pool:
+                futs = [pool.submit(t) for t in tasks]
+                for f in futs:
+                    f.result()  # re-raises InvalidCommitSignatures
+    finally:
+        if speculating:
+            # tier tells the flight tail whether a slow commit came
+            # from a cold queue (misses ran on a real tier) or a
+            # warm one (all hits -> "speculative", no launch)
+            tier = (
+                "speculative" if spec["misses"] == 0
+                else (spec["tier"] or "host")
+            )
+            root.set(
+                spec_hits=spec["hits"], spec_misses=spec["misses"],
+                tier=tier,
+            )
+            FLIGHT.record(
+                "consensus/speculative_verify",
+                height=commit.height, sigs=len(entries),
+                hits=spec["hits"], misses=spec["misses"], tier=tier,
+            )
 
 
 def verify_commit(
@@ -450,6 +488,7 @@ def verify_commit(
         count_sig=lambda cs: not cs.is_absent(),
         count_all=True,
         lookup_by_address=False,
+        mode="full",
     )
     trustguard.note_validated("verify_commit")
 
@@ -478,6 +517,7 @@ def verify_commit_light(
         count_sig=lambda cs: cs.is_commit(),
         count_all=count_all,
         lookup_by_address=False,
+        mode="light",
     )
     trustguard.note_validated("verify_commit_light")
 
@@ -515,6 +555,7 @@ def verify_commit_light_trusting(
         count_sig=lambda cs: cs.is_commit(),
         count_all=count_all,
         lookup_by_address=True,
+        mode="trusting",
         signer_vals=signer_vals,
     )
     trustguard.note_validated("verify_commit_light_trusting")

@@ -21,6 +21,12 @@ Design constraints, in order:
   most recent window, never an unbounded log.
 - **No dependencies**: stdlib only; importable from every plane
   (crypto, ops, consensus, tools) without dragging jax in.
+- **One clock with the device trace**: ``set_annotator`` takes a
+  ``name -> context manager`` callable (``cometbft_tpu/ops`` installs
+  ``jax.profiler.TraceAnnotation``); every lexical span then also
+  stands in a ``jax.profiler`` session's host plane under its own
+  name, on its own thread, beside the device programs.  Unset by
+  default; outside a session the annotation is a no-op in the runtime.
 
 Surfaces: the metrics HTTP server serves ``/trace`` next to
 ``/metrics``; the Inspector exposes a ``trace`` JSON-RPC route; and
@@ -77,13 +83,16 @@ if hasattr(os, "register_at_fork"):
 class _Span:
     """One in-flight span; records a complete ("ph": "X") event on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_parent")
+    __slots__ = (
+        "_tracer", "name", "cat", "args", "_t0", "_parent", "_annotation",
+    )
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = None
 
     def set(self, **args) -> None:
         """Attach result data discovered mid-span (e.g. batch verdict)."""
@@ -99,9 +108,18 @@ class _Span:
         # the GIL, and this is the lexical-span hot path.
         self._tracer._active[threading.get_ident()] = self.name
         self._t0 = time.perf_counter()
+        # the annotation sits INSIDE the span's own interval (entered
+        # after the start is taken, left before the end is), so the
+        # ring's duration never reads shorter than the profiler's
+        annotate = self._tracer._annotate
+        if annotate is not None:
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         end = time.perf_counter()
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
@@ -128,7 +146,8 @@ class SpanTracer:
     lands in the child's args).  ``add_complete`` records a span after
     the fact from explicit perf_counter timestamps — used by the
     consensus state machine, whose steps begin and end at different
-    call sites.
+    call sites; such spans stay ring-only (an annotation cannot be
+    written after the fact).
     """
 
     def __init__(
@@ -170,6 +189,9 @@ class SpanTracer:
         #: tid -> thread name, captured at record time — a track must
         #: keep its name after its thread exits
         self._thread_names: dict[int, str] = {}
+        #: name -> context manager entered around every lexical span
+        #: (module docstring); None keeps this module stdlib-only
+        self._annotate = None
         _PID_TRACERS.add(self)
 
     # -- recording -----------------------------------------------------
@@ -216,8 +238,10 @@ class SpanTracer:
             "name": name,
             "cat": cat,
             "ph": "X",
-            "ts": round(max(start - self.epoch, 0.0) * 1e6, 1),
-            "dur": round(max(duration_s, 0.0) * 1e6, 1),
+            # tenths of a microsecond; int() because round(x, 1) costs
+            # half a microsecond a call (it goes through dtoa)
+            "ts": int(max(start - self.epoch, 0.0) * 1e7 + 0.5) / 10,
+            "dur": int(max(duration_s, 0.0) * 1e7 + 0.5) / 10,
             "pid": self._pid,
             "tid": thread.ident,
             "args": args,
@@ -303,6 +327,12 @@ class SpanTracer:
 
     def set_enabled(self, enabled: bool) -> None:
         self.enabled = bool(enabled)
+
+    def set_annotator(self, annotate) -> None:
+        """``annotate(name)`` -> a context manager entered inside every
+        lexical span from now on (None: none).  A disabled tracer never
+        calls it."""
+        self._annotate = annotate
 
 
 #: process-wide tracer — all planes record here, all surfaces read here

@@ -286,3 +286,157 @@ class TestHeightPipeline:
             and e["args"].get("height") == 2
         ]
         assert idx and idx[0]["args"].get("parent") == "height/pipeline"
+
+
+class _Recorder:
+    """An annotator that logs (thread, "enter"/"exit", name)."""
+
+    def __init__(self):
+        self.log: list[tuple] = []
+
+    def __call__(self, name):
+        rec = self
+
+        class _Ctx:
+            def __enter__(self):
+                rec.log.append((threading.get_ident(), "enter", name))
+
+            def __exit__(self, *exc):
+                rec.log.append((threading.get_ident(), "exit", name))
+
+        return _Ctx()
+
+
+class TestAnnotator:
+    """ISSUE 26: the seam that puts every lexical span on the
+    profiler's clock (``cometbft_tpu/ops`` installs
+    ``jax.profiler.TraceAnnotation``)."""
+
+    def test_unset_by_default_and_module_stays_stdlib_only(self):
+        import ast
+        import inspect
+
+        assert SpanTracer(capacity=8, enabled=True)._annotate is None
+        tree = ast.parse(inspect.getsource(trace_mod))
+        imported = {
+            (n.module or "") if isinstance(n, ast.ImportFrom)
+            else n.names[0].name
+            for n in ast.walk(tree)
+            if isinstance(n, (ast.Import, ast.ImportFrom))
+        }
+        assert not any(m.split(".")[0] == "jax" for m in imported)
+
+    def test_entered_and_left_in_lifo_order_per_thread(self):
+        t = SpanTracer(capacity=64, enabled=True)
+        rec = _Recorder()
+        t.set_annotator(rec)
+        gate = threading.Barrier(2, timeout=10)
+
+        def work(tag):
+            with t.span(f"{tag}/outer"):
+                gate.wait()  # both threads hold a span open at once
+                with t.span(f"{tag}/inner"):
+                    pass
+                with t.span(f"{tag}/second"):
+                    pass
+
+        threads = [threading.Thread(target=work, args=(tag,))
+                   for tag in ("a", "b")]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(10)
+            assert not th.is_alive()
+        by_thread: dict[int, list] = {}
+        for tid, what, name in rec.log:
+            by_thread.setdefault(tid, []).append((what, name))
+        assert len(by_thread) == 2
+        for calls in by_thread.values():
+            tag = calls[0][1].split("/")[0]
+            assert calls == [
+                ("enter", f"{tag}/outer"),
+                ("enter", f"{tag}/inner"), ("exit", f"{tag}/inner"),
+                ("enter", f"{tag}/second"), ("exit", f"{tag}/second"),
+                ("exit", f"{tag}/outer"),
+            ]
+        assert len(t.events()) == 6
+
+    def test_annotation_sits_inside_the_spans_own_interval(self):
+        t = SpanTracer(capacity=8, enabled=True)
+        stamps = {}
+
+        class _Ctx:
+            def __enter__(self):
+                stamps["enter"] = time.perf_counter()
+
+            def __exit__(self, *exc):
+                stamps["exit"] = time.perf_counter()
+
+        t.set_annotator(lambda name: _Ctx())
+        with t.span("timed"):
+            pass
+        (e,) = t.events()
+        start = t.epoch + e["ts"] / 1e6
+        assert start <= stamps["enter"] + 1e-6
+        assert stamps["exit"] <= start + e["dur"] / 1e6 + 1e-6
+
+    def test_disabled_tracer_and_add_complete_never_annotate(self):
+        rec = _Recorder()
+        off = SpanTracer(capacity=8, enabled=False)
+        off.set_annotator(rec)
+        with off.span("hot", batch=1):
+            pass
+        assert off.span("a") is off.span("b")  # still the shared no-op
+        on = SpanTracer(capacity=8, enabled=True)
+        on.set_annotator(rec)
+        on.add_complete("after-the-fact", time.perf_counter(), 0.01)
+        assert rec.log == []
+        assert [e["name"] for e in on.events()] == ["after-the-fact"]
+
+    def test_an_exception_leaves_the_annotation_too(self):
+        t = SpanTracer(capacity=8, enabled=True)
+        rec = _Recorder()
+        t.set_annotator(rec)
+        try:
+            with t.span("boom"):
+                raise ValueError("x")
+        except ValueError:
+            pass
+        assert [c[1:] for c in rec.log] == [
+            ("enter", "boom"), ("exit", "boom"),
+        ]
+
+    def test_a_span_stands_in_the_profilers_host_plane(self, tmp_path):
+        """Under a jax.profiler session (CPU backend) the process-wide
+        tracer's spans are in the ``/host:CPU`` plane under their own
+        names, as the benchmark's reduction reads them."""
+        import os
+        import sys
+
+        import jax.numpy as jnp
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        if repo not in sys.path:
+            sys.path.insert(0, repo)
+        from benchmark import run, trace_reduce
+
+        import cometbft_tpu.ops  # noqa: F401 — installs the annotator
+
+        def work():
+            with trace_mod.TRACER.span("stagetest/outer", cat="test"):
+                with trace_mod.TRACER.span("stagetest/inner", cat="test"):
+                    jnp.arange(8).sum().block_until_ready()
+
+        work()  # compile outside the session
+        run.traced(work, str(tmp_path))
+        planes = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)))
+        spans = trace_reduce.host_spans(planes, ("stagetest/",))
+        assert [s[0] for s in spans] == ["stagetest/outer",
+                                         "stagetest/inner"]
+        (_, o_start, o_dur), (_, i_start, i_dur) = spans
+        assert o_start <= i_start and i_start + i_dur <= o_start + o_dur
+        ring = [e for e in trace_mod.TRACER.events()
+                if e["name"] == "stagetest/outer"]
+        # the ring's interval holds the profiler's (entered after the
+        # start is taken, left before the end is)
+        assert ring[-1]["dur"] * 1e3 >= o_dur
