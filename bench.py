@@ -262,7 +262,7 @@ def main() -> dict:
         pubs_b = [p.pub_key().bytes() for p in privs]
         t0 = time.time()
         entry = PR.TABLE_CACHE.lookup_or_build(pubs_b)
-        np.asarray(jax.device_get(entry.table[0, 0, 0, :4]))
+        np.asarray(jax.device_get(entry.table[0, 0, :4]))
         log(
             f"keyed tables: {nval} keys, {entry.window_bits}-bit, "
             f"{entry.set_nbytes / 1e6:.0f} MB this set "

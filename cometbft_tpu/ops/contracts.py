@@ -19,7 +19,7 @@ Spec grammar (checked by jitcheck, interpreted here):
 - a LEAF spec is ``(dtype, shape)`` — dtype one of DTYPES, shape a
   tuple of dims; a dim is an int or a string arithmetic expression
   over the symbols in ``ladder_env`` (``B``, ``bucket``, ``nblocks``,
-  ``NLIMBS``, ``nwin``, ``nent``, ``cap``, ...);
+  ``NLIMBS``, ``nwin``, ``nent``, ``cap``, ``ROW``, ...);
 - a LIST groups specs into a tuple-valued arg/output (e.g. an
   extended point is four ``("i32", ("NLIMBS", "B"))`` leaves).
 
@@ -51,7 +51,7 @@ DTYPES = {
 #: (parallel/mesh.py) express their dims as global//ndev.
 DIM_SYMBOLS = frozenset(
     {"B", "bucket", "nblocks", "NLIMBS", "nwin", "nent", "cap", "M",
-     "ndev"}
+     "ndev", "ROW"}
 )
 
 
@@ -129,11 +129,13 @@ def ladder_env(batch: int, bucket: int = 128, window_bits: int = 8,
     """The dim bindings for one rung of the batch/bucket ladder —
     exactly the quantities the dispatch path derives (ed25519_verify:
     nblocks from the bucket; precompute: nwin/nent from the window
-    width; cap from the pool ladder; parallel/mesh: ndev the mesh
+    width, ROW the key table's row width; cap from the pool ladder;
+    parallel/mesh: ndev the mesh
     device count, which must divide ``batch`` and ``cap`` the way the
     lane router / table placement pad them)."""
     from cometbft_tpu.ops import field as F
     from cometbft_tpu.ops.ed25519_verify import nblocks_for_bucket
+    from cometbft_tpu.ops.precompute import ROW
 
     return {
         "B": batch,
@@ -146,6 +148,7 @@ def ladder_env(batch: int, bucket: int = 128, window_bits: int = 8,
         "nent": 1 << window_bits,
         "cap": cap if cap is not None else batch,
         "ndev": ndev,
+        "ROW": ROW,
     }
 
 

@@ -149,8 +149,8 @@ def verify_kernel_keyed(
     decompression, and comb adds against hot tables.  Reference analog:
     the expanded-pubkey LRU (crypto/ed25519/ed25519.go:43).
 
-    key_ids (B,) int32 index rows of ``table``/``key_valid``; semantics
-    otherwise identical to verify_kernel.
+    key_ids (B,) int32 are pool slots — pages of ``table``, entries of
+    ``key_valid``; semantics otherwise identical to verify_kernel.
     """
     from cometbft_tpu.ops import precompute as PR
 
@@ -1008,7 +1008,7 @@ _CONTRACTS = {
             "msg": ("u8", ("M", "B")),
             "msglen": ("i32", ("B",)),
             "key_ids": ("i32", ("B",)),
-            "table": ("i32", ("nwin", 4, "NLIMBS", "cap*nent")),
+            "table": ("i32", ("cap", "nwin*nent", "ROW")),
             "key_valid": ("bool", ("cap",)),
         },
         "static": ("nblocks", "window_bits"),
@@ -1017,7 +1017,7 @@ _CONTRACTS = {
     "verify_kernel_keyed_packed": {
         "args": {
             "buf": ("u8", ("104+bucket", "B")),
-            "table": ("i32", ("nwin", 4, "NLIMBS", "cap*nent")),
+            "table": ("i32", ("cap", "nwin*nent", "ROW")),
             "key_valid": ("bool", ("cap",)),
         },
         "static": ("bucket", "nblocks", "window_bits"),

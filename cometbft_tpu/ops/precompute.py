@@ -19,8 +19,29 @@ Two table families:
   (Y+X, Y-X, 2Z, 2dT) — keeping Z projective skips the batched field
   inversion at build time for one extra field mul per add
   (curve.pt_add_pniels).  Window width adapts to the set size: 8-bit
-  combs (32 adds/verify, ~3.4 MB/key) for sets up to KEY8_MAX keys,
-  4-bit (64 adds, ~430 KB/key) above.
+  combs (32 adds/verify, 4 MiB/key) for sets up to KEY8_MAX keys,
+  4-bit (64 adds, 512 KiB/key) above.
+
+**Layout of the per-key tables** (the one fact ``slot_rows`` and
+``lane_rows`` hold; everything else pads, writes, gathers or shards
+whole pages on the leading axis): the pool is SLOT-MAJOR with ONE ROW
+PER ENTRY, logical ``(cap, nwin * nent, ROW)`` int32.  A key's page is
+one contiguous block; the row of (slot, window w, digit j) in the flat
+``(cap * nwin * nent, ROW)`` view is ``slot * nwin * nent + w * nent +
+j``; a row is the entry's (4, 26) limbs flattened to 104 and
+zero-padded to ROW = 128.  The verify kernel gathers its lanes' rows
+straight from that flat view, so a launch moves the bytes it reads
+(32 x 256 x 512 B at Cosmos-Hub size) and not the table.  Why 128 and
+not 104: the TPU tiles the two minor axes (8, 128) and chooses the
+physical order itself — for a logical ``(..., m, 104)`` table it puts
+the ENTRY axis minor again and re-lays the table before every gather,
+or pads the whole table into a temporary every launch; a full
+128-lane row is what it keeps minor, at 23% more bytes.  What
+also does not work: slicing one window ``(nwin, m, ROW)[w]`` inside
+the comb's scan (no relayout, but the slice is materialised every
+step), and a window-major ``(nwin, 4, 26, cap * nent)`` table
+gathered on its last axis (each scan step slices AND re-lays a
+window's 27 MB, padded to 134 MB: most of a 150-validator launch).
 
 Tables are cached PER KEY in a device pool (``_KeyPool``) bounded by
 CMT_TPU_TABLE_CACHE_MB, matching the reference's per-key LRU
@@ -54,7 +75,7 @@ from cometbft_tpu.ops.ed25519_verify import _next_pow2
 from cometbft_tpu.utils import sync as cmtsync
 from cometbft_tpu.utils.env import int_from_env
 
-#: largest set that gets 8-bit per-key combs (3.4 MB/key on device)
+#: largest set that gets 8-bit per-key combs (4 MiB/key on device)
 KEY8_MAX = int_from_env("CMT_TPU_KEY8_MAX", 256)
 #: largest set we precompute tables for at all
 TABLE_MAX_KEYS = int_from_env("CMT_TPU_TABLE_MAX_KEYS", 16384)
@@ -112,12 +133,37 @@ _BX, _BY = _ref.pt_to_affine(_ref.B_POINT)
 _B_AFFINE = (F.from_int(_BX), F.from_int(_BY))
 
 
+#: int32 limbs of one comb entry: (Y+X, Y-X, 2Z, 2dT) x NLIMBS
+ENTRY_LIMBS = 4 * F.NLIMBS
+#: width of one table row.  An entry's 104 limbs are zero-padded to one
+#: 128-lane TPU tile so the compiler keeps the ROW on the minor axis
+#: and a gather fetches whole rows from the table where it lies.
+ROW = 128
+
+
+def slot_rows(window_bits: int) -> int:
+    """Rows of one key's page: an entry for each (window, digit)."""
+    return (256 // window_bits) << window_bits
+
+
+def lane_rows(key_ids, windows, window_bits: int):
+    """Row of the flat ``(cap * slot_rows, ROW)`` table that each lane
+    reads at each window: key_ids (*batch,) pool slots, windows
+    (nwin, *batch) digits -> (nwin, *batch) int32.  The largest pool
+    (16,384 slots x 64 x 16 rows) stays inside int32."""
+    nwin = 256 // window_bits
+    win_base = jnp.arange(nwin, dtype=jnp.int32) << window_bits
+    win_base = win_base.reshape((nwin,) + (1,) * key_ids.ndim)
+    return key_ids * slot_rows(window_bits) + win_base + windows
+
+
 def build_tables_kernel(pub, window_bits: int):
     """pub (32, n) uint8 -> (table, valid).
 
-    table: (nwin, 4, 26, n * nent) int32 — window-major projective
-    Niels entries ``j * (2^wb)^w * (-A_key)``, minor axis ordered
-    (key, entry) so a verify gathers with ``key_id * nent + window``.
+    table: (n, nwin * nent, ROW) int32 — one page per key, one row per
+    projective Niels entry ``j * (2^wb)^w * (-A_key)`` at page row
+    ``w * nent + j``; the row is the entry's (4, 26) limbs flattened to
+    104 and zero-padded to ROW (see ``lane_rows``).
     valid: (n,) bool — ZIP-215 decompression validity per key; invalid
     keys get B's table (harmless) and must be masked by callers.
     """
@@ -157,10 +203,11 @@ def build_tables_kernel(pub, window_bits: int):
     ex, ey, ez, et = (jnp.moveaxis(c, 0, 1) for c in entries)
     t2d = F.mul(et, F.cvec(C.TWO_D_LIMBS, et.ndim))
     pn = jnp.stack([ey + ex, ey - ex, ez + ez, t2d])  # (4, 26, nent, nwin*n)
-    pn = pn.reshape(4, F.NLIMBS, nent, nwin, n)
-    # -> (nwin, 4, 26, n, nent) -> (nwin, 4, 26, n*nent)
-    pn = jnp.transpose(pn, (3, 0, 1, 4, 2))
-    return pn.reshape(nwin, 4, F.NLIMBS, n * nent), valid
+    pn = pn.reshape(ENTRY_LIMBS, nent, nwin, n)
+    # -> (n, nwin, nent, 104): one row per entry, pages slot-major
+    pn = jnp.transpose(pn, (3, 2, 1, 0))
+    pn = jnp.pad(pn, [(0, 0), (0, 0), (0, 0), (0, ROW - ENTRY_LIMBS)])
+    return pn.reshape(n, nwin * nent, ROW), valid
 
 
 _build_cache: dict[tuple[int, int], object] = {}
@@ -182,18 +229,30 @@ def _compiled_build(n: int, window_bits: int):
 
 
 def comb_mul_keyed(table, key_ids, windows, window_bits: int):
-    """Per-key comb: table from build_tables_kernel, key_ids (*batch,)
-    int32, windows (nwin, *batch) int32 LE digit decomposition of k.
-    Returns [k](-A_key) per lane as an extended point."""
-    nent = 1 << window_bits
-    base_idx = key_ids * nent
+    """Per-key comb: table (cap, nwin * nent, ROW) of pages from
+    build_tables_kernel, key_ids (*batch,) int32 pool slots, windows
+    (nwin, *batch) int32 LE digit decomposition of k.
+    Returns [k](-A_key) per lane as an extended point.
 
-    def body(acc, xs):
-        tbl_w, win = xs  # (4, 26, m), (*batch,)
-        e = jnp.take(tbl_w, base_idx + win, axis=-1)  # (4, 26, *batch)
+    Each scan step gathers its lanes' ROWS straight from the whole
+    table (a free reshape to 2-D), so no step slices or re-lays
+    anything table-sized, and the launch holds no temporary; only the
+    fetched (batch, ROW) block is turned limbs-major.  (One gather of
+    all windows' rows ahead of the scan is faster still on the v5e —
+    PERF.md §6, PR 27 — and is the next step once the benchmark's
+    commit chain is long enough to time it.)"""
+    batch = key_ids.shape
+    rows2d = table.reshape(-1, ROW)
+
+    def body(acc, row_ids):
+        e = jnp.take(rows2d, row_ids, axis=0, mode="clip")  # (*batch, ROW)
+        e = jnp.moveaxis(e[..., :ENTRY_LIMBS], -1, 0)
+        e = e.reshape((4, F.NLIMBS) + batch)
         return C.pt_add_pniels(acc, (e[0], e[1], e[2], e[3])), None
 
-    acc, _ = lax.scan(body, C.identity(key_ids.shape), (table, windows))
+    acc, _ = lax.scan(
+        body, C.identity(batch), lane_rows(key_ids, windows, window_bits)
+    )
     return acc
 
 
@@ -204,16 +263,18 @@ def comb_mul_keyed(table, key_ids, windows, window_bits: int):
 class KeySetTables:
     """A validator set's view into the device-resident key-table pool.
 
-    ``key_index`` maps each pubkey to its POOL SLOT; ``table``/``valid``
-    are immutable snapshots of the pool arrays, so an entry stays
-    self-consistent even after later rotations grow, compact, or evict
-    the pool underneath it.
+    ``key_index`` maps each pubkey to its POOL SLOT — the index of its
+    page on ``table``'s leading axis, and the key id the verify kernel
+    turns into rows (``lane_rows``); ``table``/``valid`` are immutable
+    snapshots of the pool arrays, so an entry stays self-consistent
+    even after later rotations grow, compact, or evict the pool
+    underneath it.
     """
 
     sethash: bytes
     window_bits: int
     key_index: dict[bytes, int]  # pubkey bytes -> pool slot
-    table: object                # device array (nwin, 4, 26, cap*nent)
+    table: object                # device array (cap, nwin*nent, ROW)
     valid: np.ndarray            # (cap,) bool
     nbytes: int                  # bytes of ``table`` (whole pool)
     set_nbytes: int = 0          # bytes attributable to this set's keys
@@ -263,12 +324,12 @@ class KeySetTables:
         would leave the high-block devices with only dead slots (150
         live keys in a 256-slot pool on 8 chips would idle 3 of them
         every launch).  The pages are gathered into per-device
-        contiguous order ONCE here (a device gather per placement, same
-        cost class as the pad), so on the minor (cap*nent) axis device
-        ``d``'s shard block holds its strided slots at LOCAL positions
-        ``slot // ndev`` — the shard-local gather with rebased ids
-        touches only local HBM and the sharded keyed kernel runs with
-        zero collectives.  Returns ``(table, valid, per_cap)``; the
+        contiguous order ONCE here (a device page gather per placement,
+        same cost class as the pad), so on the leading (slot) axis
+        device ``d``'s shard block holds its strided slots at LOCAL
+        positions ``slot // ndev`` — the shard-local row gather with
+        rebased ids touches only local HBM and the sharded keyed kernel
+        runs with zero collectives.  Returns ``(table, valid, per_cap)``; the
         placement's device bytes are recorded for the cache's budget
         accounting.
 
@@ -285,7 +346,6 @@ class KeySetTables:
         with self._mtx:
             placed = self.placements.get(key)
         if placed is None:
-            nent = 1 << self.window_bits
             cap = len(self.valid)
             per_cap = -(-cap // ndev)
             shard_cap = per_cap * ndev
@@ -299,21 +359,14 @@ class KeySetTables:
             with jax.transfer_guard("allow"):
                 table, valid = self.table, self.valid
                 if shard_cap > cap:
-                    table = jnp.pad(
-                        table,
-                        [(0, 0), (0, 0), (0, 0),
-                         (0, (shard_cap - cap) * nent)],
-                    )
+                    table = _pad_slots(table, shard_cap - cap)
                     valid = np.pad(valid, (0, shard_cap - cap))
                 # strided -> per-device-contiguous page permutation:
                 # position (d*per_cap + j) <- slot (j*ndev + d)
                 slot_perm = (
                     np.arange(shard_cap).reshape(per_cap, ndev).T.ravel()
                 )
-                idx = (
-                    slot_perm[:, None] * nent + np.arange(nent)
-                ).ravel()
-                table = table[..., jax.device_put(idx)]
+                table = table[jax.device_put(slot_perm)]
                 valid = valid[slot_perm]
                 table = jax.device_put(table, table_sharding)
                 valid = jax.device_put(valid, valid_sharding)
@@ -326,6 +379,11 @@ class KeySetTables:
         return placed[0]
 
 
+def _pad_slots(table, extra: int):
+    """``table`` with ``extra`` zero pages appended on the slot axis."""
+    return jnp.pad(table, [(0, extra), (0, 0), (0, 0)])
+
+
 _B_ENC = np.frombuffer(_ref.encode_point(_ref.B_POINT), dtype=np.uint8)
 
 
@@ -334,7 +392,7 @@ def _pool_cap(nkeys: int) -> int:
     then 2048-slot steps) so the shape-specialized verify kernel only
     retraces a bounded number of times — while avoiding pow2's up-to-2x
     HBM waste at large validator counts (10k keys: 10240 slots =
-    4.4 GB at 4-bit, vs 16384 slots = 7 GB)."""
+    5 GiB at 4-bit, vs 16384 slots = 8 GiB)."""
     if nkeys <= 4096:
         return _next_pow2(max(nkeys, 1))
     return -(-nkeys // 2048) * 2048
@@ -343,10 +401,11 @@ def _pool_cap(nkeys: int) -> int:
 class _KeyPool:
     """One window width's device pool of per-key comb pages.
 
-    The pool's minor axis holds ``cap`` fixed-size key pages
-    (cap * nent entries); a key's page lives at
-    ``[slot*nent : (slot+1)*nent]`` so ``comb_mul_keyed``'s
-    ``key_id * nent`` indexing works with slot numbers as key ids.
+    The pool is SLOT-MAJOR: ``table[slot]`` is one key's whole page,
+    ``slot_rows`` contiguous ROW-wide rows, so growth pads, page writes
+    set and compaction gathers whole pages on the leading axis, and
+    ``comb_mul_keyed`` reads rows with slot numbers as key ids
+    (``lane_rows``).
     Capacity follows the ``_pool_cap`` ladder — powers of two up to
     4096 slots, then 2048-slot steps: the compiled keyed-verify kernel
     specializes on the table shape, so growth only retraces at ladder
@@ -356,11 +415,10 @@ class _KeyPool:
 
     def __init__(self, window_bits: int) -> None:
         self.window_bits = window_bits
-        self.nent = 1 << window_bits
-        self.nwin = 256 // window_bits
-        self.key_bytes = self.nwin * 4 * F.NLIMBS * self.nent * 4
+        # what the device holds: ROW-wide rows, padding included
+        self.key_bytes = slot_rows(window_bits) * ROW * 4
         self.cap = 0
-        self.table = None  # device (nwin, 4, 26, cap*nent) int32
+        self.table = None  # device (cap, nwin*nent, ROW) int32
         self.valid = np.zeros(0, dtype=bool)
         self.slots: OrderedDict[bytes, int] = OrderedDict()  # LRU order
         self.free: list[int] = []
@@ -383,13 +441,15 @@ class _KeyPool:
         if cap >= nkeys:
             return None
         new_cap = _pool_cap(nkeys)
-        shape = (self.nwin, 4, F.NLIMBS, new_cap * self.nent)
+        return (version, new_cap, self._grown(table, cap, new_cap))
+
+    def _grown(self, table, cap: int, new_cap: int):
+        """``table`` (``cap`` pages, or None) with zero pages appended
+        up to ``new_cap``."""
         if table is None:
-            grown = jnp.zeros(shape, dtype=jnp.int32)
-        else:
-            pad = (new_cap - cap) * self.nent
-            grown = jnp.pad(table, [(0, 0), (0, 0), (0, 0), (0, pad)])
-        return (version, new_cap, grown)
+            shape = (new_cap, slot_rows(self.window_bits), ROW)
+            return jnp.zeros(shape, dtype=jnp.int32)
+        return _pad_slots(table, new_cap - cap)
 
     def ensure_capacity(self, nkeys: int, staged=None) -> None:
         """Grow to the ladder capacity for ``nkeys``.  Lock held.  A
@@ -407,14 +467,8 @@ class _KeyPool:
         ):
             new_cap = staged[1]
             self.table = staged[2]
-        elif self.table is None:
-            shape = (self.nwin, 4, F.NLIMBS, new_cap * self.nent)
-            self.table = jnp.zeros(shape, dtype=jnp.int32)
         else:
-            pad = (new_cap - self.cap) * self.nent
-            self.table = jnp.pad(
-                self.table, [(0, 0), (0, 0), (0, 0), (0, pad)]
-            )
+            self.table = self._grown(self.table, self.cap, new_cap)
         self.valid = np.concatenate(
             [self.valid, np.zeros(new_cap - self.cap, dtype=bool)]
         )
@@ -434,16 +488,9 @@ class _KeyPool:
         if new_cap >= self.cap:
             return
         order = list(self.slots.items())  # preserves LRU order
-        gather = np.concatenate(
-            [
-                np.arange(s * self.nent, (s + 1) * self.nent)
-                for _, s in order
-            ]
-        ) if order else np.zeros(0, dtype=np.int64)
-        pad = new_cap * self.nent - len(gather)
-        new_table = jnp.pad(
-            self.table[..., jnp.asarray(gather)],
-            [(0, 0), (0, 0), (0, 0), (0, pad)],
+        live = np.array([s for _, s in order], dtype=np.int32)
+        new_table = _pad_slots(
+            self.table[jnp.asarray(live)], new_cap - n_live
         )
         new_valid = np.zeros(new_cap, dtype=bool)
         new_slots: OrderedDict[bytes, int] = OrderedDict()
@@ -559,14 +606,9 @@ class KeyTableCache:
                         len(pool.slots) + len(missing), staged=staged
                     )
                     slots = [pool.free.pop() for _ in missing]
-                    idx = (
-                        np.array(slots, dtype=np.int64)[:, None]
-                        * pool.nent
-                        + np.arange(pool.nent)
-                    ).ravel()
-                    pool.table = pool.table.at[..., jnp.asarray(idx)].set(
-                        pages[..., : len(missing) * pool.nent]
-                    )
+                    pool.table = pool.table.at[
+                        jnp.asarray(slots, dtype=jnp.int32)
+                    ].set(pages[: len(missing)])
                     pool.version += 1
                     for i, (p, s) in enumerate(zip(missing, slots)):
                         pool.slots[p] = s
@@ -740,7 +782,7 @@ _CONTRACTS = {
         "args": {"pub": ("u8", (32, "B"))},
         "static": ("window_bits",),
         "out": [
-            ("i32", ("nwin", 4, "NLIMBS", "B*nent")),
+            ("i32", ("B", "nwin*nent", "ROW")),
             ("bool", ("B",)),
         ],
     },
@@ -756,7 +798,7 @@ _CONTRACTS = {
     },
     "comb_mul_keyed": {
         "args": {
-            "table": ("i32", ("nwin", 4, "NLIMBS", "cap*nent")),
+            "table": ("i32", ("cap", "nwin*nent", "ROW")),
             "key_ids": ("i32", ("B",)),
             "windows": ("i32", ("nwin", "B")),
         },

@@ -144,8 +144,8 @@ _keyed_mesh_cache: dict[tuple, object] = {}
 def _compiled_keyed_mesh(mesh: Mesh, bucket: int, window_bits: int,
                          chunk: int):
     """jit of the sharded keyed kernel over (buf, table, key_valid):
-    the batch shards on its lane axis, the TABLE shards on its minor
-    (cap*nent) axis — contiguous per-device slot blocks — and the
+    the batch shards on its lane axis, the TABLE shards on its leading
+    (slot) axis — contiguous per-device blocks of whole pages — and the
     shard-local body runs under ``shard_map`` so the comb gather stays
     local (the SPMD partitioner would otherwise all-gather the table
     per launch).  Explicit ``in_shardings``/``out_shardings`` on the
@@ -184,7 +184,7 @@ def _compiled_keyed_mesh(mesh: Mesh, bucket: int, window_bits: int,
 
     in_specs = (
         P(None, DATA_AXIS),
-        P(None, None, None, DATA_AXIS),
+        P(DATA_AXIS, None, None),
         P(DATA_AXIS),
     )
     out_spec = P(DATA_AXIS)
@@ -239,8 +239,8 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
     precompute tables SHARD across the mesh with the batch lanes routed
     to their key's owning chip (see _run_keyed / the module docstring);
     each chip holds 1/ndev of the table instead of a full replica, so a
-    10k-validator 4-bit pool (~4.4 GB) costs ~550 MB of HBM per chip
-    rather than 4.4 GB on every one.
+    10k-validator 4-bit pool (5 GiB) costs 640 MiB of HBM per chip
+    rather than 5 GiB on every one.
     """
 
     def __init__(self, mesh: Mesh | None = None, **kwargs) -> None:
@@ -351,7 +351,7 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
         # under a NamedSharding; built once per (entry, mesh)
         table, valid, per_cap = entry.sharded_tables(
             self._mesh,
-            self._sharding(None, None, None, DATA_AXIS),
+            self._sharding(DATA_AXIS, None, None),
             self._sharding(DATA_AXIS),
             ndev,
         )
@@ -425,7 +425,7 @@ _CONTRACTS = {
     "verify_keyed_shard": {
         "args": {
             "buf": ("u8", ("104+bucket", "B//ndev")),
-            "table": ("i32", ("nwin", 4, "NLIMBS", "cap*nent//ndev")),
+            "table": ("i32", ("cap//ndev", "nwin*nent", "ROW")),
             "key_valid": ("bool", ("cap//ndev",)),
         },
         "static": ("bucket", "nblocks", "window_bits"),

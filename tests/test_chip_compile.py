@@ -15,6 +15,9 @@ worker imports every test file.
 
 from __future__ import annotations
 
+import math
+import re
+
 import numpy as np
 import pytest
 
@@ -23,10 +26,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from cometbft_tpu.ops import field as F
+from cometbft_tpu.ops import precompute as PR
 
 BUCKET = 128  # canonical precommit sign-bytes are ~115 bytes
 V5E_HBM_BYTES = 16 << 30
+#: scratch a keyed launch may hold beside its arguments; the window-major
+#: table's per-window relayout alone was 135 MB at 256 slots x 8 bits
+KEYED_TEMP_BYTES = 64 << 20
 
 
 @pytest.fixture(scope="module")
@@ -57,7 +63,6 @@ def no_persistent_cache():
 
 def keyed_shapes(lanes: int, slots: int, window_bits: int, sharding=None):
     """(packed batch, key table, validity mask) of the keyed kernel."""
-    nwin, nent = 256 // window_bits, 1 << window_bits
 
     def sds(shape, dtype, s):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=s)
@@ -65,7 +70,7 @@ def keyed_shapes(lanes: int, slots: int, window_bits: int, sharding=None):
     s = sharding or (None, None, None)
     return (
         sds((104 + BUCKET, lanes), jnp.uint8, s[0]),
-        sds((nwin, 4, F.NLIMBS, slots * nent), jnp.int32, s[1]),
+        sds((slots, PR.slot_rows(window_bits), PR.ROW), jnp.int32, s[1]),
         sds((slots,), jnp.bool_, s[2]),
     )
 
@@ -76,6 +81,49 @@ def device_bytes(compiled) -> int:
         m.argument_size_in_bytes + m.output_size_in_bytes
         + m.temp_size_in_bytes + m.generated_code_size_in_bytes
     )
+
+
+_MOVER = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) (copy|dynamic-slice|fusion)\("
+)
+_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]+)\]")
+
+
+def table_sized_movers(hlo_text: str, min_elems: int) -> list[str]:
+    """The ``copy``, ``dynamic-slice`` and fusion instructions of a
+    compiled program whose output holds ``min_elems`` elements or more
+    — what a relayout or a slice of the key table shows up as."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _MOVER.match(line)
+        if m is None:
+            continue
+        name, out_type, op = m.groups()
+        for dims in _ARRAY.findall(out_type):
+            elems = math.prod(int(d) for d in dims.split(","))
+            if elems >= min_elems:
+                found.append(f"{op} {name} [{dims}]")
+    return found
+
+
+def test_table_sized_movers_finds_the_window_major_relayout():
+    """The reader has teeth: three lines of the 8-bit program as the
+    v5e compiler wrote it over the window-major table, and one of
+    today's — a slice, its relayout, the fusion that feeds it; a row
+    gather's output is none of them."""
+    window = 256 * 256 * PR.ENTRY_LIMBS
+    text = """
+  ROOT %dynamic_slice.97 = s32[1,4,26,65536]{3,1,2,0:T(4,128)S(1)} dynamic-slice(%param_0.30659, %param_1.40611), dynamic_slice_sizes={1,4,26,65536}
+  %copy.3562 = s32[1,4,26,65536]{2,1,3,0:T(4,128)} copy(%constant_dynamic-slice_fusion.14), metadata={op_name="while/body/dynamic_slice"}
+  %constant_dynamic-slice_fusion.14 = s32[1,4,26,65536]{3,1,2,0:T(4,128)S(1)} fusion(%get-tuple-element.7761, %convert.1604), kind=kLoop
+  %gather.8 = s32[256,128]{1,0:T(8,128)} gather(%param_0.1948, %transpose.26), offset_dims={1}, slice_sizes={1,128}
+  %fusion.156 = (s32[104,256]{0,1:T(8,128)S(1)}, s32[26,256]{0,1}) fusion(%fusion.155), kind=kLoop
+"""
+    assert table_sized_movers(text, window) == [
+        "dynamic-slice dynamic_slice.97 [1,4,26,65536]",
+        "copy copy.3562 [1,4,26,65536]",
+        "fusion constant_dynamic-slice_fusion.14 [1,4,26,65536]",
+    ]
 
 
 @pytest.mark.parametrize(
@@ -96,6 +144,11 @@ def test_keyed_verify_compiles_for_one_v5e(
         *keyed_shapes(lanes, slots, window_bits, (one_chip,) * 3)
     ).compile()
     assert device_bytes(compiled) < V5E_HBM_BYTES
+    # the launch reads rows where they lie: nothing as large as one
+    # window's entries of the table is copied, sliced or fused out
+    window = slots * (1 << window_bits) * PR.ENTRY_LIMBS
+    assert table_sized_movers(compiled.as_text(), window) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < KEYED_TEMP_BYTES
 
 
 def test_keyed_mesh_compiles_for_four_v5e(topo, no_persistent_cache):
@@ -107,7 +160,7 @@ def test_keyed_mesh_compiles_for_four_v5e(topo, no_persistent_cache):
     shardings = tuple(
         NamedSharding(mesh, spec)
         for spec in (
-            P(None, DATA_AXIS), P(None, None, None, DATA_AXIS), P(DATA_AXIS)
+            P(None, DATA_AXIS), P(DATA_AXIS, None, None), P(DATA_AXIS)
         )
     )
     fn = _compiled_keyed_mesh(mesh, BUCKET, 4, MAX_LAUNCH)

@@ -543,7 +543,7 @@ class TestContractEvalShape:
 
         import jax.numpy as jnp
 
-        from cometbft_tpu.ops import field as F
+        from cometbft_tpu.ops import precompute as PR
         from cometbft_tpu.parallel import mesh as M
 
         devs = jax.devices()
@@ -552,12 +552,12 @@ class TestContractEvalShape:
                 np.array(devs[:ndev]), (M.DATA_AXIS,)
             )
             fn = M._compiled_keyed_mesh(mesh, 128, 8, 8192)
-            batch, cap, nent = 64, 16, 256
+            batch, cap = 64, 16
             out = jax.eval_shape(
                 fn,
                 jax.ShapeDtypeStruct((104 + 128, batch), jnp.uint8),
                 jax.ShapeDtypeStruct(
-                    (32, 4, F.NLIMBS, cap * nent), jnp.int32
+                    (cap, PR.slot_rows(8), PR.ROW), jnp.int32
                 ),
                 jax.ShapeDtypeStruct((cap,), jnp.bool_),
             )

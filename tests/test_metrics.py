@@ -172,9 +172,9 @@ class TestCryptoMetrics:
             # shapes the insert path expects, no EC compute
             n_pad = max(len(missing), 1)
             n_pad = 1 << (n_pad - 1).bit_length() if n_pad > 1 else 1
-            nent = 1 << window_bits
-            nwin = 256 // window_bits
-            table = np.zeros((nwin, 4, 26, n_pad * nent), dtype=np.int32)
+            table = np.zeros(
+                (n_pad, PR.slot_rows(window_bits), PR.ROW), dtype=np.int32
+            )
             return table, np.ones(len(missing), dtype=bool)
 
         monkeypatch.setattr(cache, "_build_pages", fake_build)

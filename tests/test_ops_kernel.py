@@ -31,6 +31,18 @@ def to_dev(pt):
     return tuple(jnp.asarray(F.from_int(v)) for v in (x, y, 1, x * y % E.P))
 
 
+def comb_digits(ks, window_bits):
+    """(nwin, len(ks)) int32 LE ``window_bits``-wide digits of ``ks``."""
+    mask = (1 << window_bits) - 1
+    return np.array(
+        [
+            [(k >> (window_bits * w)) & mask for k in ks]
+            for w in range(256 // window_bits)
+        ],
+        dtype=np.int32,
+    )
+
+
 def affine_eq(dev_pt, ref_pt):
     x, y, z, _ = (F.to_int(np.asarray(c)) % E.P for c in dev_pt)
     zi = pow(z, E.P - 2, E.P)
@@ -345,11 +357,7 @@ class TestPrecompute:
         # lanes hit keys in scrambled order with random scalars
         key_ids = np.array([2, 0, 1, 2], dtype=np.int32)
         ks = [rng.randrange(E.L) for _ in range(4)]
-        nwin = 256 // window_bits
-        wins = np.zeros((nwin, 4), dtype=np.int32)
-        for lane, k in enumerate(ks):
-            for w in range(nwin):
-                wins[w, lane] = (k >> (window_bits * w)) & ((1 << window_bits) - 1)
+        wins = comb_digits(ks, window_bits)
         out = jax.jit(
             lambda t, i, w: PR.comb_mul_keyed(t, i, w, window_bits)
         )(table, jnp.asarray(key_ids), jnp.asarray(wins))
@@ -357,6 +365,92 @@ class TestPrecompute:
             dev_pt = tuple(np.asarray(c)[:, lane] for c in out)
             expect = E.pt_mul(k, E.pt_neg(keys[key_ids[lane]]))
             assert affine_eq(dev_pt, expect)
+
+    @pytest.mark.parametrize("window_bits", [4, 8])
+    def test_keyed_comb_over_pool_lifecycle_vs_oracle(
+        self, rng, monkeypatch, window_bits
+    ):
+        """The slot-major row layout is one fact held in several
+        places.  After each of them has moved pages — pool growth
+        across a capacity step, a page write into a live pool, the
+        strided four-device placement, compaction after eviction —
+        ``comb_mul_keyed`` over the resulting table still gives the
+        oracle's [k](-A) for every resident key."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from cometbft_tpu.ops import precompute as PR
+        from cometbft_tpu.parallel.mesh import DATA_AXIS, flat_mesh
+
+        monkeypatch.setattr(PR, "KEY8_MAX", 256 if window_bits == 8 else 0)
+        rows = PR.slot_rows(window_bits)
+        points = [
+            E.pt_mul(rng.randrange(1, E.L), E.B_POINT) for _ in range(7)
+        ]
+        pubs = [E.encode_point(p) for p in points]
+        point_of = dict(zip(pubs, points))
+        comb = jax.jit(PR.comb_mul_keyed, static_argnums=3)
+
+        def check(table, page_of):
+            """page_of: pubkey -> its page's index in ``table``; four
+            lanes a call so one compile serves every table shape."""
+            lanes = [list(page_of)[i % len(page_of)] for i in range(4)]
+            ks = [rng.randrange(E.L) for _ in lanes]
+            wins = comb_digits(ks, window_bits)
+            ids = np.array([page_of[p] for p in lanes], dtype=np.int32)
+            out = comb(jnp.asarray(table), ids, wins, window_bits)
+            for lane, (p, k) in enumerate(zip(lanes, ks)):
+                dev_pt = tuple(np.asarray(c)[:, lane] for c in out)
+                assert affine_eq(dev_pt, E.pt_mul(k, E.pt_neg(point_of[p])))
+
+        cache = PR.KeyTableCache()
+        e1 = cache.lookup_or_build(pubs[:2])
+        assert e1.window_bits == window_bits
+        assert e1.table.shape == (2, rows, PR.ROW)
+        check(e1.table, e1.key_index)
+
+        # growth 2 -> 8 slots, three pages written beside the old two
+        e2 = cache.lookup_or_build(pubs[:5])
+        assert e2.table.shape == (8, rows, PR.ROW)
+        assert cache.stats["keys_built"] == 5
+        assert bool(e2.valid[list(e2.key_index.values())].all())
+        check(e2.table, e2.key_index)
+        check(e1.table, e1.key_index)  # the old snapshot still stands
+
+        # strided placement on four devices: slot s is page s // 4 of
+        # device s % 4's block
+        mesh = flat_mesh(jax.devices()[:4])
+        table, valid, per_cap = e2.sharded_tables(
+            mesh,
+            NamedSharding(mesh, P(DATA_AXIS, None, None)),
+            NamedSharding(mesh, P(DATA_AXIS)),
+            4,
+        )
+        assert per_cap == 2 and table.shape == (8, rows, PR.ROW)
+        owners = {s % 4 for s in e2.key_index.values()}
+        assert len(owners) > 1
+        for shard in table.addressable_shards:
+            d = shard.index[0].start // per_cap
+            local = {
+                p: s // 4 for p, s in e2.key_index.items() if s % 4 == d
+            }
+            assert shard.data.shape == (per_cap, rows, PR.ROW)
+            if local:
+                check(np.asarray(shard.data), local)
+                assert bool(
+                    np.asarray(valid)[d * per_cap + np.array(
+                        list(local.values())
+                    )].all()
+                )
+
+        # two more keys over a budget of nothing: the three oldest go,
+        # and compaction re-pages the four that stay into 4 slots
+        monkeypatch.setattr(cache, "_cap", 1)
+        e3 = cache.lookup_or_build(pubs[3:])
+        assert cache.stats["keys_evicted"] == 3
+        assert e3.table.shape == (4, rows, PR.ROW)
+        assert sorted(e3.key_index.values()) == [0, 1, 2, 3]
+        assert bool(e3.valid.all())
+        check(e3.table, e3.key_index)
 
     def test_invalid_key_encoding_masked(self, rng):
         from cometbft_tpu.ops import precompute as PR
@@ -493,9 +587,10 @@ class TestPrecompute:
         assert 10_000 > PR.KEY8_MAX  # policy: large sets use 4-bit
         pool = PR._KeyPool(4)
         pool_bytes = PR._pool_cap(10_000) * pool.key_bytes
-        assert pool.key_bytes == 64 * 4 * 26 * 16 * 4  # ~426 KB/key
+        # what the device holds: 128-wide rows, 104 limbs used
+        assert pool.key_bytes == 64 * 16 * PR.ROW * 4  # 512 KiB/key
         assert pool_bytes <= PR.TABLE_CACHE_MB << 20
-        assert pool_bytes < 5 << 30  # ~4.4 GB: fits v5e HBM w/ headroom
+        assert pool_bytes <= 5 << 30  # 5 GiB: fits v5e HBM w/ headroom
 
 
 class TestDispatchThreshold:

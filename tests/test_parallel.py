@@ -518,11 +518,13 @@ class TestKeyPoolMeshAccounting:
         pubs = [p.pub_key().bytes() for p in keyed_mesh_keys[:12]]
         entry = PR.TABLE_CACHE.lookup_or_build(pubs)
         mesh = flat_mesh(jax.devices()[:8])
-        t_sh = NamedSharding(mesh, P(None, None, None, DATA_AXIS))
+        t_sh = NamedSharding(mesh, P(DATA_AXIS, None, None))
         v_sh = NamedSharding(mesh, P(DATA_AXIS))
         table, valid, per_cap = entry.sharded_tables(mesh, t_sh, v_sh, 8)
         assert per_cap * 8 >= len(entry.valid)
-        assert table.shape[-1] == per_cap * 8 * (1 << entry.window_bits)
+        assert table.shape == (
+            per_cap * 8, PR.slot_rows(entry.window_bits), PR.ROW
+        )
         # cached per (entry, mesh): the second call is the same arrays
         again = entry.sharded_tables(mesh, t_sh, v_sh, 8)
         assert again[0] is table

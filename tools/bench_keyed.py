@@ -37,7 +37,7 @@ def main():
 
     t0 = time.time()
     entry = PR.TABLE_CACHE.lookup_or_build(pubs_b)
-    np.asarray(jax.device_get(entry.table[0, 0, 0, :4]))  # sync build
+    np.asarray(jax.device_get(entry.table[0, 0, :4]))  # sync build
     print(
         f"table build: {nval} keys, {entry.window_bits}-bit windows, "
         f"{entry.nbytes / 1e6:.0f} MB, {time.time() - t0:.1f}s "

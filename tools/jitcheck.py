@@ -154,7 +154,7 @@ _WAIVER_RE = waiver_re("host sync")
 DTYPES_OK = frozenset({"u8", "i32", "i64", "u64", "bool"})
 DIM_SYMBOLS = frozenset(
     {"B", "bucket", "nblocks", "NLIMBS", "nwin", "nent", "cap", "M",
-     "ndev"}
+     "ndev", "ROW"}
 )
 STATIC_PARAMS_OK = DIM_SYMBOLS | {"window_bits"}
 

@@ -36,5 +36,5 @@ mark(f"table build dispatched wb={entry.window_bits} "
      f"bytes={entry.nbytes / 1e6:.0f}MB")
 v = np.asarray(entry.valid)
 mark(f"valid fetched: {v.all()}")
-tb = np.asarray(jax.device_get(entry.table[0, 0, 0, :4]))
+tb = np.asarray(jax.device_get(entry.table[0, 0, :4]))
 mark("table sample fetched (build complete)")

@@ -7,7 +7,7 @@ visible (a single-device mesh still exercises the real sharded code
 path and table replication), at the BASELINE config-2/5 shapes:
 
   - 150-validator commit (8-bit comb pages)
-  - 10k-validator mega-commit (4-bit pages, ~4.4 GB pool)
+  - 10k-validator mega-commit (4-bit pages, 5 GiB pool)
 
 and records, per shape: table pool bytes, device memory stats before /
 after the table build (live_bytes from device.memory_stats when the
@@ -75,7 +75,7 @@ def probe_shape(nval: int, nsig: int) -> dict:
             f"{nval} unique keys is outside table policy "
             f"(CMT_TPU_TABLE_MAX_KEYS={PR.TABLE_MAX_KEYS})"
         )
-    np.asarray(jax.device_get(tbl.table[0, 0, 0, :4]))  # force build
+    np.asarray(jax.device_get(tbl.table[0, 0, :4]))  # force build
     entry["table_build_s"] = round(time.time() - t0, 1)
     entry["window_bits"] = tbl.window_bits
     entry["set_table_bytes"] = tbl.set_nbytes
