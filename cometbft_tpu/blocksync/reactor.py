@@ -21,7 +21,10 @@ from cometbft_tpu.state import State
 from cometbft_tpu.types import codec
 from cometbft_tpu.types.block import BlockID
 from cometbft_tpu.types.part_set import BLOCK_PART_SIZE_BYTES, PartSet
-from cometbft_tpu.types.validation import verify_commit_light
+from cometbft_tpu.types.validation import (
+    commit_check_triples,
+    verify_commit_light,
+)
 from cometbft_tpu.utils import trustguard
 from cometbft_tpu.utils.flight import FLIGHT
 from cometbft_tpu.utils.log import Logger, default_logger
@@ -118,28 +121,16 @@ def decode_bs_message(data: bytes):
 
 def commit_prefetch_items(chain_id: str, vals, commit) -> list | None:
     """The ``(pub_key, sign_bytes, signature)`` triples a replay
-    prefetches for one commit — its COMMIT-flag votes, which is what
-    ``verify_commit_light`` checks — or None when the commit does not
-    line up with ``vals`` (the set rotated: never guess).  Votes
-    covered by a commit-level BLS aggregate carry no per-signature
-    proof to prefetch and are skipped."""
-    if commit is None or commit.size() != len(vals):
+    prefetches for one commit — all its COMMIT-flag votes
+    (``types/validation.commit_check_triples``) — or None when the
+    commit does not line up with ``vals`` (the set rotated: never
+    guess)."""
+    if commit is None:
         return None
     with TRACER.span(
         "blocksync/prefetch_items", cat="blocksync", height=commit.height,
     ):
-        items = []
-        for i, cs in enumerate(commit.signatures):
-            if not cs.is_commit() or commit.is_aggregated(i):
-                continue
-            val = vals.get_by_index(i)
-            if val is None or val.address != cs.validator_address:
-                return None
-            items.append((
-                val.pub_key, commit.vote_sign_bytes(chain_id, i),
-                cs.signature,
-            ))
-        return items
+        return commit_check_triples(chain_id, vals, commit)
 
 
 class BlocksyncReactor(Reactor):

@@ -340,6 +340,16 @@ class _LaneBatcher:
         now = time.perf_counter() if now is None else now
         return now - lane[0].t >= self.wait_s
 
+    def cut(self, pending: int) -> int:
+        """How many of ``pending`` requests one release takes: exactly
+        ``batch_target`` once that many are pending — what is over
+        waits for the next buffer, under its own arrival stamps and
+        deadline, instead of padding this launch to the next
+        power-of-two bucket — so a lane that releases by size always
+        launches the one shape it has compiled.  A release by deadline
+        or drain, under the target, takes them all."""
+        return min(pending, self.batch_target)
+
     def deadline_wait(
         self, lane: deque, now: float | None = None
     ) -> float | None:
@@ -479,6 +489,8 @@ class VerifyQueue(BaseService):
             "prepared_batches": 0,
             "launched_batches": 0,
             "launched_sigs": 0,
+            "launched_batches_by_lane": {p: 0 for p in _PRIORITIES},
+            "launched_sigs_by_lane": {p: 0 for p in _PRIORITIES},
             "failed_batches": 0,
         }
         self._collector_thread: threading.Thread | None = None
@@ -627,6 +639,8 @@ class VerifyQueue(BaseService):
                     continue
                 if self._pending[p] and not self._prepared[p]:
                     take = min(len(self._pending[p]), self._max_batch)
+                    if gate is not None:
+                        take = gate.cut(take)
                     reqs = [
                         self._pending[p].popleft() for _ in range(take)
                     ]
@@ -882,6 +896,10 @@ class VerifyQueue(BaseService):
                 self._launch_wall_seconds += wall
                 self._stats["launched_batches"] += 1
                 self._stats["launched_sigs"] += len(prep.reqs)
+                self._stats["launched_batches_by_lane"][prep.priority] += 1
+                self._stats["launched_sigs_by_lane"][prep.priority] += len(
+                    prep.reqs
+                )
                 # CUMULATIVE ratio: overlapped host-prep seconds over
                 # total launch wall — a final buffer with nothing
                 # behind it dilutes rather than zeroes the signal
@@ -899,6 +917,10 @@ class VerifyQueue(BaseService):
                 )
             cm = _crypto_metrics()
             cm.verify_queue_inflight.set(inflight)
+            cm.verify_queue_launched.labels(priority=prep.priority).inc()
+            cm.verify_queue_launched_sigs.labels(
+                priority=prep.priority
+            ).inc(len(prep.reqs))
             _health_metrics().host_device_overlap_ratio.set(ratio)
 
     def _execute_group(self, reqs, verifier, plan) -> None:
@@ -974,6 +996,12 @@ class VerifyQueue(BaseService):
                 "prepared_batches": self._stats["prepared_batches"],
                 "launched_batches": self._stats["launched_batches"],
                 "launched_sigs": self._stats["launched_sigs"],
+                "launched_batches_by_lane": dict(
+                    self._stats["launched_batches_by_lane"]
+                ),
+                "launched_sigs_by_lane": dict(
+                    self._stats["launched_sigs_by_lane"]
+                ),
                 "failed_batches": self._stats["failed_batches"],
                 "pending": {
                     p: len(d) for p, d in self._pending.items()
@@ -1004,6 +1032,20 @@ def install_queue(queue: VerifyQueue | None) -> None:
 
 def _installed() -> VerifyQueue | None:
     return _QUEUE
+
+
+def lane_batch_target(priority: str) -> int | None:
+    """The accumulation target, in signatures, of ``priority``'s
+    micro-batcher on the installed queue — what a caller that feeds a
+    batched lane from its own look-ahead (``light/client.py``) cuts
+    its submissions to, so that each releases at once and fills its
+    launch.  None when no queue is accepting or the lane releases
+    immediately (consensus, prefetch)."""
+    q = _QUEUE
+    if q is None or not q.accepting():
+        return None
+    gate = q._batchers.get(priority)
+    return gate.batch_target if gate is not None else None
 
 
 def speculation_active() -> bool:
@@ -1235,18 +1277,28 @@ def active_submission_lane() -> str | None:
     return lane
 
 
+def submit_speculative(items, priority: str) -> list | None:
+    """Submit ``(pub_key, msg, sig)`` tuples whose verdicts are wanted
+    in the speculative cache, not by the caller: one future per item,
+    for a caller that sleeps until its batch has been answered (the
+    light client's verify-ahead), or None when the queue is down —
+    speculation is never worth an error."""
+    q = _QUEUE
+    if q is None:
+        return None
+    try:
+        return q.submit_many(items, priority)
+    except QueueUnavailable:
+        return None
+
+
 def submit_prefetch(items) -> int:
     """Fire-and-forget prefetch submission (blocksync replay, the
     consensus proposal's last_commit): results land in the speculative
     cache for the verify_commit that follows.  Returns the number of
     requests actually enqueued (0 when the queue is down — prefetch is
     never worth an error)."""
-    q = _QUEUE
-    if q is None:
-        return 0
-    try:
-        q.submit_many(items, PRIORITY_PREFETCH)
-    except QueueUnavailable:
+    if submit_speculative(items, PRIORITY_PREFETCH) is None:
         return 0
     return len(items)
 
@@ -1277,11 +1329,13 @@ __all__ = [
     "cache_key",
     "cached_result",
     "install_queue",
+    "lane_batch_target",
     "prefetch_depth_from_env",
     "record_result",
     "spec_cache_capacity_from_env",
     "speculation_active",
     "submission_lane",
     "submit_prefetch",
+    "submit_speculative",
     "verify_or_fallback",
 ]

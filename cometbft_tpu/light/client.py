@@ -9,12 +9,13 @@ persists trusted blocks.  The 10k-header verification benchmark
 
 from __future__ import annotations
 
-import threading
-from cometbft_tpu.utils import sync as cmtsync
+from collections import deque
+from collections.abc import Callable, Iterable, Iterator
 from dataclasses import dataclass
 from fractions import Fraction
 
-from cometbft_tpu.light.provider import Provider
+from cometbft_tpu.crypto import verify_queue as _vq
+from cometbft_tpu.light.provider import Provider, ProviderError
 from cometbft_tpu.light.store import LightStore
 from cometbft_tpu.light.verifier import (
     DEFAULT_TRUST_LEVEL,
@@ -24,9 +25,12 @@ from cometbft_tpu.light.verifier import (
     verify_adjacent,
 )
 from cometbft_tpu.types.evidence import LightClientAttackEvidence
-from cometbft_tpu.types.light_block import LightBlock
+from cometbft_tpu.types.light_block import LightBlock, LightBlockError
+from cometbft_tpu.types.validation import commit_check_triples
+from cometbft_tpu.utils import sync as cmtsync
 from cometbft_tpu.utils.log import Logger, default_logger
 from cometbft_tpu.utils.time import now_ns
+from cometbft_tpu.utils.trace import TRACER as _tracer
 
 SEQUENTIAL = "sequential"   # client.go:44
 SKIPPING = "skipping"       # client.go:50
@@ -63,6 +67,144 @@ class TrustOptions:
             raise LightClientError("trust height must be positive")
         if len(self.hash) != 32:
             raise LightClientError("trust hash must be 32 bytes")
+
+
+#: what a target's verdict can be besides "verified": the primary had
+#: no such block, the block is malformed, the verifier rejected it,
+#: the client could not place it.  (A detected attack is not a
+#: verdict on one header: it ends the catch-up.)
+_VERDICTS = (
+    ProviderError, LightBlockError, VerificationError, LightClientError,
+)
+
+#: light-lane batches a catch-up keeps fetched and submitted ahead of
+#: the header it is verifying — the queue's own double buffer: one
+#: the launcher holds, one the collector prepares
+_AHEAD_BATCHES = 2
+
+
+class _VerifyAhead:
+    """One catch-up's look-ahead (``Client.verify_light_blocks_at_heights``).
+
+    Fetches the targets in order, up to D ahead of the one being
+    verified, and submits the signatures their commit checks will look
+    at (``commit_check_triples`` to past two thirds of each block's
+    OWN set, which its header binds; with the sets in agreement the
+    trusting check's signatures are among them) to the verify queue's
+    ``light_client`` lane, cut into submissions of exactly the lane's
+    batch target: each releases at once and fills its launch, a
+    header's signatures riding two launches where the cut falls inside
+    it.  Only the last targets' remainder goes out short and waits the
+    lane's deadline.  D = ``_AHEAD_BATCHES`` batch targets over the
+    signatures a header needs (21 for 150 equal validators and the
+    default 1,024), so the caller verifies header n from cached
+    verdicts while the queue prepares and launches the batch that
+    holds n + D.
+
+    Nothing here judges a header: the cache holds positive verdicts
+    only, so a signature that failed, a queue that stopped, a commit
+    that does not line up all leave the verifier's strict path to
+    decide as it would without a look-ahead.  ``target`` None: no
+    look-ahead, the targets are fetched one at a time."""
+
+    def __init__(self, client: "Client", heights: list[int],
+                 target: int | None) -> None:
+        self._client = client
+        self._todo = deque(heights)
+        #: fetched and not yet handed out:
+        #: (height, LightBlock | the fetch's exception, already trusted)
+        self._fetched: deque[tuple] = deque()
+        self._target = target
+        #: signatures the last header built needed; sizes D
+        self._per_header: int | None = None
+        #: triples built and not yet submitted, the first of them at
+        #: ``_base`` in the catch-up's stream of triples
+        self._triples: list[tuple] = []
+        self._base = 0
+        #: (stream index of a header's last triple, its height), for
+        #: the headers whose last triple is not submitted yet
+        self._marks: deque[tuple[int, int]] = deque()
+        self._last_future: dict[int, _vq.VerifyFuture] = {}
+
+    def next(self) -> tuple:
+        """-> (height, LightBlock | exception, already trusted, the
+        future of the last signature submitted for it or None)."""
+        self._top_up()
+        height, got, trusted = self._fetched.popleft()
+        return height, got, trusted, self._last_future.pop(height, None)
+
+    def _depth(self) -> int:
+        """D: how many targets may be fetched and not yet handed out."""
+        if self._target is None or self._per_header is None:
+            return 1  # no look-ahead; or the first header, which sizes it
+        return -(-_AHEAD_BATCHES * self._target // self._per_header)
+
+    def _top_up(self) -> None:
+        # twice at the start: the first header's signature count sets D
+        while self._todo and len(self._fetched) < self._depth():
+            fresh = self._fetch_to_depth()
+            if self._target is not None and fresh:
+                self._submit_ahead(fresh)
+
+    def _fetch_to_depth(self) -> list[LightBlock]:
+        """-> the light blocks fetched now that still need verifying."""
+        fresh = []
+        while self._todo and len(self._fetched) < self._depth():
+            height = self._todo.popleft()
+            got = self._client.store.get(height)
+            trusted = got is not None
+            if not trusted:
+                try:
+                    got = self._client._fetch(height)
+                except _VERDICTS as exc:
+                    got = exc
+                else:
+                    fresh.append(got)
+            self._fetched.append((height, got, trusted))
+        return fresh
+
+    def _submit_ahead(self, lbs: list[LightBlock]) -> None:
+        with _tracer.span(
+            "light/verify_ahead", cat="light", headers=len(lbs),
+        ) as sp:
+            built = 0
+            for lb in lbs:
+                vals = lb.validator_set
+                triples = commit_check_triples(
+                    self._client.chain_id, vals, lb.commit,
+                    vals.total_voting_power() * 2 // 3,
+                )
+                self._per_header = max(1, len(triples or ()))
+                if not triples:
+                    continue
+                built += len(triples)
+                self._triples.extend(triples)
+                self._marks.append(
+                    (self._base + len(self._triples) - 1, lb.height)
+                )
+            while self._target and len(self._triples) >= self._target:
+                self._submit(self._target)
+            if not self._todo:
+                self._submit(len(self._triples))
+            sp.set(sigs=built)
+
+    def _submit(self, n: int) -> None:
+        if not n or self._target is None:
+            return
+        chunk, self._triples = self._triples[:n], self._triples[n:]
+        futures = _vq.submit_speculative(chunk, _vq.PRIORITY_LIGHT)
+        if futures is None:
+            # the queue went away: the rest of the catch-up verifies
+            # on the strict path, one header at a time
+            self._target = None
+            self._triples.clear()
+            self._marks.clear()
+            return
+        end = self._base + n
+        while self._marks and self._marks[0][0] < end:
+            index, height = self._marks.popleft()
+            self._last_future[height] = futures[index - self._base]
+        self._base = end
 
 
 class Client:
@@ -151,22 +293,100 @@ class Client:
     def verify_light_block_at_height(
         self, height: int, now: int | None = None
     ) -> LightBlock:
-        """(client.go:473 VerifyLightBlockAtHeight)"""
-        if height <= 0:
+        """(client.go:473 VerifyLightBlockAtHeight) — a catch-up of
+        one target: nothing is ahead of it, so nothing is submitted
+        ahead and both commit checks run where they always have."""
+        ((_, lb, err),) = self.verify_light_blocks_at_heights([height], now)
+        if err is not None:
+            raise err
+        return lb
+
+    def verify_light_blocks_at_heights(
+        self,
+        heights: Iterable[int],
+        now: int | Callable[[LightBlock], int] | None = None,
+    ) -> Iterator[tuple[int, LightBlock | None, Exception | None]]:
+        """Verify a known, strictly ascending list of heights — a
+        relayer's or a wallet's catch-up — and yield, in order, one
+        ``(height, light block, None)`` for each target verified and
+        trusted, or ``(height, None, error)`` for each rejected: a
+        rejected target is never stored, and the catch-up goes on
+        from the last trusted header to the next target.  With more
+        than one target and a verify queue running, the targets are
+        fetched and their commits' signatures submitted to the
+        queue's ``light_client`` lane ahead of their verification
+        (:class:`_VerifyAhead`); each target is then verified exactly
+        as ``verify_light_block_at_height`` verifies it.
+
+        ``now`` is the time each target is verified at: None for the
+        clock, an integer for all targets, or a function of the
+        fetched light block.  Nothing runs until the iterator is
+        advanced, and it may be left unfinished.  A detected attack
+        (``ErrLightClientAttack``) ends the catch-up by raising."""
+        heights = list(heights)
+        if any(h <= 0 for h in heights):
             raise LightClientError("height must be positive")
-        now = now_ns() if now is None else now
-        with self._mtx:
-            existing = self.store.get(height)
-            if existing is not None:
-                return existing
+        if any(b <= a for a, b in zip(heights, heights[1:])):
+            raise LightClientError("heights must be strictly ascending")
+        if callable(now):
+            now_of = now
+        else:
+            def now_of(_lb):
+                return now_ns() if now is None else now
+        return self._catch_up(heights, now_of)
+
+    def _catch_up(
+        self, heights: list[int], now_of: Callable[[LightBlock], int]
+    ) -> Iterator[tuple]:
+        ahead = _VerifyAhead(
+            self, heights,
+            _vq.lane_batch_target(_vq.PRIORITY_LIGHT)
+            if len(heights) > 1 else None,
+        )
+        for _ in heights:
+            with _tracer.span("light/verify", cat="light") as root:
+                height, got, trusted, future = ahead.next()
+                root.set(height=height)
+                lb = err = None
+                try:
+                    if isinstance(got, Exception):
+                        raise got
+                    if not trusted:
+                        self._await_ahead(future)
+                        with self._mtx:
+                            self._verify_light_block(
+                                got, now_of(got), root
+                            )
+                    lb = got
+                except ErrLightClientAttack:
+                    raise
+                except _VERDICTS as exc:
+                    err = exc
+            yield height, lb, err
+
+    def _fetch(self, height: int) -> LightBlock:
+        with _tracer.span("light/fetch", cat="light", height=height):
             lb = self.primary.light_block(height)
             lb.validate_basic(self.chain_id)
             if lb.height != height:
                 raise LightClientError(
                     f"primary returned height {lb.height}, wanted {height}"
                 )
-            self._verify_light_block(lb, now)
             return lb
+
+    @staticmethod
+    def _await_ahead(future) -> None:
+        """Asleep until the queue has answered for the last signature
+        submitted ahead for this target.  Whatever the answer, or
+        none (the queue stopped, the batch failed), the verifier
+        decides: it finds positive verdicts cached or verifies."""
+        if future is None:
+            return
+        with _tracer.span("light/ahead_wait", cat="light"):
+            try:
+                future.result()
+            except _vq.QueueUnavailable:
+                pass
 
     def verify_header(self, header, now: int | None = None) -> LightBlock:
         """Verify a caller-supplied header by fetching its light block
@@ -180,16 +400,24 @@ class Client:
 
     # -- verification strategies -----------------------------------------
 
-    def _verify_light_block(self, new: LightBlock, now: int) -> None:
-        trusted = self.store.light_block_before(new.height)
+    def _verify_light_block(self, new: LightBlock, now: int, root) -> None:
+        """``root``: the target's ``light/verify`` span."""
+        with _tracer.span("light/store", cat="light"):
+            trusted = self.store.light_block_before(new.height)
         if trusted is None:
             # target below our first trusted block: backwards verification
             first = self.store.first()
             if first is None:
                 raise LightClientError("store has no trust root")
+            root.set(mode="backwards", trusted_height=first.height)
             self._verify_backwards(first, new)
             self._finalize_verified(new)
             return
+        root.set(
+            mode="adjacent" if new.height == trusted.height + 1
+            else "non_adjacent",
+            trusted_height=trusted.height,
+        )
         if self.mode == SEQUENTIAL:
             self._verify_sequential(trusted, new, now)
         else:
@@ -197,10 +425,12 @@ class Client:
         self._finalize_verified(new)
 
     def _finalize_verified(self, new: LightBlock) -> None:
-        self._compare_with_witnesses(new)
-        self.store.save(new)
-        if self.store.size() > self.pruning_size:
-            self.store.prune(self.pruning_size)
+        with _tracer.span("light/witness", cat="light"):
+            self._compare_with_witnesses(new)
+        with _tracer.span("light/store", cat="light"):
+            self.store.save(new)
+            if self.store.size() > self.pruning_size:
+                self.store.prune(self.pruning_size)
 
     def _verify_sequential(
         self, trusted: LightBlock, new: LightBlock, now: int

@@ -40,7 +40,10 @@ from collections import OrderedDict
 from cometbft_tpu.metrics import light_metrics as _light_metrics
 from cometbft_tpu.crypto import verify_queue as _vq
 from cometbft_tpu.types.block import BlockID
-from cometbft_tpu.types.validation import verify_commit_light
+from cometbft_tpu.types.validation import (
+    commit_check_triples,
+    verify_commit_light,
+)
 from cometbft_tpu.utils import sync as cmtsync
 from cometbft_tpu.utils.flight import FLIGHT
 from cometbft_tpu.utils.flight import ring_size_from_env as _int_env
@@ -283,8 +286,9 @@ class LightHeaderServer:
         waits for the coalesced launch; verdicts land in the
         speculative cache, so phase 2's ``verify_commit_light`` is
         cache hits).  Well-formedness is NOT judged here — a
-        malformed commit just primes less and phase 2 reports the
-        precise error.  Aggregate-covered signatures are skipped:
+        commit that does not line up with its set primes nothing
+        (``commit_check_triples``) and phase 2 reports the precise
+        error.  Aggregate-covered signatures are skipped:
         their proof is the commit-level pairing, cached under its own
         key at first verification.  Primes every commit-flag
         signature where phase 2's early-break stops at +2/3 — a
@@ -293,21 +297,11 @@ class LightHeaderServer:
             return
         items = []
         for lb in lbs:
-            commit = lb.commit
-            vals = lb.validator_set
-            if commit.size() != len(vals):
-                continue
-            for i, cs in enumerate(commit.signatures):
-                if not cs.is_commit() or commit.is_aggregated(i):
-                    continue
-                val = vals.get_by_index(i)
-                if val is None or val.address != cs.validator_address:
-                    break  # malformed: phase 2 raises the real error
-                items.append((
-                    val.pub_key,
-                    commit.vote_sign_bytes(self.chain_id, i),
-                    cs.signature,
-                ))
+            items.extend(
+                commit_check_triples(
+                    self.chain_id, lb.validator_set, lb.commit
+                ) or ()
+            )
         if items:
             _vq.light_verify_or_fallback(items)
 

@@ -19,6 +19,8 @@ from fractions import Fraction
 from cometbft_tpu.types.block import BlockID
 from cometbft_tpu.types.light_block import LightBlock
 from cometbft_tpu.types.validation import (
+    CommitError,
+    NotEnoughVotingPower,
     verify_commit_light,
     verify_commit_light_trusting,
 )
@@ -133,8 +135,14 @@ def verify_non_adjacent(
             trust_level,
             signer_vals=untrusted.validator_set,
         )
-    except Exception as exc:
+    except NotEnoughVotingPower as exc:
+        # the one failure the client answers by bisecting
+        # (verifier.go: only ErrNotEnoughVotingPowerSigned maps to
+        # ErrNewValSetCantBeTrusted)
         raise ErrNewValSetCantBeTrusted(str(exc)) from exc
+    except CommitError as exc:
+        # a wrong signature, a double vote: no midpoint can cure it
+        raise ErrInvalidHeader(f"invalid commit: {exc}") from exc
     _verify_self_commit(untrusted, chain_id)
 
 

@@ -656,6 +656,8 @@ class CryptoMetrics:
             self.jit_cache_misses = self.guard_trips = _NOP
             self.verify_queue_depth = self.verify_queue_inflight = _NOP
             self.verify_queue_submitted = _NOP
+            self.verify_queue_launched = _NOP
+            self.verify_queue_launched_sigs = _NOP
             self.verify_queue_batch_size = _NOP
             self.verify_queue_spec_cache = _NOP
             self.verify_queue_prefetch_depth = _NOP
@@ -812,6 +814,19 @@ class CryptoMetrics:
             "Verification requests submitted to the verify queue, by "
             "priority lane (consensus | prefetch | light_client | "
             "ingest).",
+            labels=("priority",),
+        )
+        self.verify_queue_launched = reg.counter(
+            s, "verify_queue_launched",
+            "Buffers the verify queue's launcher has executed, by "
+            "priority lane.",
+            labels=("priority",),
+        )
+        self.verify_queue_launched_sigs = reg.counter(
+            s, "verify_queue_launched_sigs",
+            "Signatures in the buffers the verify queue's launcher has "
+            "executed, by priority lane; over verify_queue_launched, "
+            "the lane's mean launch size.",
             labels=("priority",),
         )
         self.verify_queue_batch_size = reg.histogram(

@@ -28,7 +28,14 @@ def commit_verify_step(pub, sig, msg, msglen):
     Shapes: pub (32, ...) u8, sig (64, ...) u8, msg (128, ...) u8,
     msglen (...,) i32 -> (...,) bool. Trailing batch dims are free:
     (V,) for one commit of V validators, (H, V) for H headers x V
-    validators (the light-client / blocksync batch shapes).
+    validators (the light-client / blocksync batch shapes).  On the
+    served path the (H, V) batch is formed by the verify queue, flat:
+    blocksync's prefetch submits H commits' triples as one buffer, and
+    the light client's verify-ahead (``light/client.py``
+    ``_VerifyAhead``) cuts the stream of its next targets' triples into
+    buffers of the ``light_client`` lane's target, so H headers' checks
+    ride one keyed launch of H x V' lanes (V' = the votes to past two
+    thirds) without the step ever seeing two batch axes.
     """
     return verify_kernel(pub, sig, msg, msglen, nblocks=NBLOCKS)
 

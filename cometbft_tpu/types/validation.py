@@ -217,6 +217,43 @@ def _collect_entries(
     return entries, agg_pubs
 
 
+def commit_check_triples(
+    chain_id: str,
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int | None = None,
+) -> list | None:
+    """The ``(pub_key, sign_bytes, signature)`` triples a by-index check
+    of ``commit`` against ``vals`` will look at, in commit order: its
+    COMMIT-flag votes, up to and including the first that takes the
+    tallied power past ``voting_power_needed`` (``None``: all of them)
+    — what ``verify_commit_light`` batches, for the callers that
+    submit a commit's signatures to the verify queue ahead of its
+    check (blocksync prefetch, the light serving plane, the light
+    client's verify-ahead).  None when the commit does not line up
+    with ``vals`` (the set rotated, a malformed commit: never guess —
+    the check itself reports the precise error).  Votes covered by a
+    commit-level BLS aggregate carry no per-signature proof and are
+    skipped."""
+    if commit is None or commit.size() != len(vals):
+        return None
+    items = []
+    power = 0
+    for i, cs in enumerate(commit.signatures):
+        if not cs.is_commit() or commit.is_aggregated(i):
+            continue
+        val = vals.get_by_index(i)
+        if val is None or val.address != cs.validator_address:
+            return None
+        items.append((
+            val.pub_key, commit.vote_sign_bytes(chain_id, i), cs.signature,
+        ))
+        power += val.voting_power
+        if voting_power_needed is not None and power > voting_power_needed:
+            break
+    return items
+
+
 def _crypto_pass(
     chain_id: str, vals: ValidatorSet, commit: Commit,
     entries: list[_Entry], agg_pubs: list, groups: list[list[_Entry]],

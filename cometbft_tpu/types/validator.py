@@ -24,15 +24,22 @@ class Validator:
         return self.pub_key.address()
 
     def simple_encode(self) -> bytes:
-        """SimpleValidator encoding for the set hash
-        (types/validator.go Validator.Bytes): pubkey + power."""
+        """SimpleValidator{pub_key, voting_power} for the set hash
+        (types/validator.go Validator.Bytes), the key as upstream's
+        ``crypto.PublicKey`` oneof — the field number is the key's
+        type — so that a set's hash here is the hash a CometBFT header
+        carries for it (``benchmark/reference_light.py`` holds the
+        light client to the published encoding)."""
         w = ProtoWriter()
         pk = ProtoWriter()
-        pk.string(1, self.pub_key.type())
-        pk.bytes_(2, self.pub_key.bytes())
+        pk.bytes_(_PUBLIC_KEY_FIELD[self.pub_key.type()], self.pub_key.bytes())
         w.message(1, pk.finish())
         w.varint(2, self.voting_power)
         return w.finish()
+
+
+#: proto/cometbft/crypto/v1/keys.proto PublicKey.sum, by key type
+_PUBLIC_KEY_FIELD = {"ed25519": 1, "secp256k1": 2, "bls12_381": 3}
 
 
 class ValidatorSet:

@@ -131,6 +131,8 @@ def test_table_sized_movers_finds_the_window_major_relayout():
     [
         pytest.param(256, 256, 8, id="keyed-8bit-150val"),
         pytest.param(1024, 1024, 4, id="keyed-4bit-1000val"),
+        # the light lane's batch: 1,024 signatures over a 150-key set
+        pytest.param(1024, 256, 8, id="keyed-8bit-150val-light-lane"),
     ],
 )
 def test_keyed_verify_compiles_for_one_v5e(

@@ -691,6 +691,120 @@ class TestJitguardSteadyState:
             jitguard.reset()
 
 
+class TestBatchedLaneRelease:
+    """What the light client's verify-ahead leans on (ISSUE 28): a
+    batched lane that releases by size launches exactly its target,
+    the lane's own counts, the target a caller cuts its submissions
+    to, and the one helper for the triples a commit's check reads."""
+
+    def test_a_release_by_size_takes_exactly_the_target(self, queue_guard):
+        """What is over the target waits for the next buffer instead
+        of padding this launch to the next power-of-two bucket."""
+        sizes = []
+
+        def launch(items):
+            sizes.append(len(items))
+            return [True] * len(items)
+
+        q = vq.VerifyQueue(
+            launch=launch, light_batch=4, light_wait_ms=60_000,
+            use_cache=False,
+        )
+        q.start()
+        vq.install_queue(q)
+        futs = q.submit_many(_items(10, tag=b"cut"), vq.PRIORITY_LIGHT)
+        assert all(f.result(30) for f in futs[:8])
+        _wait(lambda: q.stats()["launched_batches"] == 2, msg="2 launches")
+        stats = q.stats()
+        assert sizes == [4, 4]
+        assert stats["pending"]["light_client"] == 2  # parked, own stamps
+        assert stats["launched_batches_by_lane"] == {
+            "consensus": 0, "prefetch": 0, "light_client": 2, "ingest": 0,
+        }
+        assert stats["launched_sigs_by_lane"]["light_client"] == 8
+        assert stats["launched_sigs"] == 8
+        q.stop()  # the drain releases the two that were parked
+        assert all(f.result(30) for f in futs) and sizes == [4, 4, 2]
+
+    @pytest.mark.parametrize(
+        "pending,taken", [(3, 3), (4, 4), (5, 4), (4096, 4)],
+    )
+    def test_the_gate_cuts_a_release_at_its_target(self, pending, taken):
+        assert vq._LaneBatcher(4, 10).cut(pending) == taken
+
+    def test_the_lanes_launches_reach_the_metrics(
+        self, live_metrics, queue_guard
+    ):
+        cm, _ = live_metrics
+        q = vq.VerifyQueue(
+            launch=lambda items: [True] * len(items), use_cache=False,
+        )
+        q.start()
+        futs = q.submit_many(_items(5, tag=b"lm"), vq.PRIORITY_PREFETCH)
+        assert all(f.result(30) for f in futs)
+        _wait(lambda: q.stats()["launched_batches"] == 1, msg="launch")
+        lane = {"priority": "prefetch"}
+        assert cm.verify_queue_launched.labels(**lane).get() == 1
+        assert cm.verify_queue_launched_sigs.labels(**lane).get() == 5
+        q.stop()
+
+    def test_lane_batch_target_is_the_running_queues(self, queue_guard):
+        vq.install_queue(None)
+        assert vq.lane_batch_target(vq.PRIORITY_LIGHT) is None
+        q = vq.VerifyQueue(light_batch=48, checktx_batch=7)
+        q.start()
+        vq.install_queue(q)
+        assert vq.lane_batch_target(vq.PRIORITY_LIGHT) == 48
+        assert vq.lane_batch_target(vq.PRIORITY_INGEST) == 7
+        assert vq.lane_batch_target(vq.PRIORITY_PREFETCH) is None
+        q.stop()
+        assert vq.lane_batch_target(vq.PRIORITY_LIGHT) is None
+
+    def test_submit_speculative_keeps_the_futures(self, queue_guard):
+        vq.install_queue(None)
+        assert vq.submit_speculative(_items(2), vq.PRIORITY_LIGHT) is None
+        q = vq.VerifyQueue(light_batch=2, light_wait_ms=60_000)
+        q.start()
+        vq.install_queue(q)
+        futs = vq.submit_speculative(_items(2), vq.PRIORITY_LIGHT)
+        assert [f.result(30) for f in futs] == [True, True]
+        q.stop()
+        assert vq.submit_speculative(_items(2), vq.PRIORITY_LIGHT) is None
+
+    @pytest.mark.parametrize(
+        "needed,count",
+        [(None, 6), (6 * 10 * 2 // 3, 5), (6 * 10 // 3, 3), (0, 1)],
+    )
+    def test_commit_check_triples_stop_past_the_power(self, needed, count):
+        """The triples are the leading COMMIT-flag votes, to the first
+        that takes the tally past ``needed``: what the check's own
+        early break collects."""
+        from cometbft_tpu.types.validation import commit_check_triples
+        from cometbft_tpu.types.validator import Validator, ValidatorSet
+        from tests.test_light_serve import CHAIN, make_chain
+
+        vals, blocks = make_chain(2)  # 6 validators of power 10
+        lb = blocks[1]
+        got = commit_check_triples(CHAIN, vals, lb.commit, needed)
+        assert len(got) == count
+        assert got == [
+            (vals.get_by_index(i).pub_key,
+             lb.commit.vote_sign_bytes(CHAIN, i),
+             lb.commit.signatures[i].signature)
+            for i in range(count)
+        ]
+        # a set the commit does not line up with: never guessed at
+        rotated = ValidatorSet([
+            Validator(k.pub_key(), 10)
+            for k in (ed.priv_key_from_secret(b"rot-%d" % i)
+                      for i in range(6))
+        ])
+        assert commit_check_triples(CHAIN, rotated, lb.commit) is None
+        assert commit_check_triples(
+            CHAIN, ValidatorSet(vals.validators[:5]), lb.commit
+        ) is None
+
+
 class TestEnvValidation:
     def test_prefetch_depth_default_and_validation(self, monkeypatch):
         monkeypatch.delenv("CMT_TPU_VERIFY_PREFETCH", raising=False)
