@@ -403,7 +403,10 @@ class Client:
     def _verify_light_block(self, new: LightBlock, now: int, root) -> None:
         """``root``: the target's ``light/verify`` span."""
         with _tracer.span("light/store", cat="light"):
+            served = self.store.stats()["anchor_memory"]
             trusted = self.store.light_block_before(new.height)
+            served = self.store.stats()["anchor_memory"] - served
+        root.set(anchor="memory" if served else "store")
         if trusted is None:
             # target below our first trusted block: backwards verification
             first = self.store.first()
