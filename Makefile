@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: test test-slow test-deadlock test-race test-e2e bench bench-all bench-micro native metrics-lint lint lockcheck jitcheck determcheck hotpathcheck envcheck trustcheck determinism-smoke test-jitguard wire-smoke flight-smoke mesh-smoke health-smoke pipeline-smoke chaos-smoke ingest-smoke light-smoke route-smoke fleet-smoke attr-smoke wan-smoke byz-smoke churn-smoke perf-gate perf-ledger
+.PHONY: test test-slow test-deadlock test-race test-e2e bench bench-all bench-micro native metrics-lint lint lockcheck jitcheck determcheck hotpathcheck envcheck trustcheck determinism-smoke test-jitguard wire-smoke flight-smoke mesh-smoke health-smoke pipeline-smoke chaos-smoke ingest-smoke light-smoke fleet-smoke attr-smoke wan-smoke byz-smoke churn-smoke perf-gate perf-ledger
 
 # default gate: soak-tier tests (@pytest.mark.slow — the 10k-sig mesh
 # torture, chunk-variant compile matrix, 150-key rotation build,
@@ -18,7 +18,7 @@ PY ?= python
 # tests/test_jitcheck.py + tests/test_determcheck.py +
 # tests/test_hotpathcheck.py + tests/test_envcheck.py +
 # tests/test_trustcheck.py).
-test: metrics-lint determinism-smoke flight-smoke mesh-smoke health-smoke pipeline-smoke chaos-smoke ingest-smoke light-smoke route-smoke fleet-smoke attr-smoke wan-smoke byz-smoke churn-smoke perf-gate
+test: metrics-lint determinism-smoke flight-smoke mesh-smoke health-smoke pipeline-smoke chaos-smoke ingest-smoke light-smoke fleet-smoke attr-smoke wan-smoke byz-smoke churn-smoke perf-gate
 	$(PY) -m pytest tests/ -x -q
 
 # everything, including the soak tier (~1 h single-core)
@@ -228,19 +228,6 @@ ingest-smoke:
 light-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_light_serve.py \
 		-k "LightSmoke" -q
-
-# route smoke: the shape-aware routing proof (ISSUE 14) — a
-# mixed-shape drive (interleaved 2-sig and 2048-sig batches through
-# production plan()/execute() with a seeded cost table) must show the
-# two `crypto_dispatch_route` buckets landing on DIFFERENT tiers on
-# this box: the small checks on host (the seeded r05 contradiction,
-# rerouted), the wide commits on the device tier — with exact
-# verdicts throughout.  Tier-1 runs the full tests/test_route.py
-# suite too; `make test` gates on this target alongside the other
-# smokes
-route-smoke:
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_route.py \
-		-k "RouteSmoke" -q
 
 # fleet smoke: the cross-node SLO proof (ISSUE 15) — a 4-node
 # SUBPROCESS localnet (one node mixed-version: CMT_TPU_TRACE_CTX=0)

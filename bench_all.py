@@ -361,27 +361,7 @@ def main() -> None:
     )
 
     # forced-device variant: kernel+link progress stays visible even
-    # when the production router prefers the CPU at this size.  The
-    # cost router (ISSUE 14) is pinned OFF for this row — its whole
-    # point is rerouting a slower device tier to host, which would
-    # turn this row into a second host measurement and starve the
-    # ledger of the device trajectory (and of the very per-bucket
-    # device estimates the router seeds from)
-    from cometbft_tpu.crypto import dispatch as _dispatch
-
-    def forced_device(fn):
-        prior = os.environ.get("CMT_TPU_ROUTE")
-        os.environ["CMT_TPU_ROUTE"] = "0"
-        _dispatch.reset_for_tests()
-        try:
-            return fn()
-        finally:
-            if prior is None:
-                os.environ.pop("CMT_TPU_ROUTE", None)
-            else:
-                os.environ["CMT_TPU_ROUTE"] = prior
-            _dispatch.reset_for_tests()
-
+    # when the production threshold keeps this size on the CPU
     def micro_device():
         bv = TpuBatchVerifier(device_min_batch=1)
         for m, s in zip(msgs64, sigs64):
@@ -389,7 +369,7 @@ def main() -> None:
         ok, _ = bv.verify()
         assert ok
 
-    dt = forced_device(lambda: timed(micro_device))
+    dt = timed(micro_device)
     record(
         "micro_64sig_device", 64 / dt, "sigs/sec",
         latency_ms=round(dt * 1e3, 2),
@@ -411,13 +391,11 @@ def main() -> None:
         "verify_commit_150", dt * 1e3, "ms",
         sigs_per_sec=round(150 / dt, 1),
     )
-    # device-forced variant: kernel+link progress stays visible even
-    # while the production router prefers the CPU at this size (cost
-    # router pinned off, same rationale as micro_64sig_device)
+    # device-forced variant (same rationale as micro_64sig_device)
     prior = os.environ.get("CMT_TPU_DEVICE_MIN_BATCH")
     os.environ["CMT_TPU_DEVICE_MIN_BATCH"] = "1"
     try:
-        dt = forced_device(lambda: timed(vc150))
+        dt = timed(vc150)
         record(
             "verify_commit_150_device", dt * 1e3, "ms",
             sigs_per_sec=round(150 / dt, 1),
@@ -608,90 +586,6 @@ def main() -> None:
     queue_config(
         "blocksync_replay_1kval_pipelined", vals1k, commit1k, n5
     )
-
-    # ---- config 4c: dispatch_shape_mix — static walk vs cost-ordered
-    # routing on the SAME mixed-shape workload (ISSUE 14).  Interleaved
-    # 64-sig micro-batches and 150-sig commit batches, device-forced
-    # (device_min_batch=1, the *_device convention) so the static walk
-    # pays the device tier for every batch; the cost arm seeds the
-    # TierCostModel from THIS run's ledger rows (configs 1/2/4b above
-    # appended host + device measurements at both shape buckets
-    # moments ago) and routes each shape by measured throughput.  On a
-    # box where the ledger contradicts the static order (r05: host
-    # beats the device path) the cost arm reroutes and wins; on a box
-    # where the device genuinely leads, the arms converge — parity,
-    # not regression.  Both rows land in the ledger; perfdiff gates
-    # the cost row run over run.
-    def shape_mix_batches():
-        wide_pks = [
-            vals150.get_by_index(i).pub_key for i in range(150)
-        ]
-        wide_msgs = [
-            commit150.vote_sign_bytes(CHAIN_ID, i) for i in range(150)
-        ]
-        wide_sigs = [cs.signature for cs in commit150.signatures]
-        batches = []
-        for r in range(4):
-            small = TpuBatchVerifier(device_min_batch=1)
-            for m, s in zip(msgs64, sigs64):
-                small.add(pub, m, s)
-            wide = TpuBatchVerifier(device_min_batch=1)
-            for pk, m, s in zip(wide_pks, wide_msgs, wide_sigs):
-                wide.add(pk, m, s)
-            batches += [small, wide]
-        return batches
-
-    def shape_mix_arm(route_on: bool):
-        os.environ["CMT_TPU_ROUTE"] = "1" if route_on else "0"
-        _dispatch.reset_for_tests()  # fresh ladder + (re-)seeded model
-        batches = shape_mix_batches()  # signing outside the clock
-        nsigs = sum(len(b._pubs) for b in batches)
-        tiers_used: dict[str, int] = {}
-        t0 = time.perf_counter()
-        for bv in batches:
-            ok, _ = bv.verify()
-            assert ok, "shape-mix sigs must verify"
-            tiers_used[bv._last_tier] = (
-                tiers_used.get(bv._last_tier, 0) + 1
-            )
-        dt = time.perf_counter() - t0
-        snap = _dispatch.LADDER.cost_snapshot()
-        reorders = sum(o["reorders"] for o in snap["orders"])
-        return nsigs / dt, tiers_used, reorders
-
-    prior_route = os.environ.get("CMT_TPU_ROUTE")
-    try:
-        static_rate, static_tiers, _ = shape_mix_arm(False)
-        record(
-            "dispatch_shape_mix_static", static_rate, "sigs/sec",
-            shapes=[64, 150], batches_per_shape=4,
-            tiers_used=static_tiers, route="static",
-            # a mixed-workload rate is not single-batch tier
-            # throughput: never a routing seed, and dispatch_tier=None
-            # suppresses record()'s majority-tier auto-stamp so the
-            # tier-level measured_tier_throughput map (last row per
-            # tier wins) keeps the tier's genuine measurement instead
-            # of this interleaved aggregate
-            route_seed=False,
-            dispatch_tier=None,
-        )
-        cost_rate, cost_tiers, reorders = shape_mix_arm(True)
-        record(
-            "dispatch_shape_mix", cost_rate, "sigs/sec",
-            shapes=[64, 150], batches_per_shape=4,
-            tiers_used=cost_tiers, route="cost",
-            route_reorders=reorders,
-            baseline="dispatch_shape_mix_static",
-            speedup_vs_static=round(cost_rate / static_rate, 2),
-            route_seed=False,
-            dispatch_tier=None,
-        )
-    finally:
-        if prior_route is None:
-            os.environ.pop("CMT_TPU_ROUTE", None)
-        else:
-            os.environ["CMT_TPU_ROUTE"] = prior_route
-        _dispatch.reset_for_tests()
 
     # ---- configs 6a-c: device-batched CheckTx admission (ISSUE 10) ---
     # The ingest plane end to end: signed-envelope txs through

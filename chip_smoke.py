@@ -24,8 +24,6 @@ logs stay on stderr):
 - ``replay1k``  1,000 validators: 32 blocks' commits the way blocksync
                 replays them (prefetch lane, then
                 ``verify_commit_light``), two tampered.
-- ``as_shipped`` 8 commits of each width with the cost router at its
-                default; prints the route table (not asserted on tier).
 
 Verdicts and first-bad indices must equal the pure-Python ZIP-215
 oracle.  The LAST line of stdout is exactly
@@ -746,47 +744,6 @@ def phase_replay1k(
     }
 
 
-def phase_as_shipped(compiles: CompileLog, seed: int,
-                     widths=(150, 1000), n_commits: int = 8) -> dict:
-    """The cost router at its default (``CMT_TPU_ROUTE`` unset): what a
-    node does as shipped.  Reported, not asserted on tier — a host-only
-    table here is a finding, not a failure."""
-    from cometbft_tpu.crypto import dispatch
-    from cometbft_tpu.types.validation import verify_commit
-
-    chain_id = f"chip-smoke-{seed}"
-    pinned = os.environ.pop("CMT_TPU_ROUTE", None)
-    # only the cost model is rebuilt (from the env as it now is): the
-    # node is still verifying its own commits through the same ladder
-    dispatch.LADDER.rebuild_cost_model()
-    try:
-        p0 = Probe(compiles)
-        for n_vals in widths:
-            vals, keys = make_validators(seed, n_vals)
-            base = 3_000_000 + seed * 10_000 + n_vals
-            for j in range(n_commits):
-                bid, commit = make_commit(chain_id, keys, base + j * 2000)
-                check(
-                    run_verify(verify_commit, chain_id, vals, bid, commit)
-                    is None,
-                    f"as_shipped: valid {n_vals}-validator commit rejected",
-                )
-        d = Probe(compiles).delta(p0)
-        cost = dispatch.LADDER.cost_snapshot()
-    finally:
-        if pinned is not None:
-            os.environ["CMT_TPU_ROUTE"] = pinned
-        dispatch.LADDER.rebuild_cost_model()
-    return {
-        "phase": "as_shipped", "ok": True, "widths": list(widths),
-        "commits_each": n_commits, "seconds": d["seconds"],
-        "router_enabled": cost["enabled"], "router_seeded": cost["seeded"],
-        "route_table": cost["table"], "route_orders": cost["orders"],
-        "batches": d["batches"], "decisions": d["decisions"],
-        "transitions": d["transitions"], "compiles": d["compiles"],
-    }
-
-
 def phase_mesh(
     compiles: CompileLog, seed: int, platform: str, n_devices: int = 4,
     n_vals: int = 1000, n_commits: int = 8, oracle_sample: int = 256,
@@ -901,9 +858,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4 runs ONLY the multi-chip phase")
     args = ap.parse_args(argv)
-    # the static ladder order for the asserted phases; said on the
-    # first line.  Set before the package is imported.
-    os.environ["CMT_TPU_ROUTE"] = "0"
     import jax
 
     devices = jax.devices()
@@ -937,9 +891,6 @@ def main(argv: list[str] | None = None) -> int:
         return 1
     emit({
         "phase": "start", "seed": args.seed, "chips": args.chips,
-        "route": ("CMT_TPU_ROUTE=0 set by this script: static ladder "
-                  "order for the asserted phases; as_shipped runs "
-                  "with it unset"),
         "versions": {
             "jax": jax.__version__,
             "jaxlib": importlib.metadata.version("jaxlib"),
@@ -965,7 +916,6 @@ def main(argv: list[str] | None = None) -> int:
             emit(line)
             emit(phase_commit150(compiles, args.seed, platform))
             emit(phase_replay1k(compiles, args.seed, platform))
-            emit(phase_as_shipped(compiles, args.seed))
     except Exception as exc:  # noqa: BLE001 — report, stop the node, fail
         import traceback
 

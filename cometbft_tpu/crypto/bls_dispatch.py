@@ -233,27 +233,12 @@ class BlsLadderVerifier(BatchVerifier):
         _crypto_metrics().dispatch_decisions.labels(
             route="bls", reason=plan.mode
         ).inc()
-        # cost-ordered walk (ISSUE 14): the BLS tiers self-place
-        # through the SAME shape-bucket cost model the device tiers
-        # use — zero BLS-specific routing code.  Aggregates offer no
-        # host rung (host == python for a pairing-product), so only
-        # the admissible native tier is ordered; batch mode orders
-        # native against the pure-RLC host rung by measured
-        # throughput for this batch's shape.
+        # aggregates offer no host rung (host == python for a
+        # pairing-product); a batch has the pure-RLC host rung
         if plan.mode == "aggregate":
-            walk = ladder.route(
-                admissible, plan.n, add_host=False,
-                family=_failover.ROUTE_FAMILY_BLS_AGG,
-            )
-            if not walk:
-                # floor-only plan: still one dispatch_route sample
-                ladder.note_route(_failover.FLOOR_TIER, plan.n)
-            plan.tiers = walk + [_failover.FLOOR_TIER]
+            plan.tiers = admissible + [_failover.FLOOR_TIER]
         else:
-            plan.tiers = ladder.route(
-                admissible, plan.n,
-                family=_failover.ROUTE_FAMILY_BLS,
-            ) + [_failover.FLOOR_TIER]
+            plan.tiers = admissible + ["host", _failover.FLOOR_TIER]
         return plan
 
     def execute(self, plan: _BlsPlan) -> tuple[bool, list[bool]]:
@@ -297,12 +282,8 @@ class BlsLadderVerifier(BatchVerifier):
                 )
                 continue
             self._last_tier = tier
-            # shape + wall feed the cost model (ed25519 execute
-            # parity), in the BLS family matching the plan's mode —
-            # the host rung here is pure-RLC BLS, and its timings must
-            # never drag the ed25519 host estimate (nor may an
-            # aggregate's one-pairing-covers-N rate masquerade as
-            # per-signature batch throughput)
+            # counted in the BLS family matching the plan's mode: the
+            # host rung here is pure-RLC BLS, not the ed25519 one
             ladder.note_batch(
                 tier, batch=plan.n,
                 seconds=time.perf_counter() - t_tier,

@@ -46,37 +46,16 @@ mesh shard loss — so tier-1 can prove consensus keeps committing
 heights while the ladder demotes and re-promotes (`make chaos-smoke`,
 tests/test_dispatch.py).  Chaos never faults the host/python floor.
 
-**Cost-based routing** (ISSUE 14) sits ON TOP of the availability
-ladder: the :class:`TierCostModel` keeps per-(tier, pow2-shape-bucket)
-throughput estimates — seeded from the operator's perf ledger
-(CMT_TPU_PERF_LEDGER, if any) at first consult, refined online by an
-EWMA over the per-batch timings ``note_batch`` already receives — and
-``route()`` orders a batch's admissible tiers by predicted wall time
-for *that batch's shape* instead of walking the static preference
-order.  "Performance of EdDSA and BLS Signatures in Committee-Based
-Consensus" (arXiv:2302.00418) quantifies why the order must be
-shape-dependent: which strategy wins flips with batch size, so the one
-static walk is wrong at one end or the other (the r05 contradiction —
-host Pippenger at 56.8k sigs/s outran the generic device path — made
-``/debug/dispatch`` publish ``order_contradictions`` nobody consumed;
-the router is that consumer).  The ladder remains the availability
-mechanism: cost ordering only PERMUTES the admissible list, demotion /
-cool-down / chaos are untouched, and the python floor is always last.
-Hysteresis keeps one noisy sample from flapping the routing: estimates
-participate only with ledger provenance or ``CMT_TPU_ROUTE_MIN_SAMPLES``
-online samples, a reorder needs a ``CMT_TPU_ROUTE_MARGIN`` predicted
-gain, EWMA updates are winsorized, and adopted orders hold for
-``CMT_TPU_ROUTE_COOLDOWN_S`` per shape bucket.
-
 Every transition emits a ``crypto/dispatch_transition`` flight event
 and feeds ``crypto_dispatch_demotions_total{from,to,reason}`` /
 ``crypto_dispatch_promotions_total{tier}`` /
-``crypto_dispatch_current_tier{tier}`` (one-hot); routing decisions
-feed ``crypto_dispatch_route{tier,bucket,source}`` and order adoptions
-``crypto_route_reorders_total{bucket}``.  ``/debug/dispatch`` (metrics
-server and JSON-RPC route, inspect mode included) serves the ladder
-state, cool-downs, the recent transition trail, and the live cost
-table with the contradictions the router has resolved.  Policy
+``crypto_dispatch_current_tier{tier}`` (one-hot).  ``/debug/dispatch``
+(metrics server and JSON-RPC route, inspect mode included) serves the
+ladder state, cool-downs, the recent transition trail, the chaos plan
+and the per-(family, tier, pow2-bucket) batch counts.  Which tiers a
+batch is ELIGIBLE for is not decided here: that is
+``TpuBatchVerifier._plan()`` (ops/ed25519_verify.py, the table over
+``DEVICE_MIN_BATCH``) and ``BlsLadderVerifier.plan()``.  Policy
 documentation: docs/dispatch_ladder.md.
 
 This module deliberately imports no jax: host-only nodes (device
@@ -89,7 +68,7 @@ from __future__ import annotations
 import os
 import random
 import time
-from collections import deque
+from collections import Counter, deque
 
 from cometbft_tpu.crypto import ed25519 as _ed
 from cometbft_tpu.metrics import crypto_metrics as _crypto_metrics
@@ -135,34 +114,17 @@ DEFAULT_COOLDOWN_MAX_S = 600.0
 #: transition-trail ring depth served at /debug/dispatch
 TRANSITION_RING = 64
 
-# -- cost-routing policy (TierCostModel) --------------------------------
-#: online learned samples a (tier, bucket) estimate needs before it may
-#: participate in reordering (seeded ledger estimates carry a whole
-#: bench run's worth of evidence and participate immediately)
-DEFAULT_ROUTE_MIN_SAMPLES = 3
-#: predicted throughput gain required before a lower rung outranks a
-#: higher one (0.2 = the lower tier must predict >= 20% faster)
-DEFAULT_ROUTE_MARGIN = 0.2
-#: per-(bucket, candidate-set) reorder cool-down: an adopted order
-#: holds at least this long, so estimates hovering at the margin
-#: boundary cannot flap the routing per batch
-DEFAULT_ROUTE_COOLDOWN_S = 30.0
-#: EWMA smoothing for online refinement — one sample moves an
-#: established estimate at most alpha * (winsor - 1) = 20%
-ROUTE_EWMA_ALPHA = 0.2
-#: winsorization bound: a single sample is clamped to
-#: [est / 2, est * 2] before the EWMA, so one wild outlier (a paused
-#: process, a cold compile) can never flip an established pair on its
-#: own — consistent repeats are evidence and still win in 2-3 batches
-ROUTE_WINSOR_FACTOR = 2.0
 #: shape-bucket ceiling (anything larger shares the top bucket)
 MAX_SHAPE_BUCKET = 1 << 20
-#: cost-estimate families: "host" means ed25519 CPU-batch for an
-#: ed25519 walk but pure-RLC BLS for a BLS batch walk — orders of
-#: magnitude apart — and a BLS aggregate (one pairing covers N
-#: signers) is not N independent pairings.  Estimates therefore key
-#: on (family, tier, bucket): same-name rungs in different families
-#: never share (or pollute) a number.
+#: batch-counter families: "host" means ed25519 CPU-batch for an
+#: ed25519 walk but pure-RLC BLS for a BLS batch walk, and a BLS
+#: aggregate (one pairing covers N signers) is not N independent
+#: pairings — so the counter keys on (family, tier, bucket) and
+#: same-name rungs in different families never share a row.  The
+#: ``ROUTE_FAMILY_*`` names and ``DispatchLadder.cost_snapshot`` date
+#: from the deleted cost router; they are kept because
+#: ``benchmark/observe.py`` reads them (the rename is a ``benchmark``
+#: issue's: ROADMAP Design 2).
 ROUTE_FAMILY_ED25519 = "ed25519"
 ROUTE_FAMILY_BLS = "bls"
 ROUTE_FAMILY_BLS_AGG = "bls_agg"
@@ -170,9 +132,8 @@ ROUTE_FAMILY_BLS_AGG = "bls_agg"
 
 def shape_bucket(n: int) -> int:
     """The pow2 ceiling bucket a batch of ``n`` signatures falls in —
-    the shape key of the cost model (a 2-sig evidence check and a
-    10k-sig commit must never share an estimate: per-launch overhead
-    dominates one and amortizes in the other)."""
+    the shape key of the ladder's batch counter (a 2-sig evidence
+    check and a 10k-sig commit must never share a row)."""
     if n <= 1:
         return 1
     return min(1 << (n - 1).bit_length(), MAX_SHAPE_BUCKET)
@@ -214,33 +175,6 @@ def cooldown_max_from_env() -> float:
     """Cool-down ceiling for repeat offenders."""
     return _float_env(
         "CMT_TPU_COOLDOWN_MAX_S", DEFAULT_COOLDOWN_MAX_S, 0.001
-    )
-
-
-def route_enabled_from_env() -> bool:
-    """Cost-based shape-aware routing on/off (default on).  Fail-loudly
-    contract: anything but 0/1 raises naming the variable."""
-    return flag_from_env("CMT_TPU_ROUTE", default=True)
-
-
-def route_min_samples_from_env() -> int:
-    """Online samples a (tier, bucket) estimate needs to participate
-    in reordering."""
-    return _int_env(
-        "CMT_TPU_ROUTE_MIN_SAMPLES", DEFAULT_ROUTE_MIN_SAMPLES, 1
-    )
-
-
-def route_margin_from_env() -> float:
-    """Predicted throughput gain required before the cost model
-    reorders a tier pair (0.2 = 20%)."""
-    return _float_env("CMT_TPU_ROUTE_MARGIN", DEFAULT_ROUTE_MARGIN, 0.0)
-
-
-def route_cooldown_from_env() -> float:
-    """Per-shape-bucket reorder cool-down seconds."""
-    return _float_env(
-        "CMT_TPU_ROUTE_COOLDOWN_S", DEFAULT_ROUTE_COOLDOWN_S, 0.0
     )
 
 
@@ -484,277 +418,6 @@ class Chaos:
         }
 
 
-# -- the cost model ------------------------------------------------------
-
-
-class TierCostModel:
-    """Per-(tier, pow2-shape-bucket) throughput estimates and the
-    shape-aware order they imply (module docstring, "Cost-based
-    routing").  NOT independently locked: the owning
-    :class:`DispatchLadder` calls every ``*_locked`` method under its
-    own ``_mtx`` — the hot path gains no new lock acquisitions, the
-    cost update rides the one per-batch ``note_batch`` critical
-    section that already exists.
-
-    Estimate lifecycle: a **seeded** entry comes from the perf
-    ledger's measured rows and participates immediately (it carries a
-    whole bench run's evidence); an online entry starts **warming**
-    and participates only after ``min_samples`` batches; either
-    becomes **learned** once ``min_samples`` online samples have
-    refined it.  Estimates are strictly per-bucket — no cross-shape
-    extrapolation, because shape-dependence (which strategy wins flips
-    with batch size, arXiv:2302.00418) is exactly what makes
-    extrapolation wrong.
-
-    Ordering: starting from the static ladder order, adjacent pairs
-    where BOTH tiers have participating estimates for the bucket are
-    bubble-swapped when the lower tier predicts a ``margin`` faster
-    run; pairs with a missing estimate keep their static relative
-    order (evidence permutes the walk, absence of evidence never
-    does).  Adopted orders are cached per (bucket, candidate-set) and
-    held for ``cooldown_s`` — the flap bound.
-    """
-
-    def __init__(
-        self,
-        enabled: bool | None = None,
-        min_samples: int | None = None,
-        margin: float | None = None,
-        cooldown_s: float | None = None,
-    ) -> None:
-        self.enabled = (
-            enabled if enabled is not None else route_enabled_from_env()
-        )
-        self.min_samples = (
-            min_samples if min_samples is not None
-            else route_min_samples_from_env()
-        )
-        self.margin = (
-            margin if margin is not None else route_margin_from_env()
-        )
-        self.cooldown_s = (
-            cooldown_s if cooldown_s is not None
-            else route_cooldown_from_env()
-        )
-        # (family, tier, bucket) -> {sigs_per_sec, samples, source,
-        # config} — family-keyed so a BLS batch's host-RLC timing can
-        # never drag the ed25519 host estimate (or vice versa)
-        self._est: dict[tuple[str, str, int], dict] = {}
-        # (family, bucket, static candidates) -> {order, last_reorder,
-        # reorders}
-        self._orders: dict[tuple[str, int, tuple], dict] = {}
-        #: ledger seeding happened (racy read is fine: seeding is
-        #: idempotent — seed_locked never overwrites online evidence)
-        self.seeded = False
-
-    # -- estimates (call under the ladder's _mtx) ------------------------
-
-    def seed_locked(self, measured: dict) -> int:  # holds ladder _mtx
-        """Seed from ``health.measured_tier_throughput()`` output (its
-        per-bucket view of single-batch tier-throughput rows).  Online
-        evidence outranks a seed: an entry that already has samples is
-        never overwritten.  Rows land in the family their tier implies
-        — device/host rows are ed25519 benches, ``bls_native`` rows
-        are BLS (aggregate when the config says so)."""
-        n = 0
-        for tier, info in measured.items():
-            if tier not in TIER_ORDER or tier == FLOOR_TIER:
-                continue
-            for bucket, entry in (info.get("buckets") or {}).items():
-                if tier in BLS_TIERS:
-                    family = (
-                        ROUTE_FAMILY_BLS_AGG
-                        if "aggregate" in (entry.get("config") or "")
-                        else ROUTE_FAMILY_BLS
-                    )
-                else:
-                    family = ROUTE_FAMILY_ED25519
-                key = (family, tier, int(bucket))
-                st = self._est.get(key)
-                if st is not None and st["samples"] > 0:
-                    continue
-                self._est[key] = {
-                    "sigs_per_sec": float(entry["sigs_per_sec"]),
-                    "samples": 0,
-                    "source": "seeded",
-                    "config": entry.get("config"),
-                }
-                n += 1
-        self.seeded = True
-        return n
-
-    def observe_locked(
-        self, tier: str, batch: int, seconds: float | None,
-        family: str = ROUTE_FAMILY_ED25519,
-    ) -> None:  # holds ladder _mtx
-        """One batch's measured throughput, folded into the (family,
-        tier, bucket) EWMA.  Winsorized: the sample is clamped to
-        [est/2, est*2] first, so an established estimate moves at most
-        20% per batch — one outlier can never clear the reorder margin
-        alone."""
-        if (
-            tier == FLOOR_TIER or tier not in TIER_ORDER
-            or batch < 1 or not seconds or seconds <= 0
-        ):
-            return
-        sample = batch / seconds
-        key = (family, tier, shape_bucket(batch))
-        st = self._est.get(key)
-        if st is None:
-            self._est[key] = {
-                "sigs_per_sec": sample,
-                "samples": 1,
-                "source": (
-                    "learned" if self.min_samples <= 1 else "warming"
-                ),
-                "config": None,
-            }
-            return
-        prev = st["sigs_per_sec"]
-        clamped = min(
-            max(sample, prev / ROUTE_WINSOR_FACTOR),
-            prev * ROUTE_WINSOR_FACTOR,
-        )
-        st["sigs_per_sec"] = (
-            (1.0 - ROUTE_EWMA_ALPHA) * prev + ROUTE_EWMA_ALPHA * clamped
-        )
-        st["samples"] += 1
-        if st["samples"] >= self.min_samples:
-            st["source"] = "learned"
-
-    def _participating_locked(
-        self, tier: str, bucket: int,
-        family: str = ROUTE_FAMILY_ED25519,
-    ) -> dict | None:  # holds ladder _mtx
-        st = self._est.get((family, tier, bucket))
-        if st is None:
-            return None
-        if st["source"] in ("seeded", "learned"):
-            return st
-        return None  # warming: under min_samples, no routing say yet
-
-    # -- ordering (call under the ladder's _mtx) -------------------------
-
-    def desired_locked(
-        self, candidates: list[str], bucket: int,
-        family: str = ROUTE_FAMILY_ED25519,
-    ) -> tuple:  # holds ladder _mtx
-        """The cost-implied order: tiers WITH participating estimates
-        are reordered among themselves (margin-gated bubble over the
-        estimated SUBSEQUENCE, so an estimate-less tier sitting
-        between two estimated ones never blocks their comparison —
-        keyed/generic/host with generic unmeasured still ranks host
-        against keyed) and re-inserted into the position slots the
-        estimated tiers occupied; tiers without evidence keep their
-        exact static positions.  Bounded passes — the list is <= 7
-        tiers."""
-        order = list(candidates)
-        idxs = [
-            i for i, t in enumerate(order)
-            if self._participating_locked(t, bucket, family) is not None
-        ]
-        sub = [order[i] for i in idxs]
-        for _ in range(len(sub)):
-            swapped = False
-            for k in range(len(sub) - 1):
-                ea = self._participating_locked(sub[k], bucket, family)
-                eb = self._participating_locked(
-                    sub[k + 1], bucket, family
-                )
-                if eb["sigs_per_sec"] > (
-                    ea["sigs_per_sec"] * (1.0 + self.margin)
-                ):
-                    sub[k], sub[k + 1] = sub[k + 1], sub[k]
-                    swapped = True
-            if not swapped:
-                break
-        for i, t in zip(idxs, sub):
-            order[i] = t
-        return tuple(order)
-
-    def order_locked(
-        self, candidates: list[str], bucket: int, now: float,
-        family: str = ROUTE_FAMILY_ED25519,
-    ) -> tuple[tuple, bool, str]:  # holds ladder _mtx
-        """-> (order, reordered_now, source) for one batch.  ``source``
-        labels how the FIRST tier got its slot: ``static`` when it
-        holds its configured position, else the winning estimate's
-        provenance (``seeded`` | ``learned``)."""
-        static = tuple(candidates)
-        if not self.enabled or len(static) < 2:
-            return static, False, "static"
-        desired = self.desired_locked(candidates, bucket, family)
-        key = (family, bucket, static)
-        st = self._orders.get(key)
-        if st is None:
-            st = {"order": static, "last_reorder": None, "reorders": 0}
-            self._orders[key] = st
-        reordered = False
-        if desired != st["order"]:
-            last = st["last_reorder"]
-            if last is None or now - last >= self.cooldown_s:
-                st["order"] = desired
-                st["last_reorder"] = now
-                st["reorders"] += 1
-                reordered = True
-        order = st["order"]
-        if order[0] == static[0]:
-            source = "static"
-        else:
-            est = self._participating_locked(order[0], bucket, family)
-            source = est["source"] if est is not None else "learned"
-        return order, reordered, source
-
-    def snapshot_locked(self, now: float) -> dict:  # holds ladder _mtx
-        """The live cost table /debug/dispatch serves."""
-        table = [
-            {
-                "family": family,
-                "tier": tier,
-                "bucket": bucket,
-                "sigs_per_sec": round(st["sigs_per_sec"], 1),
-                "samples": st["samples"],
-                "source": st["source"],
-                "config": st["config"],
-                "participating": (
-                    self._participating_locked(tier, bucket, family)
-                    is not None
-                ),
-            }
-            for (family, tier, bucket), st in sorted(self._est.items())
-        ]
-        orders = [
-            {
-                "family": family,
-                "bucket": bucket,
-                "candidates": list(cands),
-                "order": list(st["order"]),
-                "reorders": st["reorders"],
-                "last_reorder_age_s": (
-                    round(now - st["last_reorder"], 3)
-                    if st["last_reorder"] is not None else None
-                ),
-            }
-            for (family, bucket, cands), st in sorted(
-                self._orders.items()
-            )
-            if st["order"] != cands or st["reorders"]
-        ]
-        return {
-            "enabled": self.enabled,
-            "seeded": self.seeded,
-            "policy": {
-                "min_samples": self.min_samples,
-                "margin": self.margin,
-                "cooldown_s": self.cooldown_s,
-                "ewma_alpha": ROUTE_EWMA_ALPHA,
-                "winsor_factor": ROUTE_WINSOR_FACTOR,
-            },
-            "table": table,
-            "orders": orders,
-        }
-
-
 # -- the ladder ----------------------------------------------------------
 
 
@@ -770,6 +433,7 @@ class DispatchLadder:
         "_known": "_mtx",
         "_transitions": "_mtx",
         "_gauge_set": "_mtx",
+        "_batches": "_mtx",
     }
 
     def __init__(
@@ -780,7 +444,6 @@ class DispatchLadder:
         cooldown_max_s: float | None = None,
         clock=time.monotonic,
         logger=None,
-        cost_model: TierCostModel | None = None,
     ) -> None:
         self._mtx = cmtsync.Mutex()
         self._clock = clock
@@ -809,13 +472,8 @@ class DispatchLadder:
         # the one-hot gauge only changes on transitions and _known
         # growth — not per batch, so the hot path skips the rewrite
         self._gauge_set = False
-        # unguarded: immutable reference — the cost model's inner
-        # state is mutated only while holding _mtx (its *_locked
-        # contract); only `seeded`/`enabled` are read lock-free, both
-        # benign (set-once / idempotent-seed)
-        self._cost = (
-            cost_model if cost_model is not None else TierCostModel()
-        )
+        # (family, tier, pow2 bucket) -> batches that ran there
+        self._batches: Counter = Counter()
 
     # -- state helpers (call under _mtx) ---------------------------------
 
@@ -899,133 +557,23 @@ class DispatchLadder:
         with self._mtx:
             return self._current_locked()
 
-    # -- cost routing -----------------------------------------------------
-
-    def ensure_seeded(self) -> None:
-        """Lazily seed the cost model from the perf ledger — the
-        "process start" seed, deferred to the first routing consult so
-        importing this module never does file I/O.  The ledger read
-        runs OUTSIDE the mutex; seeding is idempotent, so a racing
-        double-read costs one redundant parse, never a wrong table."""
-        if self._cost.seeded or not self._cost.enabled:
-            return
-        from cometbft_tpu.crypto.health import measured_tier_throughput
-
-        try:
-            measured = measured_tier_throughput()
-        except Exception as exc:  # noqa: BLE001 — a malformed ledger
-            # must not take routing (or the node) down: run unseeded,
-            # learn online, and say so once
-            measured = {}
-            self.logger.error(
-                "perf-ledger seed failed; cost model learns online "
-                "only", err=repr(exc),
-            )
-        with self._mtx:
-            n = self._cost.seed_locked(measured)
-        if n:
-            self.logger.info(
-                "cost model seeded from perf ledger", entries=n
-            )
-
-    def route(
-        self, admissible: list[str], batch: int, add_host: bool = True,
-        family: str = ROUTE_FAMILY_ED25519,
-    ) -> list[str]:
-        """Cost-order one batch's walk: the ladder-admissible tiers
-        plus the host rung (cross-family ordering is the point — the
-        r05 contradiction is host beating a device tier), permuted by
-        predicted wall time for this batch's shape bucket.  The caller
-        appends the floor; cost ordering never touches it.  Emits
-        ``crypto_dispatch_route{tier,bucket,source}`` for the chosen
-        first tier and ``crypto_route_reorders_total{bucket}`` when a
-        new order is adopted."""
-        candidates = list(admissible)
-        if add_host and "host" not in candidates:
-            candidates.append("host")
-        if not candidates:
-            return []
-        bucket = shape_bucket(batch)
-        self.ensure_seeded()
-        with self._mtx:
-            order, reordered, source = self._cost.order_locked(
-                candidates, bucket, self._clock(), family
-            )
-        cm = _crypto_metrics()
-        if reordered:
-            cm.route_reorders_total.labels(bucket=str(bucket)).inc()
-            FLIGHT.record(
-                "crypto/route_reorder", bucket=bucket,
-                order=list(order),
-            )
-            self.logger.info(
-                "cost model reordered dispatch walk", bucket=bucket,
-                order=list(order), static=candidates,
-            )
-        cm.dispatch_route.labels(
-            tier=order[0], bucket=str(bucket), source=source
-        ).inc()
-        return list(order)
-
-    def note_route(
-        self, tier: str, batch: int, source: str = "static"
-    ) -> None:
-        """Route accounting for plans that never reach ``route()``
-        (the host-only branch: batch below every device threshold) —
-        every plan lands in ``crypto_dispatch_route`` exactly once."""
-        _crypto_metrics().dispatch_route.labels(
-            tier=tier, bucket=str(shape_bucket(batch)), source=source
-        ).inc()
-
-    def router_prefers(
-        self, faster: str, preferred: str, bucket: int | None
-    ) -> bool:
-        """Does the cost model, consulted for ``bucket``, rank the
-        measured-faster tier above the statically-preferred one in a
-        FULL walk?  The ``resolved_by_router`` flag on
-        /debug/dispatch's ``order_contradictions`` — pure read, no
-        metrics, no order adoption.  Deliberately evaluated over every
-        tier with a participating estimate at this bucket, not the
-        bare pair: the margin-gated ordering is non-transitive, so a
-        pairwise check could claim "resolved" while a real plan()'s
-        walk (with a third estimated tier between them) still
-        dispatches the slower tier first.  The full-walk form
-        under-claims at worst (a batch whose eligibility excludes the
-        middle tier may reorder anyway) — the flag stays honest."""
-        if bucket is None or not self._cost.enabled:
-            return False
-        if FLOOR_TIER in (faster, preferred):
-            # the floor is never part of the permutation (it is
-            # always last), and it is excluded from the candidate
-            # walk below — a degraded box CAN ledger a python-tier
-            # row that out-measures a barely-alive device tier, and
-            # that contradiction must not crash /debug/dispatch
-            return False
-        family = (
-            ROUTE_FAMILY_BLS
-            if faster in BLS_TIERS or preferred in BLS_TIERS
-            else ROUTE_FAMILY_ED25519
-        )
-        self.ensure_seeded()
-        with self._mtx:
-            candidates = [
-                t for t in TIER_ORDER
-                if t != FLOOR_TIER and (
-                    t in (faster, preferred)
-                    or self._cost._participating_locked(
-                        t, int(bucket), family
-                    ) is not None
-                )
-            ]
-            order = self._cost.desired_locked(
-                candidates, int(bucket), family
-            )
-        return order.index(faster) < order.index(preferred)
-
     def cost_snapshot(self) -> dict:
-        self.ensure_seeded()
+        """The batch counter as ``{"table": [...]}``: one row per
+        (family, tier, pow2 bucket) with ``samples`` batches.  The
+        python floor is not in it (``note_batch``).  Read by
+        /debug/dispatch, chip_smoke.py's Probe and the benchmark's
+        ``device_sig_pct.*`` — whence the name (ROUTE_FAMILY_* above)."""
         with self._mtx:
-            return self._cost.snapshot_locked(self._clock())
+            rows = sorted(self._batches.items())
+        return {
+            "table": [
+                {
+                    "family": family, "tier": tier, "bucket": bucket,
+                    "samples": count,
+                }
+                for (family, tier, bucket), count in rows
+            ]
+        }
 
     # -- evidence ---------------------------------------------------------
 
@@ -1037,16 +585,20 @@ class DispatchLadder:
         records the tier it ACTUALLY ran on here (host-only factory
         verifiers and device verifiers alike — PR 6's split accounting
         unified), and a successful batch on a trial-re-admitted tier
-        promotes it.  ``batch``/``seconds`` (the batch's shape and
-        measured wall) feed the cost model's per-(tier, bucket) EWMA
-        inside the same critical section — online refinement costs the
-        hot path zero new lock acquisitions."""
+        promotes it.  A batch with a shape and a measured wall
+        (``batch`` >= 1, ``seconds`` > 0) on any tier but the python
+        floor is also counted per (family, tier, pow2 bucket) inside
+        the same critical section (``cost_snapshot``)."""
         _crypto_metrics().dispatch_tier.labels(tier=tier).inc()
         promote = False
         with self._mtx:
             refresh = not self._gauge_set or tier not in self._known
             self._known.add(tier)
-            self._cost.observe_locked(tier, batch, seconds, family)
+            if (
+                tier != FLOOR_TIER and tier in TIER_ORDER
+                and batch >= 1 and seconds is not None and seconds > 0
+            ):
+                self._batches[family, tier, shape_bucket(batch)] += 1
             st = self._st(tier)
             st["fail_streak"] = 0
             if st["demoted"] and self._clock() >= st["cooldown_until"]:
@@ -1244,26 +796,15 @@ class DispatchLadder:
                 "transitions": list(self._transitions),
             }
 
-    def rebuild_cost_model(self) -> None:
-        """Replace the cost model with an empty, unseeded one built
-        from the env as it is NOW (CMT_TPU_ROUTE and the routing
-        knobs); the next routing consult re-seeds from whatever
-        CMT_TPU_PERF_LEDGER points at.  Tier health, cool-downs and
-        the transition trail are untouched, so it is safe while a
-        node is verifying in this process (chip_smoke.py's as_shipped
-        phase)."""
-        with self._mtx:
-            self._cost = TierCostModel()
-
     def reset(self) -> None:
-        """Tests only: wipe all tier state and re-read the env knobs
-        (the cost model is rebuilt too)."""
+        """Tests only: wipe all tier state and the batch counter and
+        re-read the env knobs."""
         with self._mtx:
             self._state.clear()
             self._known = {"host", FLOOR_TIER}
             self._transitions.clear()
             self._gauge_set = False
-        self.rebuild_cost_model()
+            self._batches.clear()
         self.demote_after = demote_after_from_env()
         self.promote_after = promote_after_from_env()
         self.cooldown_s = cooldown_from_env()
@@ -1303,12 +844,6 @@ class LadderHostVerifier(_ed.CpuBatchVerifier):
         if not self._entries:
             return False, []
         n = len(self._entries)
-        # route accounting parity with the plan() seam: a factory-host
-        # verifier's walk is host->python by construction, and on a
-        # host-only node (the only place this class serves) it is the
-        # ONLY verifier — without this sample the dispatch_route
-        # family would be empty exactly where operators read it most
-        LADDER.note_route("host", n)
         if LADDER.active("host"):
             try:
                 t0 = time.perf_counter()
@@ -1338,64 +873,16 @@ class LadderHostVerifier(_ed.CpuBatchVerifier):
 # -- the /debug/dispatch payload -----------------------------------------
 
 
-def _contradiction_bucket(measured: dict, lo: str, hi: str) -> int | None:
-    """The shape bucket a contradiction was measured at: the faster
-    (lower) tier's latest measurement's bucket, else the preferred
-    tier's — None when neither row carried batch provenance (the
-    router is shape-aware; a shapeless contradiction it cannot
-    resolve)."""
-    for tier in (lo, hi):
-        bucket = measured.get(tier, {}).get("bucket")
-        if bucket is not None:
-            return bucket
-    return None
-
-
 def debug_dispatch_payload() -> dict:
     """Everything ``/debug/dispatch`` serves: ladder order + per-tier
     state (demoted, cool-downs, streaks), the recent transition trail,
-    the chaos plan (docs/dispatch_ladder.md), the live cost table
-    (TierCostModel), and the perf ledger's latest MEASURED sigs/s per
-    tier next to the configured order — with an explicit contradiction
-    list whenever a tier the ladder prefers measures slower than one
-    below it (the r05 host-Pippenger-beats-generic shape).  Each
-    contradiction carries ``resolved_by_router``: True when the cost
-    model now ranks the pair correctly for that measured shape, so the
-    surface reports the router WORKING instead of a standing
-    complaint."""
-    from cometbft_tpu.crypto.health import measured_tier_throughput
-
-    measured = measured_tier_throughput()
-    contradictions = []
-    for i, hi in enumerate(TIER_ORDER):
-        # a tier may carry only a bucket view (its rows were latency-
-        # united) — the tier-level contradiction scan needs the
-        # tier-level number
-        if measured.get(hi, {}).get("sigs_per_sec") is None:
-            continue
-        for lo in TIER_ORDER[i + 1:]:
-            if measured.get(lo, {}).get("sigs_per_sec") is None:
-                continue
-            hi_v = measured[hi]["sigs_per_sec"]
-            lo_v = measured[lo]["sigs_per_sec"]
-            if lo_v > hi_v:
-                bucket = _contradiction_bucket(measured, lo, hi)
-                contradictions.append({
-                    "preferred": hi,
-                    "preferred_sigs_per_sec": hi_v,
-                    "faster": lo,
-                    "faster_sigs_per_sec": lo_v,
-                    "bucket": bucket,
-                    "resolved_by_router": LADDER.router_prefers(
-                        lo, hi, bucket
-                    ),
-                })
+    the chaos plan (docs/dispatch_ladder.md) and the batch counter
+    (``batches``: per (family, tier, pow2 bucket), the batches that
+    ran there)."""
     return {
         "ladder": LADDER.snapshot(),
         "chaos": CHAOS.snapshot(),
-        "cost_model": LADDER.cost_snapshot(),
-        "measured_tier_throughput": measured,
-        "order_contradictions": contradictions,
+        "batches": LADDER.cost_snapshot()["table"],
     }
 
 
@@ -1414,7 +901,6 @@ __all__ = [
     "ChaosPlan",
     "DispatchLadder",
     "LadderHostVerifier",
-    "TierCostModel",
     "TierFault",
     "TierUnavailable",
     "chaos_enabled",
@@ -1428,9 +914,5 @@ __all__ = [
     "ROUTE_FAMILY_BLS_AGG",
     "ROUTE_FAMILY_ED25519",
     "reset_for_tests",
-    "route_cooldown_from_env",
-    "route_enabled_from_env",
-    "route_margin_from_env",
-    "route_min_samples_from_env",
     "shape_bucket",
 ]

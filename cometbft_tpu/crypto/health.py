@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import threading
 import time
 from contextlib import contextmanager
@@ -782,8 +781,7 @@ USAGE = DeviceUsage()
 def perf_ledger_path() -> str | None:
     """The perf ledger ``CMT_TPU_PERF_LEDGER`` names (the merged perf
     trajectory tools/perfledger.py maintains), or None: the node reads
-    a ledger only when the operator points it at one — a ledger taken
-    on another machine must never seed this one's routing."""
+    a ledger only when the operator points it at one."""
     return os.environ.get("CMT_TPU_PERF_LEDGER") or None  # env ok: free-form filesystem path — no parse to fail
 
 
@@ -800,137 +798,6 @@ def perf_ledger_tail(n: int = 10) -> list[dict]:
         return entries[-n:] if n else entries
     except (OSError, ValueError):
         return []
-
-
-#: batch-size provenance from a ledger config NAME, for rows predating
-#: the explicit ``batch`` field: "<n>sig"/"<n>val" tokens
-#: (micro_64sig, bls_aggregate_150val, light_sync_150val_pipelined)
-#: and the verify_commit_<n> family.  Deliberately narrow — "8dev" or
-#: "1kval" must NOT parse as a batch size.
-_SHAPE_TOKEN_RE = re.compile(r"(?:^|[_-])(\d+)(?:sig|val)s?(?=$|[_-])")
-_VERIFY_COMMIT_RE = re.compile(r"^verify_commit_(\d+)(?:$|_)")
-
-
-def _entry_batch(e: dict) -> int | None:
-    """The signature-batch size a ledger row measured, when its
-    provenance carries one (explicit ``batch``/``nval`` field, or a
-    parseable config name) — the shape key the cost-routing seed
-    needs.  None means the row stays a tier-level fact only."""
-    for field in ("batch", "nval"):
-        v = e.get(field)
-        if isinstance(v, (int, float)) and v >= 1:
-            return int(v)
-    cfg = e.get("config") or ""
-    m = _SHAPE_TOKEN_RE.search(cfg) or _VERIFY_COMMIT_RE.match(cfg)
-    if m:
-        return int(m.group(1))
-    return None
-
-
-#: config families known to measure SINGLE-BATCH tier throughput (one
-#: batch at a time through one tier's verify path) — the only numbers
-#: a routing seed may treat as "what one launch of this shape costs on
-#: this tier".  Deliberately default-deny: pipelined/overlapped rows
-#: (verify_queue_pipelined), whole-pipeline stream rows (light_sync,
-#: blocksync_replay), and mixed-workload rows (dispatch_shape_mix)
-#: measure something else entirely and would mis-seed routing.
-_SEEDABLE_CONFIG_RE = re.compile(
-    r"^(micro_|verify_commit_|verify_queue_sync$|keyed_mesh_|"
-    r"bls_aggregate_)"
-)
-
-
-def _route_seedable(e: dict) -> bool:
-    """May this row seed a per-(tier, bucket) routing estimate?  An
-    explicit ``route_seed`` field wins either way (the contract for
-    new bench rows); otherwise the conservative single-batch config
-    allowlist above decides."""
-    flag = e.get("route_seed")
-    if flag is not None:
-        return bool(flag)
-    return bool(_SEEDABLE_CONFIG_RE.match(e.get("config") or ""))
-
-
-def _entry_throughput(e: dict) -> float | None:
-    """A row's sigs/s for the per-bucket view: the value itself on a
-    throughput row, else an explicit ``sigs_per_sec`` provenance field
-    (latency rows like verify_commit_150_device record both) — None
-    when the row carries no usable positive rate."""
-    if e.get("unit") == "sigs/sec":
-        v = e.get("value")
-    else:
-        v = e.get("sigs_per_sec")
-    if isinstance(v, (int, float)) and v > 0:
-        return float(v)
-    return None
-
-
-def measured_tier_throughput() -> dict[str, dict]:
-    """Latest MEASURED sigs/s per dispatch tier from the perf ledger —
-    the r05 lesson (host Pippenger outran the generic device path)
-    made concrete: the static ladder order is a configuration, these
-    numbers are evidence.  Ledger append order is recency (same-key
-    replaces move to the end), so a later row for a tier wins; zero
-    values are skipped (the ledger records device-down rounds as 0 —
-    availability, not performance).
-
-    Shape buckets (ISSUE 14): rows that measured SINGLE-BATCH tier
-    throughput (``_route_seedable``: an explicit ``route_seed`` field
-    or the known config allowlist — pipelined / sustained /
-    mixed-workload rows are deliberately excluded, they measure a
-    pipeline, not a launch) and whose provenance names a batch size
-    additionally land in ``buckets`` — latest row per (tier,
-    pow2-bucket) — the per-shape view ``dispatch.TierCostModel`` seeds
-    from.  Latency rows carrying an explicit ``sigs_per_sec`` field
-    (verify_commit_150_device) qualify for the bucket view even
-    though their unit keeps them out of the tier-level map.  A row
-    without batch provenance stays a tier-level fact only (the router
-    never extrapolates a shapeless number across shapes)."""
-    from cometbft_tpu.crypto.dispatch import shape_bucket
-
-    out: dict[str, dict] = {}
-    for e in perf_ledger_tail(0):  # 0 = the whole ledger, in order
-        tier = e.get("dispatch_tier")
-        if not tier:
-            continue
-        prev = out.get(tier)
-        buckets = prev.get("buckets", {}) if prev else {}
-        rate = _entry_throughput(e)
-        batch = _entry_batch(e)
-        bucket = shape_bucket(batch) if batch is not None else None
-        if rate is not None and bucket is not None and (
-            _route_seedable(e)
-        ):
-            buckets[bucket] = {
-                "sigs_per_sec": rate,
-                "batch": batch,
-                "config": e.get("config"),
-                "source": e.get("source"),
-                "measured": e.get("measured"),
-            }
-        val = e.get("value")
-        if e.get("unit") != "sigs/sec" or not isinstance(
-            val, (int, float)
-        ) or val <= 0:
-            # not a tier-level throughput point; keep any bucket it
-            # contributed attached to the tier's existing entry
-            if prev is not None:
-                prev["buckets"] = buckets
-            elif buckets:
-                out[tier] = {"buckets": buckets}
-            continue
-        entry = {
-            "sigs_per_sec": val,
-            "config": e.get("config"),
-            "source": e.get("source"),
-            "measured": e.get("measured"),
-            "buckets": buckets,
-        }
-        if batch is not None:
-            entry["batch"] = batch
-            entry["bucket"] = bucket
-        out[tier] = entry
-    return out
 
 
 def debug_perf_payload(ledger_tail_n: int = 10) -> dict:
@@ -970,6 +837,5 @@ __all__ = [
     "health_interval_from_env",
     "launch_budget_from_env",
     "perf_ledger_path",
-    "measured_tier_throughput",
     "perf_ledger_tail",
 ]

@@ -802,11 +802,11 @@ class VerifyQueue(BaseService):
             # and lands in crypto_dispatch_tier; the per-sig fallback
             # below covers only unsupported key types and factory
             # failures.  The submission's COALESCED shape carries
-            # through plan() untouched — the cost router (ISSUE 14)
-            # sees the micro-batched size the launch will actually
-            # have, not the per-caller fragment sizes, so an ingest
-            # lane full of 1-sig CheckTx requests routes by the
-            # 256-sig buffer it coalesced into
+            # through plan() untouched — plan() sees the micro-batched
+            # size the launch will actually have, not the per-caller
+            # fragment sizes, so an ingest lane full of 1-sig CheckTx
+            # requests is routed (and counted) as the 256-sig buffer
+            # it coalesced into
             if crypto_batch.supports_batch_verifier(pk0):
                 try:
                     verifier = (

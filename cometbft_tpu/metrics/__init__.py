@@ -642,8 +642,6 @@ class CryptoMetrics:
             self.batch_verify_batch_size = _NOP
             self.dispatch_decisions = _NOP
             self.dispatch_tier = _NOP
-            self.dispatch_route = _NOP
-            self.route_reorders_total = _NOP
             self.dispatch_demotions_total = _NOP
             self.dispatch_promotions_total = _NOP
             self.dispatch_current_tier = _NOP
@@ -690,30 +688,6 @@ class CryptoMetrics:
             "host-only factory routes and device routes alike, so "
             "counts are comparable across tiers.",
             labels=("tier",),
-        )
-        self.dispatch_route = reg.counter(
-            s, "dispatch_route",
-            "Shape-aware cost-routing decisions "
-            "(crypto/dispatch.TierCostModel): the tier plan() placed "
-            "FIRST in the walk for a batch in this pow2 shape bucket, "
-            "and where that placement came from — source=seeded (perf-"
-            "ledger estimate), learned (online EWMA refinement), or "
-            "static (the configured ladder order; also every batch "
-            "routed host below the device thresholds).  A 2-sig bucket "
-            "landing on host while a 2048-sig bucket lands on a device "
-            "tier is the router working.",
-            labels=("tier", "bucket", "source"),
-        )
-        self.route_reorders_total = reg.counter(
-            s, "route_reorders_total",
-            "Cost-model order adoptions per shape bucket: the router "
-            "replaced the walk order for a (bucket, candidate-set) "
-            "with a measured-throughput order (hysteresis-gated: "
-            "min-samples + switch margin + per-bucket cool-down).  A "
-            "steadily climbing count on one bucket means estimates "
-            "are flapping around the margin — widen "
-            "CMT_TPU_ROUTE_MARGIN or raise the cool-down.",
-            labels=("bucket",),
         )
         self.dispatch_demotions_total = reg.counter(
             s, "dispatch_demotions_total",

@@ -663,15 +663,6 @@ class Node(BaseService):
         # chaos must SAY so, loudly, before the first injected fault
         from cometbft_tpu.crypto import dispatch as _dispatch
 
-        # cost-routing knobs validate fail-loudly at assembly (the
-        # documented env contract, same as the micro-batcher knobs
-        # below): a malformed CMT_TPU_ROUTE / CMT_TPU_ROUTE_MIN_SAMPLES
-        # / CMT_TPU_ROUTE_MARGIN / CMT_TPU_ROUTE_COOLDOWN_S fails the
-        # node LOUDLY instead of silently routing on defaults
-        _dispatch.route_enabled_from_env()
-        _dispatch.route_min_samples_from_env()
-        _dispatch.route_margin_from_env()
-        _dispatch.route_cooldown_from_env()
         if _dispatch.chaos_enabled():
             _dispatch.CHAOS.start()
             self.logger.error(

@@ -523,28 +523,48 @@ def verify_stream(jobs, max_in_flight: int = 8, dispatch=None):
         yield from flush(len(pending))
 
 
+# -- host or device: the one decision -----------------------------------
+# ``TpuBatchVerifier._plan()`` decides which tiers a batch of n
+# signatures is ELIGIBLE for, from what it can observe; the ladder
+# (crypto/dispatch.LADDER.admissible) then says which of those are
+# AVAILABLE, and the walk is ``admissible + ["host", "python"]``.  With
+# threshold = ``device_min_batch`` (ACCELERATOR_MIN_BATCH on an
+# accelerator, NO_DEVICE_DISPATCH on the CPU backend, or the
+# CMT_TPU_DEVICE_MIN_BATCH / constructor override):
+#
+#   n < DEVICE_MIN_BATCH and n < threshold     host, ``batch_size``
+#   DEVICE_MIN_BATCH <= n < threshold          keyed only if the set's
+#       tables are already warm (TABLE_CACHE.peek; ``keyed_warm``), else
+#       host, ``batch_size`` — a cold set is never built for here
+#   n >= threshold                             keyed after
+#       TABLE_CACHE.lookup_or_build, generic beside it, ``batch_size``
+#   a message over the largest bucket          host, ``msg_too_large``
+#   the CPU backend (no override)              host, ``cpu_backend``
+#   every eligible tier demoted                host, ``ladder_demoted``
+#
+# CMT_TPU_DISABLE_PRECOMPUTE or a demoted keyed tier skips the table
+# lookup (generic alone from the threshold up); a lookup that raises
+# faults the keyed tier and the batch goes on without it.
+
 #: The static floor: below this many signatures nothing goes to the
-#: device, warm key tables or not (a launch's fixed cost can never pay
-#: for a 2-signature evidence check; reference analog:
-#: types/validation.go:15 shouldBatchVerify).
+#: device on warm tables (a launch's fixed round trip is unchanged by
+#: warm tables and can never pay for a 2-signature evidence check;
+#: reference analog: types/validation.go:15 shouldBatchVerify).
 DEVICE_MIN_BATCH = 64
 
-#: The dispatch threshold on an accelerator: batches of at least this
-#: many signatures plan for the device tiers outright (building key
-#: tables when the set is cold); between the floor and this, only a
-#: batch whose tables are already warm does (``keyed_warm``).  A
-#: constant, not a crossover model: it is what the retired formula
-#: (round trip / 10 us) gave on the v5e's host at every start-up
-#: measured (1.06-1.20 ms -> n* 106-120 -> 128, PR 22), frozen because
-#: a threshold rounded from a tiny transfer's noisy round trip flips
-#: between pow2s — at exactly the 150-validator size — and that round
-#: trip is not the chip's launch floor anyway.  It is also the largest
-#: pow2 that still sends a 150-validator commit to the chip.  Which
-#: tier WINS for a shape is the cost router's question
-#: (crypto/dispatch.TierCostModel measures every batch's wall per
-#: tier); tools/derive_device_min_batch.py measures the host/device
-#: crossover on the attached chip for it (1,024 for the generic kernel
-#: on the v5e, PR 22).
+#: The dispatch threshold on an accelerator.  A constant, not a
+#: crossover model: it is what the retired formula (round trip / 10 us)
+#: gave on the v5e's host at every start-up measured (1.06-1.20 ms ->
+#: n* 106-120 -> 128, PR 22), frozen because a threshold rounded from a
+#: tiny transfer's noisy round trip flips between pow2s — at exactly
+#: the 150-validator size — and that round trip is not the chip's
+#: launch floor anyway.  It is also the largest pow2 that still sends
+#: a 150-validator commit to the chip.  Measured on either side of it:
+#: the chip ~5.9 ms against the host's 7.9 ms at 256 signatures (PR
+#: 27); between 65 and 127 on warm tables nothing has been measured
+#: (ROADMAP Design 2a).  tools/derive_device_min_batch.py measures the
+#: host/device crossover on the attached chip (1,024 for the generic
+#: kernel on the v5e, PR 22).
 ACCELERATOR_MIN_BATCH = 128
 
 #: Threshold on the XLA-on-CPU backend: the "device" there IS the host
@@ -702,19 +722,11 @@ class TpuBatchVerifier(BatchVerifier):
                 if n >= self._device_min_batch:
                     entry = _pr.TABLE_CACHE.lookup_or_build(self._pubs)
                 elif n >= DEVICE_MIN_BATCH:
-                    # KEYED-BY-DEFAULT promotion: below the device
-                    # threshold, a batch whose key-set tables are
-                    # already WARM still takes the keyed tier — the
-                    # threshold guards a table build and the generic
-                    # kernel's cost, and with hot tables the device
-                    # does only SHA-512 + R decompress + comb adds.
-                    # peek() never builds, so a cold set is not
+                    # the threshold guards a table build and the
+                    # generic kernel's cost; with warm tables the
+                    # device does only SHA-512 + R decompress + comb
+                    # adds.  peek() never builds, so a cold set is not
                     # stalled behind an EC build it didn't ask for.
-                    # The static DEVICE_MIN_BATCH floor still applies:
-                    # the per-launch link RTT is unchanged by warm
-                    # tables, so a tiny batch (a 2-sig evidence check)
-                    # must never trade a ~30us host verify for a
-                    # device launch's fixed round trip.
                     entry = _pr.TABLE_CACHE.peek(self._pubs)
                     if entry is not None:
                         reason = "keyed_warm"
@@ -757,27 +769,13 @@ class TpuBatchVerifier(BatchVerifier):
             plan.route = "host"
             plan.reason = reason
             plan.tiers = ["host", _failover.FLOOR_TIER]
-            # route accounting for the host-only branch too: every
-            # plan lands in crypto_dispatch_route exactly once, so the
-            # 2-sig bucket's host routing is as visible as the
-            # 2048-sig bucket's device routing
-            ladder.note_route("host", n)
             return plan
         cm.dispatch_decisions.labels(route="device", reason=reason).inc()
         cm.batch_verify_batch_size.observe(n)
         plan.route = "device"
         plan.reason = reason
         plan.entry = entry
-        # cost-ordered walk (ISSUE 14): the admissible device tiers
-        # PLUS the host rung, ordered by predicted wall time for this
-        # batch's shape bucket (crypto/dispatch.TierCostModel) — the
-        # r05 contradiction (host Pippenger beating the generic device
-        # path) reroutes here instead of standing in /debug/dispatch;
-        # with routing off (CMT_TPU_ROUTE=0) or no participating
-        # estimates this is exactly the static admissible + host walk
-        plan.tiers = ladder.route(admissible, n) + [
-            _failover.FLOOR_TIER
-        ]
+        plan.tiers = admissible + ["host", _failover.FLOOR_TIER]
         if entry is not None:
             plan.key_ids = entry.key_ids(self._pubs)
         plan.pub = np.frombuffer(
@@ -847,9 +845,8 @@ class TpuBatchVerifier(BatchVerifier):
                 )
                 continue
             self._last_tier = tier
-            # shape + wall feed the cost model's per-(tier, bucket)
-            # EWMA at the one per-batch accounting point — the wall is
-            # this tier's run only, never a failed rung above it
+            # the wall is this tier's run only, never a failed rung
+            # above it
             ladder.note_batch(
                 tier, batch=n, seconds=time.perf_counter() - t_tier
             )

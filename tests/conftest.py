@@ -18,18 +18,6 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# Cost-based dispatch routing (ISSUE 14) is ON by default in
-# production.  On this box it would — CORRECTLY — reroute the
-# XLA-on-CPU "device" tiers to the faster host rung as soon as
-# estimates accumulate, but the device-path suites pin tier-level
-# behavior (sharded equivalence, warm-table promotion, chaos demotion
-# chains) that depends on the STATIC walk order, so the suite runs
-# with routing off.  The routing suites (tests/test_route.py, `make
-# route-smoke`) opt back in explicitly per test via
-# dispatch.reset_for_tests().  setdefault: an explicit CMT_TPU_ROUTE
-# in the environment still wins.
-os.environ.setdefault("CMT_TPU_ROUTE", "0")
-
 # NB: kernel-compile caching for the suite is provided by
 # cometbft_tpu/ops/__init__.py — JAX_COMPILATION_CACHE_DIR when set,
 # else <checkout>/.xla_cache: warm runs skip recompiles of unchanged

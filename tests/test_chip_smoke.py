@@ -168,7 +168,7 @@ def test_native_artefact_is_keyed_by_content(tmp_path, monkeypatch):
 # seconds each, on every core the compiler can find), so the phases are
 # sized to SHARE programs: every one-chip batch is at most 16
 # signatures of one 12-key set — one table-build program and one
-# 16-lane keyed program for commit150, replay1k and as_shipped, plus
+# 16-lane keyed program for commit150 and replay1k, plus
 # the mesh program.  The 4-bit table width the real replay1k and mesh
 # phases run at is a constant of the same programs; its kernels are
 # proved on the CPU by tests/test_ops_kernel.py and at their real
@@ -189,7 +189,6 @@ def smoke_env():
     from cometbft_tpu.ops import precompute as PR
 
     mp = pytest.MonkeyPatch()
-    mp.setenv("CMT_TPU_ROUTE", "0")
     mp.setenv("CMT_TPU_DEVICE_MIN_BATCH", "2")
     mp.setenv("CMT_TPU_DISABLE_MESH_VERIFY", "1")
     mp.setenv("CMT_TPU_VERIFY_PREFETCH", "1")
@@ -251,32 +250,19 @@ def test_phase_replay1k(running_node, smoke_env):
 def test_phase_replay1k_fails_when_nothing_was_recorded(
     running_node, smoke_env, monkeypatch
 ):
-    """The phase's pass criteria look at recorded batches; a cost
-    table that stopped recording must fail it, not pass it unchecked."""
+    """The phase's pass criteria look at recorded batches; a counter
+    that stopped recording must fail it, not pass it unchecked."""
     from cometbft_tpu.crypto import dispatch
 
     monkeypatch.setattr(
         dispatch.DispatchLadder, "cost_snapshot",
-        lambda self: {"table": [], "enabled": False, "seeded": False,
-                      "orders": []},
+        lambda self: {"table": []},
     )
     with pytest.raises(S.SmokeFailure, match="no commit-sized batch"):
         S.phase_replay1k(
             smoke_env, seed=0, platform="cpu", n_vals=N_VALS,
             n_blocks=3, oracle_sample=2,
         )
-
-
-def test_phase_as_shipped(running_node, smoke_env):
-    line = S.phase_as_shipped(
-        smoke_env, seed=0, widths=(N_VALS,), n_commits=4
-    )
-    assert line["ok"] and line["router_enabled"] is True
-    # (bucket 1 is the running node's own one-signature commits)
-    assert [r["tier"] for r in line["route_table"]
-            if r["bucket"] > 1] == ["keyed"]
-    assert os.environ["CMT_TPU_ROUTE"] == "0"  # put back for the rest
-    json.dumps(line)
 
 
 def test_phase_mesh_on_virtual_devices(smoke_env, monkeypatch):

@@ -11,7 +11,9 @@ the launch_hang fault reproducing the r04 watchdog signature end to
 end, zero steady-state retraces under a sealed CMT_TPU_JITGUARD while
 the ladder demotes and re-promotes on the forced-8-device CPU mesh,
 the /debug/dispatch surfaces, race-mode hammering of the new guarded
-classes, and the tier-1 chaos liveness drive: a single-validator node
+classes, the host-or-device decision table of ``TpuBatchVerifier.
+_plan()`` and the static BLS walks, the ladder's batch counter up to
+the benchmark's reader of it, and the tier-1 chaos liveness drive: a single-validator node
 under CMT_TPU_CHAOS=1 commits >= 20 consecutive heights through an
 injected device loss and recovery while the flight recorder shows the
 demotion chain and the later re-promotion (`make chaos-smoke` runs the
@@ -22,6 +24,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 import urllib.request
@@ -40,6 +43,11 @@ from cometbft_tpu.metrics import (
 from cometbft_tpu.utils import sync as cmtsync
 from cometbft_tpu.utils.flight import FLIGHT
 from cometbft_tpu.utils.metrics import Registry
+
+# benchmark/ (the counter's reader) sits beside tests/, not on the path
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
 
 
 @pytest.fixture
@@ -753,6 +761,376 @@ class TestExecuteLadderWalk:
         assert counter_value(cm.dispatch_tier, tier="host") == 2
 
 
+# -- the host-or-device decision (ops/ed25519_verify.py _plan) -----------
+
+THRESHOLD = 128  # the accelerator's ACCELERATOR_MIN_BATCH, made explicit
+HOST_WALK = ["host", "python"]
+
+
+class FakeTables:
+    """TABLE_CACHE stand-in for plan()-only tests: no EC build, no XLA.
+    Records which of the two probes the plan made."""
+
+    class Entry:
+        def key_ids(self, pubs):
+            return np.zeros(len(pubs), dtype=np.int32)
+
+    def __init__(self, warm: bool, build="entry") -> None:
+        self.warm = warm
+        self.build = build
+        self.calls: list[str] = []
+
+    def peek(self, pubs):
+        self.calls.append("peek")
+        return self.Entry() if self.warm else None
+
+    def lookup_or_build(self, pubs):
+        self.calls.append("lookup_or_build")
+        if self.build == "raise":
+            raise RuntimeError("table build failed")
+        return self.Entry() if self.build == "entry" else None
+
+
+def _unsigned(bv, n: int, msg: bytes = b"plan-only"):
+    """n entries that are only ever planned, never verified."""
+    pub = ed.priv_key_from_secret(b"plan").pub_key()
+    for _ in range(n):
+        bv.add(pub, msg, bytes(64))
+    return bv
+
+
+def _table_row(n: int, warm: bool):
+    """The comment block over DEVICE_MIN_BATCH, as data: (route,
+    reason, tiers, table probes) for a batch of n under THRESHOLD."""
+    if n >= THRESHOLD:
+        return ("device", "batch_size",
+                ["keyed", "generic"] + HOST_WALK, ["lookup_or_build"])
+    if n >= 64:
+        if warm:
+            return ("device", "keyed_warm", ["keyed"] + HOST_WALK,
+                    ["peek"])
+        return "host", "batch_size", HOST_WALK, ["peek"]
+    return "host", "batch_size", HOST_WALK, []
+
+
+class TestPlanDecisionTable:
+    @pytest.fixture(autouse=True)
+    def clean(self, cm, dispatch_env, monkeypatch):
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        monkeypatch.delenv("CMT_TPU_DISABLE_PRECOMPUTE", raising=False)
+        monkeypatch.delenv("CMT_TPU_DEVICE_MIN_BATCH", raising=False)
+        self.cm = cm
+
+    def plan(self, monkeypatch, n, tables, msg=b"plan-only", **kw):
+        from cometbft_tpu.ops import precompute as PR
+        from cometbft_tpu.ops.ed25519_verify import TpuBatchVerifier
+
+        monkeypatch.setattr(PR, "TABLE_CACHE", tables)
+        return _unsigned(TpuBatchVerifier(**kw), n, msg).plan()
+
+    @pytest.mark.parametrize("warm", [True, False], ids=["warm", "cold"])
+    @pytest.mark.parametrize(
+        "n", [1, 2, 63, 64, 100, 127, 128, 150, 256, 1000, 1024]
+    )
+    def test_size_against_the_two_constants(self, n, warm, monkeypatch):
+        tables = FakeTables(warm)
+        plan = self.plan(monkeypatch, n, tables,
+                         device_min_batch=THRESHOLD)
+        route, reason, tiers, probes = _table_row(n, warm)
+        assert (plan.route, plan.reason) == (route, reason)
+        assert plan.tiers == tiers
+        assert tables.calls == probes
+        assert (plan.entry is not None) == ("keyed" in tiers)
+        assert counter_value(
+            self.cm.dispatch_decisions, route=route, reason=reason
+        ) == 1
+
+    def test_empty_batch_plans_nothing(self, monkeypatch):
+        plan = self.plan(monkeypatch, 0, FakeTables(True),
+                         device_min_batch=THRESHOLD)
+        assert plan.route == "empty" and plan.tiers == []
+
+    @pytest.mark.parametrize("n", [100, 150])
+    def test_message_over_the_largest_bucket(self, n, monkeypatch):
+        from cometbft_tpu.ops.ed25519_verify import _BUCKETS
+
+        tables = FakeTables(True)
+        plan = self.plan(monkeypatch, n, tables,
+                         msg=bytes(_BUCKETS[-1] + 1),
+                         device_min_batch=THRESHOLD)
+        assert (plan.route, plan.reason) == ("host", "msg_too_large")
+        assert plan.tiers == HOST_WALK and tables.calls == []
+
+    @pytest.mark.parametrize("n", [100, 150, 1024])
+    def test_cpu_backend_never_dispatches(self, n, monkeypatch):
+        from cometbft_tpu.ops import ed25519_verify as EV
+
+        assert EV.runtime_device_min_batch() == EV.NO_DEVICE_DISPATCH
+        tables = FakeTables(True)
+        plan = self.plan(monkeypatch, n, tables)  # the backend's rule
+        assert (plan.route, plan.reason) == ("host", "cpu_backend")
+        assert plan.tiers == HOST_WALK and tables.calls == []
+
+    @pytest.mark.parametrize("demoted,route,reason,tiers,probes", [
+        (("keyed", "generic"), "host", "ladder_demoted", HOST_WALK, []),
+        (("keyed",), "device", "batch_size", ["generic"] + HOST_WALK,
+         []),
+        (("generic",), "device", "batch_size", ["keyed"] + HOST_WALK,
+         ["lookup_or_build"]),
+    ], ids=["both", "keyed", "generic"])
+    def test_demoted_tiers_leave_the_walk(
+        self, demoted, route, reason, tiers, probes, monkeypatch
+    ):
+        for tier in demoted:
+            dispatch.LADDER.tier_fault(tier, reason="watchdog")
+        tables = FakeTables(True)
+        plan = self.plan(monkeypatch, 150, tables,
+                         device_min_batch=THRESHOLD)
+        assert (plan.route, plan.reason) == (route, reason)
+        assert plan.tiers == tiers
+        # a dead keyed tier must not stall the plan behind a build
+        assert tables.calls == probes
+
+    @pytest.mark.parametrize("n,route,tiers", [
+        (100, "host", HOST_WALK),
+        (150, "device", ["generic"] + HOST_WALK),
+    ])
+    def test_disable_precompute_skips_the_tables(
+        self, n, route, tiers, monkeypatch
+    ):
+        monkeypatch.setenv("CMT_TPU_DISABLE_PRECOMPUTE", "1")
+        tables = FakeTables(True)
+        plan = self.plan(monkeypatch, n, tables,
+                         device_min_batch=THRESHOLD)
+        assert (plan.route, plan.reason) == (route, "batch_size")
+        assert plan.tiers == tiers and tables.calls == []
+
+    @pytest.mark.parametrize("build,faulted", [
+        ("none", False), ("raise", True),
+    ])
+    def test_no_tables_leaves_generic(self, build, faulted, monkeypatch):
+        """Out of table policy (None) is no fault; a lookup that
+        raises faults the keyed tier.  Either way the batch goes on."""
+        plan = self.plan(monkeypatch, 150, FakeTables(False, build),
+                         device_min_batch=THRESHOLD)
+        assert plan.route == "device" and plan.entry is None
+        assert plan.tiers == ["generic"] + HOST_WALK
+        assert dispatch.LADDER.active("keyed") is not faulted
+
+
+class TestBlsStaticWalks:
+    class Pub:
+        def type(self):
+            from cometbft_tpu.crypto import bls12381
+
+            return bls12381.KEY_TYPE
+
+    def verifier(self, mode: str):
+        from cometbft_tpu.crypto import bls12381, bls_dispatch
+
+        v = bls_dispatch.BlsLadderVerifier()
+        sig = bytes(bls12381.SIGNATURE_SIZE)
+        if mode == "aggregate":
+            v.set_aggregate([self.Pub()] * 3, b"m", sig)
+        else:
+            for _ in range(3):
+                v.add(self.Pub(), b"m", sig)
+        return v
+
+    @pytest.mark.parametrize("mode,native,tiers", [
+        ("aggregate", True, ["bls_native", "python"]),
+        ("batch", True, ["bls_native", "host", "python"]),
+        ("aggregate", False, ["python"]),
+        ("batch", False, ["host", "python"]),
+    ])
+    def test_walks(self, mode, native, tiers, cm, dispatch_env,
+                   monkeypatch):
+        from cometbft_tpu.crypto import bls_dispatch
+
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        monkeypatch.setattr(
+            bls_dispatch.bls_native, "available", lambda: native
+        )
+        plan = self.verifier(mode).plan()
+        assert plan.mode == mode and plan.tiers == tiers
+
+    def test_demoted_native_leaves_the_walk(self, cm, dispatch_env,
+                                            monkeypatch):
+        from cometbft_tpu.crypto import bls_dispatch
+
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        monkeypatch.setattr(
+            bls_dispatch.bls_native, "available", lambda: True
+        )
+        dispatch.LADDER.tier_fault("bls_native", reason="watchdog")
+        assert self.verifier("batch").plan().tiers == HOST_WALK
+
+
+# -- the batch counter (LADDER.cost_snapshot) and its readers ------------
+
+
+class TestShapeBucket:
+    def test_pow2_ceiling(self):
+        assert dispatch.shape_bucket(0) == 1
+        assert dispatch.shape_bucket(1) == 1
+        assert dispatch.shape_bucket(2) == 2
+        assert dispatch.shape_bucket(3) == 4
+        assert dispatch.shape_bucket(64) == 64
+        assert dispatch.shape_bucket(150) == 256
+        assert dispatch.shape_bucket(10_000) == 16384
+
+    def test_capped(self):
+        assert dispatch.shape_bucket(1 << 30) == dispatch.MAX_SHAPE_BUCKET
+
+
+class TestBatchCounter:
+    @pytest.mark.parametrize("family,tier,n,bucket", [
+        ("ed25519", "keyed", 150, 256),
+        ("ed25519", "keyed_mesh", 1000, 1024),
+        ("ed25519", "generic", 1 << 21, dispatch.MAX_SHAPE_BUCKET),
+        ("ed25519", "host", 1, 1),
+        ("bls", "host", 3, 4),
+        ("bls", "bls_native", 8, 8),
+        ("bls_agg", "bls_native", 150, 256),
+    ])
+    def test_one_row_per_family_tier_bucket(
+        self, family, tier, n, bucket, cm
+    ):
+        ladder = make_ladder(Clock())
+        for samples in (1, 2):
+            ladder.note_batch(tier, batch=n, seconds=0.01, family=family)
+            assert ladder.cost_snapshot() == {"table": [{
+                "family": family, "tier": tier, "bucket": bucket,
+                "samples": samples,
+            }]}
+
+    @pytest.mark.parametrize("tier,batch,seconds", [
+        ("python", 150, 0.01),   # the floor is not in the table
+        ("keyed", 0, 0.01),      # no shape
+        ("keyed", 150, None),    # no measured wall
+        ("keyed", 150, 0.0),
+        ("warp", 150, 0.01),     # not a rung
+    ])
+    def test_not_counted(self, tier, batch, seconds, cm):
+        ladder = make_ladder(Clock())
+        ladder.note_batch(tier, batch=batch, seconds=seconds)
+        assert ladder.cost_snapshot() == {"table": []}
+        # the per-tier metric counts every call all the same
+        assert counter_value(cm.dispatch_tier, tier=tier) == 1
+
+    def test_rows_sorted_and_reset_empties(self, cm, dispatch_env):
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        for tier, n in (("keyed", 1000), ("host", 2), ("keyed", 150)):
+            dispatch.LADDER.note_batch(tier, batch=n, seconds=0.01)
+        assert [
+            (r["tier"], r["bucket"])
+            for r in dispatch.LADDER.cost_snapshot()["table"]
+        ] == [("host", 2), ("keyed", 256), ("keyed", 1024)]
+        dispatch.LADDER.reset()
+        assert dispatch.LADDER.cost_snapshot() == {"table": []}
+
+    def test_faulted_tier_not_counted_answering_rung_is(
+        self, cm, dispatch_env, verifier_cls
+    ):
+        dispatch_env(
+            CMT_TPU_CHAOS="1",
+            CMT_TPU_CHAOS_PLAN="device_loss@0-3600",
+            CMT_TPU_COOLDOWN_S="30",
+        )
+        bv = _fill(verifier_cls(device_min_batch=1), 3)
+        ok, _ = bv.verify()
+        assert ok and bv._last_tier == "host"
+        assert dispatch.LADDER.cost_snapshot()["table"] == [{
+            "family": "ed25519", "tier": "host", "bucket": 4,
+            "samples": 1,
+        }]
+
+    def test_coalesced_submission_counts_once_at_buffer_shape(
+        self, cm, dispatch_env, verifier_cls, monkeypatch
+    ):
+        """The queue's collector hands plan() the COALESCED buffer: one
+        8-sig submission is one batch in bucket 8, not eight bucket-1
+        fragments."""
+        from cometbft_tpu.crypto import verify_queue as vq
+
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        monkeypatch.setattr(
+            verifier_cls, "_run_generic",
+            lambda self, pub, sig, msgs: np.ones(len(msgs), dtype=bool),
+        )
+        priv = ed.priv_key_from_secret(b"qshape")
+        msg = b"qshape-msg"
+        sig = priv.sign(msg)
+        q = vq.VerifyQueue(
+            verifier_factory=lambda pk: verifier_cls(device_min_batch=1),
+            use_cache=False,
+        )
+        q.start()
+        try:
+            futs = q.submit_many([(priv.pub_key(), msg, sig)] * 8)
+            assert all(f.result(30) for f in futs)
+        finally:
+            q.stop()
+        assert dispatch.LADDER.cost_snapshot()["table"] == [{
+            "family": "ed25519", "tier": "generic", "bucket": 8,
+            "samples": 1,
+        }]
+
+
+class TestBenchmarkReadsTheCounter:
+    """benchmark/observe.py and readers/device_share.py read the
+    counter by name (``cost_snapshot``, ``ROUTE_FAMILY_ED25519``):
+    ``device_sig_pct.*`` must read what it read before."""
+
+    DEVICE = ["keyed_mesh", "keyed", "generic_mesh", "generic"]
+
+    def test_counters_key_batches_by_tier_and_bucket(
+        self, cm, dispatch_env
+    ):
+        from benchmark import observe
+
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        was = observe.counters()
+        assert was["batches"] == {}
+        note = dispatch.LADDER.note_batch
+        note("keyed", batch=150, seconds=0.004)
+        note("keyed", batch=150, seconds=0.004)
+        note("keyed", batch=1000, seconds=0.014)
+        note("host", batch=1, seconds=0.0001)
+        note("python", batch=150, seconds=0.3)
+        # another family's rows are not the ed25519 cells' business
+        note("bls_native", batch=150, seconds=0.01, family="bls_agg")
+        now = observe.counters()
+        assert now["batches"] == {
+            "host/1": 1, "keyed/256": 2, "keyed/1024": 1,
+        }
+        assert now["tiers"]["python"] == 1
+        assert observe.delta(now, was)["batches"] == now["batches"]
+        assert now["transitions"] == 0
+
+    @pytest.mark.parametrize("batches,pct", [
+        ([("keyed", 150), ("keyed", 1000)], 100.0),
+        ([("host", 150), ("host", 1)], 0.0),
+        ([("keyed", 150), ("host", 150), ("host", 150), ("host", 150)],
+         25.0),
+        ([("keyed", 1000), ("host", 1)], 100.0 * 1024 / 1025),
+        ([], None),
+    ], ids=["device", "host", "quarter", "warmup_host", "none"])
+    def test_device_share_reads_the_ladder(
+        self, batches, pct, cm, dispatch_env
+    ):
+        from benchmark import observe
+        from benchmark.readers import device_share
+
+        dispatch_env(CMT_TPU_COOLDOWN_S="30")
+        for tier, n in batches:
+            dispatch.LADDER.note_batch(tier, batch=n, seconds=0.01)
+        ctx = {"counters": observe.counters()}
+        assert device_share.read(
+            ctx, {"device_tiers": self.DEVICE}
+        ) == pct
+
+
 # -- race-mode harness over the new guarded classes ----------------------
 
 
@@ -845,6 +1223,13 @@ class TestDebugDispatchSurfaces:
         assert payload["chaos"]["windows"] == [
             {"kind": "device_loss", "start_s": 1.0, "end_s": 2.0}
         ]
+        assert set(payload) == {"ladder", "chaos", "batches"}
+        assert payload["batches"] == []
+        dispatch.LADDER.note_batch("host", batch=150, seconds=0.01)
+        assert dispatch.debug_dispatch_payload()["batches"] == [
+            {"family": "ed25519", "tier": "host", "bucket": 256,
+             "samples": 1}
+        ]
         json.dumps(payload)  # must be JSON-serializable as served
 
     def test_debug_dispatch_http_and_index(self, cm, dispatch_env):
@@ -853,6 +1238,10 @@ class TestDebugDispatchSurfaces:
         dispatch_env(CMT_TPU_COOLDOWN_S="30")
         dispatch.LADDER.admissible(["keyed"])
         dispatch.LADDER.tier_fault("keyed", reason="probe_failures")
+        dispatch.LADDER.note_batch(
+            "bls_native", batch=8, seconds=0.01,
+            family=dispatch.ROUTE_FAMILY_BLS_AGG,
+        )
         srv = MetricsServer(Registry(), "127.0.0.1:0")
         srv.start()
         try:
@@ -862,6 +1251,11 @@ class TestDebugDispatchSurfaces:
             ).read())
             assert body["ladder"]["tiers"]["keyed"]["demoted"] is True
             assert body["ladder"]["transitions"][-1]["kind"] == "demote"
+            assert body["chaos"]["enabled"] is False
+            assert body["batches"] == [
+                {"family": "bls_agg", "tier": "bls_native", "bucket": 8,
+                 "samples": 1}
+            ]
             index = json.loads(urllib.request.urlopen(
                 base + "/debug", timeout=5
             ).read())
@@ -877,7 +1271,7 @@ class TestDebugDispatchSurfaces:
         dispatch_env(CMT_TPU_COOLDOWN_S="30")
         assert "debug/dispatch" in _INSPECT_ROUTES
         payload = Environment().routes()["debug/dispatch"]()
-        assert "ladder" in payload and "chaos" in payload
+        assert set(payload) == {"ladder", "chaos", "batches"}
 
 
 # -- sealed JITGUARD through ladder transitions --------------------------

@@ -587,61 +587,6 @@ class TestSustainedHarness:
         assert rep["steps"][0]["offered_per_sec"] <= 80
 
 
-# -- /debug/dispatch measured per-tier throughput (ISSUE 10 satellite) ---
-
-
-class TestDispatchMeasuredThroughput:
-    def test_payload_surfaces_ledger_and_contradictions(
-        self, tmp_path, monkeypatch
-    ):
-        import json as _json
-
-        from cometbft_tpu.crypto.dispatch import debug_dispatch_payload
-        from cometbft_tpu.crypto.health import measured_tier_throughput
-
-        ledger = tmp_path / "ledger.json"
-        ledger.write_text(_json.dumps({"schema": 1, "entries": [
-            {"config": "old_keyed", "value": 9000.0,
-             "unit": "sigs/sec", "dispatch_tier": "keyed"},
-            # same tier later: recency wins
-            {"config": "new_keyed", "value": 12000.0,
-             "unit": "sigs/sec", "dispatch_tier": "keyed"},
-            # host measures FASTER than the preferred keyed tier —
-            # the r05 shape the surface exists to expose
-            {"config": "host_msm", "value": 50000.0,
-             "unit": "sigs/sec", "dispatch_tier": "host"},
-            # device-down zero: availability, not perf — skipped
-            {"config": "dead", "value": 0,
-             "unit": "sigs/sec", "dispatch_tier": "generic"},
-            # wrong unit: not a throughput point
-            {"config": "lat", "value": 5.0,
-             "unit": "ms", "dispatch_tier": "generic_mesh"},
-        ]}))
-        monkeypatch.setenv("CMT_TPU_PERF_LEDGER", str(ledger))
-        measured = measured_tier_throughput()
-        assert measured["keyed"]["sigs_per_sec"] == 12000.0
-        assert measured["keyed"]["config"] == "new_keyed"
-        assert "generic" not in measured  # zero skipped
-        assert "generic_mesh" not in measured  # wrong unit skipped
-        payload = debug_dispatch_payload()
-        assert payload["measured_tier_throughput"] == measured
-        contr = payload["order_contradictions"]
-        assert any(
-            c["preferred"] == "keyed" and c["faster"] == "host"
-            for c in contr
-        ), contr
-
-    def test_empty_ledger_is_quiet(self, tmp_path, monkeypatch):
-        from cometbft_tpu.crypto.dispatch import debug_dispatch_payload
-
-        monkeypatch.setenv(
-            "CMT_TPU_PERF_LEDGER", str(tmp_path / "absent.json")
-        )
-        payload = debug_dispatch_payload()
-        assert payload["measured_tier_throughput"] == {}
-        assert payload["order_contradictions"] == []
-
-
 # -- the ingest-smoke node drive (make ingest-smoke) ---------------------
 
 

@@ -12,10 +12,10 @@ refuses the CPU backend:
 
 Each of the four sizes is one cold compile of the generic kernel
 (about a minute).
-The output is evidence for whoever sets dispatch policy (the static
-floor ops/ed25519_verify.DEVICE_MIN_BATCH, the cost router's seed); no
-code reads it.  On the v5e of PR 22 the generic kernel won from 1,024
-signatures up (CHANGES.md).
+The output is evidence for whoever sets dispatch policy (the
+constants ops/ed25519_verify.DEVICE_MIN_BATCH and
+ACCELERATOR_MIN_BATCH); no code reads it.  On the v5e of PR 22 the
+generic kernel won from 1,024 signatures up (CHANGES.md).
 """
 
 from __future__ import annotations
