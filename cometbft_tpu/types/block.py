@@ -215,19 +215,51 @@ class Commit:
     def size(self) -> int:
         return len(self.signatures)
 
+    # the sign-bytes this commit has encoded: ``(chain_id, slots)``,
+    # one slot a signature.  Not a field (no annotation): set on the
+    # instance with object.__setattr__, so replace(), ==, hash, repr,
+    # the codec and a public vars() dump never see it.  The commit is
+    # frozen, so a slot cannot go stale; two threads filling one slot
+    # write equal bytes, so there is no lock.
+    _sign_bytes = (None, ())
+
+    def _sign_bytes_slots(self, chain_id: str) -> list:
+        """The slots kept for ``chain_id``: one chain id's at a time,
+        replaced when the id differs."""
+        memo_id, slots = self._sign_bytes
+        if memo_id != chain_id:
+            slots = [None] * len(self.signatures)
+            object.__setattr__(self, "_sign_bytes", (chain_id, slots))
+        return slots
+
     def vote_sign_bytes(self, chain_id: str, idx: int) -> bytes:
         """Reconstruct the canonical sign-bytes of validator idx's
         precommit (types/block.go:902 — the per-signature distinct
-        message consumed by batch verification)."""
-        cs = self.signatures[idx]
-        return canonical.vote_sign_bytes(
-            chain_id,
-            canonical.PRECOMMIT_TYPE,
-            self.height,
-            self.round,
-            cs.block_id(self.block_id),
-            cs.timestamp_ns,
-        )
+        message consumed by batch verification).  Encoded once a
+        commit and chain id: a prefetch's encoding is what the check
+        of the same object reads."""
+        memo_id, slots = self._sign_bytes
+        if memo_id != chain_id:  # else no call: a read costs a lookup
+            slots = self._sign_bytes_slots(chain_id)
+        sb = slots[idx]
+        if sb is None:
+            cs = self.signatures[idx]
+            sb = slots[idx] = canonical.vote_sign_bytes(
+                chain_id,
+                canonical.PRECOMMIT_TYPE,
+                self.height,
+                self.round,
+                cs.block_id(self.block_id),
+                cs.timestamp_ns,
+            )
+        return sb
+
+    def sign_bytes_missing(self, chain_id: str, idxs) -> int:
+        """How many of the votes ``idxs`` this commit has not encoded
+        under ``chain_id`` yet: what a following ``vote_sign_bytes``
+        pass over them will have to encode."""
+        slots = self._sign_bytes_slots(chain_id)
+        return sum(1 for i in idxs if slots[i] is None)
 
     def aggregate_sign_bytes(self, chain_id: str) -> bytes:
         """The ONE canonical message every aggregate-covered precommit

@@ -329,8 +329,12 @@ def _crypto_pass(
         pks = [vals.get_by_index(e.val_idx).pub_key for e in group]
         with _tracer.span(
             "verify_commit/sign_bytes", cat="crypto", sigs=len(group),
-        ):
-            sbs = [commit.vote_sign_bytes(chain_id, e.idx) for e in group]
+        ) as sb_span:
+            # encoded: how many of the group's sign-bytes this span has
+            # to encode (0 where a prefetch of this commit object did)
+            idxs = [e.idx for e in group]
+            sb_span.set(encoded=commit.sign_bytes_missing(chain_id, idxs))
+            sbs = [commit.vote_sign_bytes(chain_id, i) for i in idxs]
         pending = list(range(len(group)))
         keys: list[bytes] | None = None
         if _vq.speculation_active():

@@ -107,6 +107,37 @@ def test_the_root_names_its_mode(device_route, fn, mode):
     assert root["args"]["sigs"] == {"light": 5, "trusting": 3}[mode]
 
 
+@pytest.mark.parametrize("needed, encoded", [
+    ("first_sight", 6), (None, 0), (6 * 10 * 2 // 3, 1),
+], ids=["first_sight", "prefetched", "prefetched_to_two_thirds"])
+def test_the_sign_bytes_span_says_what_it_encoded(
+    device_route, needed, encoded,
+):
+    """``encoded`` beside ``sigs``: all of them on a commit seen for the
+    first time, none after a prefetch of the same object, the votes
+    beyond the cut after a prefetch that stopped at two thirds."""
+    vals, bid, commit = _commit(b"stage-encoded")
+    if needed != "first_sight":
+        triples = validation.commit_check_triples(
+            CHAIN_ID, vals, commit, needed
+        )
+        assert len(triples) == 6 - encoded
+
+    def sign_bytes_args(check) -> dict:
+        _, events = _spans_of(
+            lambda: check(CHAIN_ID, vals, bid, 1, commit)
+        )
+        return next(
+            e for e in events if e["name"] == "verify_commit/sign_bytes"
+        )["args"]
+
+    args = sign_bytes_args(validation.verify_commit)
+    assert args["sigs"] == 6 and args["encoded"] == encoded
+    # a second check of the same object encodes nothing
+    args = sign_bytes_args(validation.verify_commit_light)
+    assert args["sigs"] == 5 and args["encoded"] == 0
+
+
 def test_queue_on_the_commit_consults_and_records(device_route):
     vals, bid, commit = _commit(b"stage-on")
     q = vq.VerifyQueue()

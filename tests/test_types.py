@@ -8,8 +8,11 @@ import pytest
 from cometbft_tpu.crypto import ed25519 as ed
 from cometbft_tpu.types import (
     BLOCK_ID_FLAG_ABSENT,
+    BLOCK_ID_FLAG_COMMIT,
+    BLOCK_ID_FLAG_NIL,
     Block,
     BlockID,
+    Commit,
     CommitSig,
     ConflictingVoteError,
     Data,
@@ -441,3 +444,176 @@ class TestVoteCodec:
         v = signed_vote(keys[0], 0, NIL_BLOCK_ID)
         rt = Vote.decode(v.encode())
         assert rt.is_nil() and rt == v
+
+
+_T0 = 1_700_000_000_000_000_000
+
+
+def _mixed_commit() -> Commit:
+    """COMMIT, NIL, absent and a COMMIT vote beside the absent one,
+    every vote with a timestamp of its own."""
+    return Commit(
+        height=3,
+        round=1,
+        block_id=make_block_id(b"memo"),
+        signatures=(
+            CommitSig(BLOCK_ID_FLAG_COMMIT, b"\x01" * 20, _T0 + 1, b"a" * 64),
+            CommitSig(BLOCK_ID_FLAG_NIL, b"\x02" * 20, _T0 + 2, b"b" * 64),
+            CommitSig(BLOCK_ID_FLAG_ABSENT),
+            CommitSig(BLOCK_ID_FLAG_COMMIT, b"\x04" * 20, _T0 + 4, b"d" * 64),
+        ),
+    )
+
+
+def _fresh_sign_bytes(commit: Commit, chain_id: str, idx: int) -> bytes:
+    cs = commit.signatures[idx]
+    return canonical.vote_sign_bytes(
+        chain_id, PRECOMMIT_TYPE, commit.height, commit.round,
+        cs.block_id(commit.block_id), cs.timestamp_ns,
+    )
+
+
+def _timed_commit(n: int = 6):
+    """A signed commit of ``n`` equal validators whose vote ``i`` has
+    timestamp ``T0 + i``: an encoding names the vote it encoded."""
+    vals, keys = make_val_set(n)
+    bid = make_block_id(b"memo-signed")
+    vs = VoteSet(CHAIN_ID, 1, 0, PRECOMMIT_TYPE, vals)
+    for i, key in enumerate(keys):
+        vs.add_vote(signed_vote(key, i, bid, time_ns=_T0 + i))
+    return vals, bid, vs.make_commit()
+
+
+@pytest.fixture
+def timed(monkeypatch):
+    """``(vals, block_id, commit, encoded)``: a :func:`_timed_commit`
+    and the vote indices ``canonical.vote_sign_bytes`` is asked to
+    encode from here on, in order."""
+    vals, bid, commit = _timed_commit()
+    encoded: list[int] = []
+    real = canonical.vote_sign_bytes
+
+    def counting(chain_id, vote_type, height, round_, block_id, time_ns):
+        encoded.append(time_ns - _T0)
+        return real(chain_id, vote_type, height, round_, block_id, time_ns)
+
+    monkeypatch.setattr(canonical, "vote_sign_bytes", counting)
+    return vals, bid, commit, encoded
+
+
+class TestCommitSignBytesMemo:
+    """A commit keeps the sign-bytes it encodes (ISSUE 31): the bytes
+    are canonical's, the memo is invisible, a copy starts without."""
+
+    @pytest.mark.parametrize("chain_id", [CHAIN_ID, "other-chain"])
+    @pytest.mark.parametrize(
+        "idx", [0, 1, 3], ids=["commit", "nil", "absent_neighbour"]
+    )
+    def test_memoised_bytes_are_canonicals_and_kept(self, idx, chain_id):
+        commit = _mixed_commit()
+        first = commit.vote_sign_bytes(chain_id, idx)
+        assert first == _fresh_sign_bytes(commit, chain_id, idx)
+        assert commit.vote_sign_bytes(chain_id, idx) is first
+        # one chain id's list at a time: another id encodes afresh,
+        # and coming back encodes the first id's bytes again
+        other = chain_id + "-b"
+        assert commit.vote_sign_bytes(other, idx) == _fresh_sign_bytes(
+            commit, other, idx
+        )
+        again = commit.vote_sign_bytes(chain_id, idx)
+        assert again == first and again is not first
+        # the neighbours were not encoded along the way
+        assert commit.sign_bytes_missing(chain_id, range(4)) == 3
+
+    @pytest.mark.parametrize("change", [
+        lambda c: {"signatures": tuple(
+            replace(cs, timestamp_ns=cs.timestamp_ns + 7)
+            for cs in c.signatures
+        )},
+        lambda c: {"round": c.round + 1},
+        lambda c: {"block_id": make_block_id(b"memo-other")},
+    ], ids=["signatures", "round", "block_id"])
+    def test_a_replaced_commit_carries_no_memo(self, change):
+        commit = _mixed_commit()
+        old = [commit.vote_sign_bytes(CHAIN_ID, i) for i in range(4)]
+        copy = replace(commit, **change(commit))
+        assert "_sign_bytes" not in vars(copy)
+        assert copy.sign_bytes_missing(CHAIN_ID, range(4)) == 4
+        new = [copy.vote_sign_bytes(CHAIN_ID, i) for i in range(4)]
+        assert new == [_fresh_sign_bytes(copy, CHAIN_ID, i) for i in range(4)]
+        assert new[0] != old[0] and new[3] != old[3]
+        # and the original still answers with what it kept
+        assert all(
+            commit.vote_sign_bytes(CHAIN_ID, i) is old[i] for i in range(4)
+        )
+
+    @pytest.mark.parametrize("facet", [
+        lambda c: c == _mixed_commit(),
+        hash,
+        repr,
+        Commit.hash,
+        lambda c: codec.encode_commit(c),
+        lambda c: codec.decode_commit(codec.encode_commit(c)) == c,
+        lambda c: sorted(k for k in vars(c) if not k.startswith("_")),
+    ], ids=["eq", "hash", "repr", "commit_hash", "encode", "round_trip",
+            "public_vars"])
+    def test_the_memo_is_invisible(self, facet):
+        commit = _mixed_commit()
+        before = facet(commit)
+        for i in range(4):
+            commit.vote_sign_bytes(CHAIN_ID, i)
+        assert "_sign_bytes" in vars(commit)
+        assert facet(commit) == before
+        assert before is not False  # the eq facets held to begin with
+
+    def test_a_check_after_the_prefetch_encodes_nothing(self, timed):
+        vals, bid, commit, encoded = timed
+        triples = validation.commit_check_triples(CHAIN_ID, vals, commit)
+        assert encoded == list(range(6))
+        validation.verify_commit_light(CHAIN_ID, vals, bid, 1, commit)
+        validation.verify_commit(CHAIN_ID, vals, bid, 1, commit)
+        assert encoded == list(range(6))
+        assert all(
+            commit.vote_sign_bytes(CHAIN_ID, i) is t[1]
+            for i, t in enumerate(triples)
+        )
+
+    def test_a_check_encodes_only_beyond_the_prefetchs_cut(self, timed):
+        vals, bid, commit, encoded = timed
+        needed = vals.total_voting_power() * 2 // 3
+        cut = validation.commit_check_triples(CHAIN_ID, vals, commit, needed)
+        assert len(cut) == 5 and encoded == list(range(5))
+        validation.verify_commit(CHAIN_ID, vals, bid, 1, commit)
+        assert encoded == list(range(6))
+
+    def test_two_checks_of_a_commit_encode_each_index_once(self, timed):
+        vals, bid, commit, encoded = timed
+        validation.verify_commit_light_trusting(CHAIN_ID, vals, commit)
+        assert sorted(encoded) == [0, 1, 2]
+        validation.verify_commit_light(CHAIN_ID, vals, bid, 1, commit)
+        assert sorted(encoded) == [0, 1, 2, 3, 4]
+
+    @pytest.mark.parametrize("tamper", [
+        lambda cs: replace(
+            cs, signature=bytes([cs.signature[0] ^ 1]) + cs.signature[1:]
+        ),
+        lambda cs: replace(cs, timestamp_ns=cs.timestamp_ns + 100),
+    ], ids=["signature", "timestamp"])
+    def test_a_tampered_copy_encodes_its_own_bytes(self, timed, tamper):
+        vals, bid, commit, encoded = timed
+        validation.commit_check_triples(CHAIN_ID, vals, commit)
+        del encoded[:]
+        sigs = list(commit.signatures)
+        sigs[2] = tamper(sigs[2])
+        bad = replace(commit, signatures=tuple(sigs))
+        with pytest.raises(
+            validation.InvalidCommitSignatures,
+            match=r"wrong signature \(#2\)",
+        ):
+            validation.verify_commit(CHAIN_ID, vals, bid, 1, bad)
+        want = [0, 1, 2, 3, 4, 5]
+        want[2] = sigs[2].timestamp_ns - _T0
+        assert encoded == want
+        # the prefetched original is untouched and still accepted
+        validation.verify_commit(CHAIN_ID, vals, bid, 1, commit)
+        assert encoded == want
