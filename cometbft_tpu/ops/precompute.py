@@ -74,6 +74,7 @@ from cometbft_tpu.ops import jitguard
 from cometbft_tpu.ops.ed25519_verify import _next_pow2
 from cometbft_tpu.utils import sync as cmtsync
 from cometbft_tpu.utils.env import int_from_env
+from cometbft_tpu.utils.trace import TRACER as _tracer
 
 #: largest set that gets 8-bit per-key combs (4 MiB/key on device)
 KEY8_MAX = int_from_env("CMT_TPU_KEY8_MAX", 256)
@@ -81,6 +82,12 @@ KEY8_MAX = int_from_env("CMT_TPU_KEY8_MAX", 256)
 TABLE_MAX_KEYS = int_from_env("CMT_TPU_TABLE_MAX_KEYS", 16384)
 #: total device bytes across cached sets before LRU eviction
 TABLE_CACHE_MB = int_from_env("CMT_TPU_TABLE_CACHE_MB", 6144)
+#: most keys one build call computes pages for.  A build holds its
+#: output and 2-3x that in temporaries (1,024 4-bit keys: 0.5 GiB out,
+#: 1.3 GiB scratch on the v5e), so a 10,000-key set is built and placed
+#: a chunk at a time; 1,024 is a shape the 1,000-validator sets compile
+#: anyway.
+BUILD_CHUNK = 1024
 
 
 # -- fixed-base 8-bit comb (host-built, shared) ------------------------
@@ -392,7 +399,9 @@ def _pool_cap(nkeys: int) -> int:
     then 2048-slot steps) so the shape-specialized verify kernel only
     retraces a bounded number of times — while avoiding pow2's up-to-2x
     HBM waste at large validator counts (10k keys: 10240 slots =
-    5 GiB at 4-bit, vs 16384 slots = 8 GiB)."""
+    5 GiB at 4-bit, vs 16384 slots = 8 GiB; measured on one v5e, PR 33:
+    5,368,709,120 B resident, 12,096,562,688 B at the chunked build's
+    peak — docs/device_kernel_perf.md §2.1)."""
     if nkeys <= 4096:
         return _next_pow2(max(nkeys, 1))
     return -(-nkeys // 2048) * 2048
@@ -534,7 +543,7 @@ class KeyTableCache:
         self._entries: OrderedDict[bytes, tuple[int, KeySetTables]] = (
             OrderedDict()
         )
-        self.stats = {"keys_built": 0, "keys_evicted": 0}
+        self.stats = {"keys_built": 0, "keys_evicted": 0, "build_chunks": 0}
 
     def _set_key(self, pubs: list[bytes]):
         """The dispatch-policy prologue shared by peek and
@@ -593,28 +602,26 @@ class KeyTableCache:
                     ev.wait()
                 continue
             try:
-                pages, page_valid = self._build_pages(missing, window_bits)
-                # stage any pool growth outside the lock: the pad is a
-                # device copy of the whole table, and cached-set
-                # lookups must not queue behind it
-                with self._lock:
-                    snap = (pool.version, pool.table, pool.cap)
-                    need = len(pool.slots) + len(missing)
-                staged = pool.stage_growth(*snap, need)
-                with self._lock:
-                    pool.ensure_capacity(
-                        len(pool.slots) + len(missing), staged=staged
+                chunks = [
+                    missing[i : i + BUILD_CHUNK]
+                    for i in range(0, len(missing), BUILD_CHUNK)
+                ]
+                for k, chunk in enumerate(chunks):
+                    pages, page_valid = self._build_pages(
+                        chunk, window_bits, k + 1, len(chunks)
                     )
-                    slots = [pool.free.pop() for _ in missing]
-                    pool.table = pool.table.at[
-                        jnp.asarray(slots, dtype=jnp.int32)
-                    ].set(pages[: len(missing)])
-                    pool.version += 1
-                    for i, (p, s) in enumerate(zip(missing, slots)):
-                        pool.slots[p] = s
-                        pool.valid[s] = page_valid[i]
-                    self.stats["keys_built"] += len(missing)
-                    _crypto_metrics().key_pool_builds.inc(len(missing))
+                    self.stats["build_chunks"] += 1
+                    # the pool grows ONCE, with the first chunk, to
+                    # hold every missing key; between chunks the write
+                    # is waited for, so that the next build's scratch
+                    # is never allocated beside the pool being copied
+                    self._place_pages(
+                        pool, chunk, pages, page_valid,
+                        room=len(missing) - k * BUILD_CHUNK,
+                        wait=len(chunks) > 1,
+                    )
+                    del pages
+                with self._lock:
                     self._evict_over_budget(keep=set(unique))
                     self._update_pool_gauges()
                     # a concurrent lookup's eviction may have dropped
@@ -656,10 +663,13 @@ class KeyTableCache:
             self._entries.popitem(last=False)
         return entry
 
-    def _build_pages(self, missing: list[bytes], window_bits: int):
+    def _build_pages(
+        self, missing: list[bytes], window_bits: int, chunk: int, of: int
+    ):
         """EC-compute comb pages for ``missing`` keys (device kernel,
-        pow2-padded with B's encoding). Runs OUTSIDE the cache lock so
-        cached-set lookups aren't blocked behind a build."""
+        pow2-padded with B's encoding) — chunk ``chunk`` of ``of`` of
+        one lookup's build. Runs OUTSIDE the cache lock so cached-set
+        lookups aren't blocked behind a build."""
         n = len(missing)
         n_pad = _next_pow2(n)
         pub = np.zeros((32, n_pad), dtype=np.uint8)
@@ -668,14 +678,57 @@ class KeyTableCache:
         if n_pad > n:
             pub[:, n:] = _B_ENC[:, None]
         fn = _compiled_build(n_pad, window_bits)
-        from cometbft_tpu.utils.trace import TRACER as _tracer
-
         with _tracer.span(
-            "table_build", cat="device", keys=n, window_bits=window_bits
+            "table_build", cat="device", keys=n, window_bits=window_bits,
+            chunk=chunk, of=of,
         ):
             table, valid = fn(jax.device_put(pub))
             valid = jax.device_get(valid)[:n]  # host sync: per-build validity fetch (build path, not the verify hot loop)
         return table, valid
+
+    def _place_pages(
+        self, pool: _KeyPool, keys: list[bytes], pages, page_valid,
+        room: int, wait: bool,
+    ) -> None:
+        """Write one build call's pages into free slots of the pool,
+        grown first to hold ``room`` more keys (``keys`` and whatever
+        of the same lookup is still to build).  The write is a COPY of
+        the pool, never a donated in-place update: memoized entries,
+        plans in flight and a concurrent ``stage_growth`` snapshot hold
+        the old array, and a donated buffer would be deleted under
+        their launch.  So for the length of a placement the device
+        holds the pool twice, and one build's pages; ``wait`` blocks
+        (lock released) until the old pool can go."""
+        n = len(keys)
+        with _tracer.span(
+            "table_build/place", cat="device", keys=n,
+        ) as sp:
+            # stage any pool growth outside the lock: the pad is a
+            # device copy of the whole table, and cached-set
+            # lookups must not queue behind it
+            with self._lock:
+                snap = (pool.version, pool.table, pool.cap)
+                need = len(pool.slots) + room
+            staged = pool.stage_growth(*snap, need)
+            with self._lock:
+                pool.ensure_capacity(len(pool.slots) + room, staged=staged)
+                # the slots ``pop()`` would hand out, taken off the
+                # free list only once the write is dispatched
+                slots = pool.free[: -n - 1 : -1]
+                pool.table = pool.table.at[
+                    jnp.asarray(slots, dtype=jnp.int32)
+                ].set(pages if pages.shape[0] == n else pages[:n])
+                del pool.free[-n:]
+                pool.version += 1
+                for i, (p, s) in enumerate(zip(keys, slots)):
+                    pool.slots[p] = s
+                    pool.valid[s] = page_valid[i]
+                self.stats["keys_built"] += n
+                _crypto_metrics().key_pool_builds.inc(n)
+                sp.set(slots=len(pool.slots), cap=pool.cap)
+                placed = pool.table
+            if wait:
+                placed.block_until_ready()  # host sync: between the chunks of one build the old pool must be released before the next build allocates (build path, not the verify hot loop)
 
     def _sweep_stale_entries(self) -> None:
         """Drop memoized entries whose pool version moved on.  Lock

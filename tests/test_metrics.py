@@ -168,7 +168,7 @@ class TestCryptoMetrics:
         reg, m = self._install()
         cache = PR.KeyTableCache(cap_bytes=4 << 20)  # ~1 key at 8-bit
 
-        def fake_build(missing, window_bits):
+        def fake_build(missing, window_bits, *chunk_of):
             # shapes the insert path expects, no EC compute
             n_pad = max(len(missing), 1)
             n_pad = 1 << (n_pad - 1).bit_length() if n_pad > 1 else 1

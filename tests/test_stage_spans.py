@@ -217,3 +217,37 @@ def test_queue_on_a_prefetch_fires_every_queue_stage_once(device_route):
     assert prep["ts"] + prep["dur"] <= park["ts"] + 1.0
     assert park["ts"] + park["dur"] <= by["verify_queue/launch"]["ts"] + 1.0
     q.stop()
+
+
+def test_a_chunked_table_build_leaves_a_span_a_chunk(monkeypatch):
+    """One ``table_build`` span a build call, numbered ``chunk`` of
+    ``of``, and one ``table_build/place`` span a placement into the
+    pool (ISSUE 33): 5 keys in chunks of 4 are two of each."""
+    from cometbft_tpu.ops import precompute as PR
+
+    monkeypatch.setattr(PR, "KEY8_MAX", 0)  # 4-bit pages: small builds
+    monkeypatch.setattr(PR, "BUILD_CHUNK", 4)
+    pubs = [ed.priv_key_from_secret(b"span/%d" % i).pub_key().bytes()
+            for i in range(5)]
+    cache = PR.KeyTableCache()
+    was = TRACER.enabled
+    TRACER.set_enabled(True)
+    try:
+        names, events = _spans_of(lambda: cache.lookup_or_build(pubs))
+    finally:
+        TRACER.set_enabled(was)
+    assert names["table_build"] == 2 == names["table_build/place"]
+    assert cache.stats["build_chunks"] == 2
+    builds = [e for e in events if e["name"] == "table_build"]
+    places = [e for e in events if e["name"] == "table_build/place"]
+    assert [(e["args"]["keys"], e["args"]["chunk"], e["args"]["of"])
+            for e in builds] == [(4, 1, 2), (1, 2, 2)]
+    assert [(e["args"]["keys"], e["args"]["slots"], e["args"]["cap"])
+            for e in places] == [(4, 4, 8), (1, 5, 8)]
+    # build, place, build, place: a chunk is placed before the next
+    # is built
+    order = sorted(builds + places, key=lambda e: e["ts"])
+    assert [e["name"] for e in order] == [
+        "table_build", "table_build/place"] * 2
+    for b, p in zip(builds, places):
+        assert b["ts"] + b["dur"] <= p["ts"] + 0.2
