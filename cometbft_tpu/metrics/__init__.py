@@ -639,6 +639,7 @@ class CryptoMetrics:
     def __init__(self, reg: Registry | None = None):
         if reg is None:
             self.batch_verify_launches = _NOP
+            self.batch_verify_padded_lanes = _NOP
             self.batch_verify_batch_size = _NOP
             self.dispatch_decisions = _NOP
             self.dispatch_tier = _NOP
@@ -665,6 +666,14 @@ class CryptoMetrics:
             s, "batch_verify_launches",
             "Batch-verify launches by kernel "
             "(generic | keyed | host_rlc).",
+            labels=("kernel",),
+        )
+        self.batch_verify_padded_lanes = reg.counter(
+            s, "batch_verify_padded_lanes",
+            "Lanes of device launches that carried no signature "
+            "(lanes - signatures per launch), by kernel "
+            "(generic | keyed): over batch_verify_launches it is the "
+            "padding a launch pays for.",
             labels=("kernel",),
         )
         self.batch_verify_batch_size = reg.histogram(

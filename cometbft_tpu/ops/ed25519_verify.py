@@ -19,14 +19,19 @@ Batch shaping (TPU-first):
 - Device arrays are **feature-first**: the packed buffer is
   (100+bucket, batch) so the batch axis rides the 128-wide vector
   lanes (see ops/field.py design notes).
-- Inputs are padded to (power-of-two batch, message-length bucket) so
-  the jit cache stays small and shapes stay static for XLA.
-- Batches larger than MAX_LAUNCH split into multiple asynchronously
-  dispatched launches (one XLA program executes at a time on the chip,
-  but transfers and host packing overlap device compute). MAX_LAUNCH
-  bounds the working set so XLA's fusions stay within on-chip memory —
-  measured round 3: one huge launch falls off a memory cliff, pipelined
-  8-16k launches do not.
+- Inputs are padded to (lanes, message-length bucket) so the jit cache
+  stays small and shapes stay static for XLA.  ``launch_lanes`` is the
+  one rule for the lanes: up to MAX_LAUNCH signatures pad to the next
+  power of two and run as one straight program (the queue's coalesced
+  batches vary in size, and each shape is a compile and a load).
+- A batch of more than MAX_LAUNCH signatures is still ONE launch: it
+  pads to whole slices of WIDE_SLICE lanes and one program runs the
+  slices in turn (lax.map), so the working set is one slice's — one
+  huge straight launch falls off a memory cliff (measured round 3) —
+  and the empty lanes are at most a slice less one: 10,000 signatures
+  ride 10,240 lanes, not the 16,384 of the next power of two.  Only a
+  batch whose messages span length buckets goes out as several
+  launches of at most MAX_LAUNCH signatures each.
 - A and R decompress as ONE concatenated batch (32, 2B): the sqrt
   exponentiation chain is the deepest part of the graph, and fusing
   both halves halves the traced program.
@@ -60,11 +65,18 @@ from cometbft_tpu.ops import sha512 as SH
 _BUCKETS = (128, 256, 512, 1024, 4096)
 _MIN_BATCH = 8
 
-#: Largest single device launch (lanes). Above this, verify_arrays
-#: splits into pipelined launches. Derived from round-3 measurement:
-#: 8192 sustains peak device rate; 65536 in one launch hits an
-#: XLA memory cliff.
+#: Widest straight program (lanes): a batch of more signatures runs in
+#: slices (``launch_lanes``). Derived from round-3 measurement: 8192
+#: sustains peak device rate; 65536 in one launch hits an XLA memory
+#: cliff.
 MAX_LAUNCH = int_from_env("CMT_TPU_MAX_LAUNCH", 8192, minimum=1)
+
+#: Slice width (lanes) of a launch wider than MAX_LAUNCH.  It divides
+#: 8,192, and every commit the protocol admits above MAX_LAUNCH
+#: (8,193-10,000 signatures) lands on one shape, five slices.  Chosen
+#: on the chip among 1,024 / 2,048 / 4,096 / 8,192 (PERF.md section 6,
+#: PR 34).
+WIDE_SLICE = 2048
 
 
 def nblocks_for_bucket(bucket: int) -> int:
@@ -265,13 +277,27 @@ def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length() if n > 1 else 1
 
 
+def launch_lanes(n: int) -> tuple[int, int]:
+    """-> (lanes, slices): the lanes a launch of ``n`` signatures is
+    padded to, and the equal slices its program runs them in.  Up to
+    MAX_LAUNCH signatures: the next power of two, one straight program.
+    Above it: whole slices of ``min(WIDE_SLICE, MAX_LAUNCH)`` lanes.
+    Both constants are read at the call (tests patch MAX_LAUNCH)."""
+    if n <= MAX_LAUNCH:
+        return max(_next_pow2(n), _MIN_BATCH), 1
+    width = min(WIDE_SLICE, MAX_LAUNCH)
+    slices = -(-n // width)
+    return slices * width, slices
+
+
 def pack_inputs(
     pub: np.ndarray, sig: np.ndarray, msgs: list[bytes], start: int = 0,
     end: int | None = None, key_ids: np.ndarray | None = None,
 ) -> tuple[np.ndarray, int]:
     """Pad + pack (pub, sig, msgs[start:end]) into the feature-first
     (100+bucket, batch) u8 layout of verify_kernel_packed — fully
-    vectorized, no per-message Python loop. Returns (packed, bucket).
+    vectorized, no per-message Python loop. Returns (packed, bucket);
+    batch is ``launch_lanes``'s for the messages packed.
     With ``key_ids`` (int32 per message), appends 4 LE id bytes per
     lane for the keyed kernel ((104+bucket, batch))."""
     if end is None:
@@ -283,7 +309,7 @@ def pack_inputs(
     bucket = next((b for b in _BUCKETS if b >= maxlen), None)
     if bucket is None:
         raise ValueError(f"message too large for device path: {maxlen}")
-    batch = max(_next_pow2(n), _MIN_BATCH)
+    batch = launch_lanes(n)[0]
     tail = 100 if key_ids is None else 104
     packed = np.zeros((tail + bucket, batch), dtype=np.uint8)
     packed[:32, :n] = pub[start:end].T
@@ -307,52 +333,62 @@ def pack_inputs(
     return packed, bucket
 
 
+def _launch_span(kernel: str, packed: np.ndarray, sigs: int, slices: int,
+                 bucket: int, **args):
+    """One launch's counters and its ``device_launch`` span: ``batch``
+    lanes carrying ``sigs`` signatures in ``slices`` slices, so a ring
+    gives a launch's occupancy as sigs / batch.  The span covers the
+    (async) dispatch, not device compute — the synchronous wall time
+    is the kernel_time_seconds histogram."""
+    lanes = packed.shape[-1]
+    cm = _crypto_metrics()
+    cm.batch_verify_launches.labels(kernel=kernel).inc()
+    cm.batch_verify_padded_lanes.labels(kernel=kernel).inc(lanes - sigs)
+    cm.bytes_transferred.labels(direction="h2d").inc(packed.nbytes)
+    return _tracer.span(
+        "device_launch", cat="device", kernel=kernel, batch=lanes,
+        sigs=sigs, slices=slices, bucket=bucket, **args,
+    )
+
+
 def _dispatch(pub, sig, msgs, start, end):
     with _tracer.span("verify/pack", cat="device", batch=end - start):
         packed, bucket = pack_inputs(pub, sig, msgs, start, end)
     fn = _compiled(packed.shape[-1], bucket)
-    cm = _crypto_metrics()
-    cm.batch_verify_launches.labels(kernel="generic").inc()
-    cm.bytes_transferred.labels(direction="h2d").inc(packed.nbytes)
-    # span covers the (async) dispatch, not device compute — the
-    # synchronous wall time is the kernel_time_seconds histogram
-    with _tracer.span(
-        "device_launch", cat="device", kernel="generic",
-        batch=packed.shape[-1], bucket=bucket,
-    ):
+    with _launch_span("generic", packed, end - start, 1, bucket):
         return fn(jax.device_put(packed))
 
 
 _keyed_cache: dict[tuple[int, int, int], object] = {}
 
 
-def _compiled_keyed(bucket: int, window_bits: int, chunk: int):
+def _compiled_keyed(bucket: int, window_bits: int, slices: int):
     """Jit of the keyed kernel over (buf, table, key_valid); batch and
     table shapes retrace inside the one jit wrapper (jax caches per
     shape; table widths are pow2-padded by the table cache so the
-    variant count stays small).  Batches wider than ``chunk`` process
-    in lax.map slices — bounded working set, one dispatch."""
-    key = (bucket, window_bits, chunk, _F.trace_config())
+    variant count stays small).  ``slices`` is ``launch_lanes``'s: 1
+    is the straight program, more process the lanes in that many equal
+    lax.map slices — bounded working set, one dispatch."""
+    key = (bucket, window_bits, slices, _F.trace_config())
     fn = _keyed_cache.get(key)
     if fn is None:
         _jitguard.note_compile("keyed", key)
         nblocks = nblocks_for_bucket(bucket)
 
         def run(buf, table, key_valid):
-            batch = buf.shape[-1]
-            if batch <= chunk:
+            if slices == 1:
                 return verify_kernel_keyed_packed(
                     buf, table, key_valid, bucket, nblocks, window_bits
                 )
-            k = batch // chunk
-            chunks = buf.reshape(buf.shape[0], k, chunk).transpose(1, 0, 2)
+            rows, lanes = buf.shape
+            chunks = buf.reshape(rows, slices, lanes // slices)
             out = jax.lax.map(
                 lambda c: verify_kernel_keyed_packed(
                     c, table, key_valid, bucket, nblocks, window_bits
                 ),
-                chunks,
+                chunks.transpose(1, 0, 2),
             )
-            return out.reshape(batch)
+            return out.reshape(lanes)
 
         run.__name__ = f"verify_keyed_w{window_bits}_b{bucket}"
         fn = jax.jit(run)
@@ -367,18 +403,10 @@ def verify_arrays_keyed_async(entry, key_ids, pub, sig, msgs):
     n = len(msgs)
     with _tracer.span("verify/pack", cat="device", batch=n):
         packed, bucket = pack_inputs(pub, sig, msgs, key_ids=key_ids)
-        batch = packed.shape[-1]
-        if batch > MAX_LAUNCH and batch % MAX_LAUNCH:
-            pad = MAX_LAUNCH - batch % MAX_LAUNCH
-            packed = np.pad(packed, [(0, 0), (0, pad)])
-    fn = _compiled_keyed(bucket, entry.window_bits, MAX_LAUNCH)
-    cm = _crypto_metrics()
-    cm.batch_verify_launches.labels(kernel="keyed").inc()
-    cm.bytes_transferred.labels(direction="h2d").inc(packed.nbytes)
-    with _tracer.span(
-        "device_launch", cat="device", kernel="keyed",
-        batch=packed.shape[-1], bucket=bucket,
-        window_bits=entry.window_bits,
+    slices = launch_lanes(n)[1]
+    fn = _compiled_keyed(bucket, entry.window_bits, slices)
+    with _launch_span(
+        "keyed", packed, n, slices, bucket, window_bits=entry.window_bits,
     ):
         # valid_device(): the per-entry device copy of the validity
         # mask — a jnp.asarray here paid an implicit h2d transfer per
@@ -392,13 +420,13 @@ def verify_arrays_keyed_async(entry, key_ids, pub, sig, msgs):
 def verify_arrays_async(pub: np.ndarray, sig: np.ndarray, msgs: list[bytes]):
     """Enqueue verification launches without waiting: returns a list of
     (device_array, chunk_len) pairs.  Batches over MAX_LAUNCH go out
-    as ONE chunked launch (lax.map over MAX_LAUNCH-wide slices inside
-    a single XLA program — bounded working set, single dispatch);
-    CMT_TPU_MULTI_LAUNCH=1 restores the multi-launch split for
-    comparison.  Synchronize through ``_finish`` (or verify_stream) —
-    one explicit ``jax.device_get`` per batch, the idiom the
-    CMT_TPU_JITGUARD transfer window admits.  Each device array is
-    pow2/chunk padded — slice to its chunk_len."""
+    as ONE chunked launch (lax.map over ``launch_lanes``'s slices
+    inside a single XLA program — bounded working set, single
+    dispatch); CMT_TPU_MULTI_LAUNCH=1 restores the multi-launch split
+    for comparison.  Synchronize through ``_finish`` (or
+    verify_stream) — one explicit ``jax.device_get`` per batch, the
+    idiom the CMT_TPU_JITGUARD transfer window admits.  Each device
+    array is padded to ``launch_lanes`` — slice to its chunk_len."""
     n = len(msgs)
     homogeneous = n > MAX_LAUNCH and not flag_from_env(
         "CMT_TPU_MULTI_LAUNCH"
@@ -417,18 +445,10 @@ def verify_arrays_async(pub: np.ndarray, sig: np.ndarray, msgs: list[bytes]):
     if homogeneous:
         with _tracer.span("verify/pack", cat="device", batch=n):
             packed, bucket = pack_inputs(pub, sig, msgs)
-            batch = packed.shape[-1]
-            if batch % MAX_LAUNCH:  # pad columns to a whole chunk count
-                pad = MAX_LAUNCH - batch % MAX_LAUNCH
-                packed = np.pad(packed, [(0, 0), (0, pad)])
-                batch += pad
-        fn = _compiled_chunked(batch, bucket, MAX_LAUNCH)
-        cm = _crypto_metrics()
-        cm.batch_verify_launches.labels(kernel="generic").inc()
-        cm.bytes_transferred.labels(direction="h2d").inc(packed.nbytes)
-        with _tracer.span(
-            "device_launch", cat="device", kernel="generic",
-            batch=batch, bucket=bucket, chunked=True,
+        lanes, slices = launch_lanes(n)
+        fn = _compiled_chunked(lanes, bucket, lanes // slices)
+        with _launch_span(
+            "generic", packed, n, slices, bucket, chunked=True,
         ):
             return [(fn(jax.device_put(packed)), n)]
     parts = []
@@ -481,8 +501,8 @@ def _finish(parts) -> np.ndarray:
 def verify_arrays(pub: np.ndarray, sig: np.ndarray, msgs: list[bytes]):
     """Host entry: numpy (n,32), (n,64), list of n messages -> bool[n].
 
-    Pads to (pow2 batch, length bucket); one device launch per
-    MAX_LAUNCH chunk.
+    Pads to (``launch_lanes``, length bucket); one device launch,
+    or one per MAX_LAUNCH signatures where the messages span buckets.
     """
     return _finish(verify_arrays_async(pub, sig, msgs))
 

@@ -7,9 +7,9 @@ chip's memory (on-chip-measurement guide §2.3).  Three programs are
 kept — the two keyed-verify programs ``chip_smoke.py`` spends its time
 in, and the four-chip ``keyed_mesh`` program — each a cold compile of
 about half a minute, so the file stays on one worker for a few minutes.
-A fourth test holds the 10,000-validator commit's shapes (ISSUE 33): the
-16,384-lane launch over the 10,240-slot table, and one chunk's write
-into that pool.
+A fourth test holds the 10,000-validator commit's shapes (ISSUEs 33, 34):
+the 10,240-lane launch in slices of ``WIDE_SLICE`` over the 10,240-slot
+table, and one chunk's write into that pool.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every xdist
@@ -141,10 +141,11 @@ def test_table_sized_movers_finds_the_window_major_relayout():
 def test_keyed_verify_compiles_for_one_v5e(
     topo, no_persistent_cache, lanes, slots, window_bits
 ):
-    from cometbft_tpu.ops.ed25519_verify import MAX_LAUNCH, _compiled_keyed
+    from cometbft_tpu.ops.ed25519_verify import _compiled_keyed, launch_lanes
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    fn = _compiled_keyed(BUCKET, window_bits, MAX_LAUNCH)
+    assert launch_lanes(lanes) == (lanes, 1)  # one straight program
+    fn = _compiled_keyed(BUCKET, window_bits, 1)
     compiled = fn.lower(
         *keyed_shapes(lanes, slots, window_bits, (one_chip,) * 3)
     ).compile()
@@ -157,26 +158,32 @@ def test_keyed_verify_compiles_for_one_v5e(
 
 
 def test_mega_commit_shapes_fit_one_v5e(topo, no_persistent_cache):
-    """10,000 signatures pad to 16,384 lanes in two 8,192-lane slices
-    over the 5 GiB table, which stays an argument (no copy of it, no
-    slice); and a chunk of 1,024 built pages written into that pool is
-    the pool twice and the chunk, with no scratch."""
-    from cometbft_tpu.ops.ed25519_verify import MAX_LAUNCH, _compiled_keyed
+    """10,000 signatures pad to 10,240 lanes in five slices of
+    ``WIDE_SLICE`` over the 5 GiB table, which stays an argument (no
+    copy of it, no slice); and a chunk of 1,024 built pages written
+    into that pool is the pool twice and the chunk, with no scratch."""
+    from cometbft_tpu.ops.ed25519_verify import (
+        WIDE_SLICE, _compiled_keyed, launch_lanes,
+    )
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    lanes, slots, window_bits = 2 * MAX_LAUNCH, PR._pool_cap(10_000), 4
-    assert (lanes, slots) == (16_384, 10_240)
+    (lanes, slices), slots, window_bits = (
+        launch_lanes(10_000), PR._pool_cap(10_000), 4
+    )
+    assert (lanes, slices, slots) == (10_240, 5, 10_240)
+    assert lanes == slices * WIDE_SLICE
     pool_bytes = slots * PR.slot_rows(window_bits) * PR.ROW * 4
     assert pool_bytes == 5 << 30
-    fn = _compiled_keyed(BUCKET, window_bits, MAX_LAUNCH)
+    fn = _compiled_keyed(BUCKET, window_bits, slices)
     compiled = fn.lower(
         *keyed_shapes(lanes, slots, window_bits, (one_chip,) * 3)
     ).compile()
     assert device_bytes(compiled) < V5E_HBM_BYTES
     window = slots * (1 << window_bits) * PR.ENTRY_LIMBS
     assert table_sized_movers(compiled.as_text(), window) == []
-    # an 8,192-lane slice's working set, not the table's
-    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    # one slice's working set (37 MB at 2,048 lanes), not the launch's
+    # and not the table's: the bound of the straight programs holds
+    assert compiled.memory_analysis().temp_size_in_bytes < KEYED_TEMP_BYTES
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

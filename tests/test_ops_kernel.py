@@ -232,16 +232,18 @@ class TestBatchVerifyKernel:
 
 class TestChunkedLaunches:
     def test_non_pow2_max_launch_alignment(self, rng, monkeypatch):
-        """Chunk outputs are pow2-padded per launch; results must be
-        sliced per chunk, not globally (regression: a non-pow2
-        MAX_LAUNCH misaligned every verdict after the first chunk)."""
+        """A batch over a MAX_LAUNCH that is no power of two pads to
+        whole slices of it (``launch_lanes``: 23 signatures in 30
+        lanes), and the verdicts of the padding are sliced off the end
+        (regression: a non-pow2 MAX_LAUNCH misaligned every verdict
+        after the first chunk)."""
         from cometbft_tpu.ops import ed25519_verify as ev
 
         monkeypatch.setattr(ev, "MAX_LAUNCH", 10)
         bv = TpuBatchVerifier(device_min_batch=0)
         oracle = []
         priv = ed.gen_priv_key()
-        for i in range(23):  # 3 chunks: 10 (pad 16), 10 (pad 16), 3 (pad 8)
+        for i in range(23):  # 3 slices of 10: the last 3 signatures, 7 zeros
             m = bytes([i]) * 40
             sig = bytearray(priv.sign(m))
             ok = True
@@ -252,6 +254,49 @@ class TestChunkedLaunches:
             oracle.append(ok)
         _, results = bv.verify()
         assert results == oracle
+
+    def test_generic_tier_pads_a_wide_batch_to_whole_slices(
+        self, monkeypatch
+    ):
+        """The generic tier is the ladder's next rung for the same
+        batch and pads by the same rule: ONE launch, 23 signatures in
+        30 lanes run as three slices of 10, no power of two in sight;
+        flipped signatures on both sides of each seam and in the last,
+        partly empty slice."""
+        from cometbft_tpu.ops import ed25519_verify as ev
+        from cometbft_tpu.utils.trace import TRACER
+
+        monkeypatch.setattr(ev, "MAX_LAUNCH", 10)
+        assert ev.launch_lanes(23) == (30, 3)
+        priv = ed.priv_key_from_secret(b"generic-wide")
+        pubs = np.tile(
+            np.frombuffer(priv.pub_key().bytes(), dtype=np.uint8), (23, 1)
+        )
+        msgs = [bytes([i]) * 40 for i in range(23)]
+        sigs = np.stack(
+            [np.frombuffer(priv.sign(m), dtype=np.uint8) for m in msgs]
+        )
+        bad = {9, 10, 19, 20, 22}
+        for i in bad:
+            sigs[i, 5] ^= 0x40
+        packed, bucket = ev.pack_inputs(pubs, sigs, msgs)
+        assert packed.shape == (100 + bucket, 30)
+        assert not packed[:, 23:].any()
+        was = TRACER.enabled
+        TRACER.set_enabled(True)
+        try:
+            TRACER.clear()
+            parts = ev.verify_arrays_async(pubs, sigs, msgs)
+            launches = [e["args"] for e in TRACER.events()
+                        if e["name"] == "device_launch"]
+        finally:
+            TRACER.set_enabled(was)
+        assert len(parts) == 1 and parts[0][0].shape == (30,)
+        assert len(launches) == 1 and launches[0]["chunked"] is True
+        assert (launches[0]["sigs"], launches[0]["batch"],
+                launches[0]["slices"]) == (23, 30, 3)
+        out = ev._finish(parts)
+        assert out.tolist() == [i not in bad for i in range(23)]
 
 
 @pytest.mark.slow
@@ -272,7 +317,7 @@ def test_chunked_single_launch_matches_multi_launch(monkeypatch):
     from cometbft_tpu.ops import ed25519_verify as EV
 
     monkeypatch.setattr(EV, "MAX_LAUNCH", 64)
-    n = 200  # 3 full chunks of 64 + a 8-wide tail after pow2 padding
+    n = 200  # chunked: 256 lanes in 4 slices; multi: 64, 64, 64 and 8
     rng = np.random.RandomState(5)
     priv = ed.priv_key_from_secret(b"chunked")
     pub_b = np.frombuffer(priv.pub_key().bytes(), dtype=np.uint8)

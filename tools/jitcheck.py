@@ -103,9 +103,11 @@ JIT_SEAMS = frozenset(
 
 #: parameter names a seam may key its cache on — the pow2/bucket/chunk
 #: ladder (plus the mesh handle, itself drawn from the cached
-#: flat_mesh).  Anything else is an unbounded cache dimension.
+#: flat_mesh, and the slice count ``launch_lanes`` gives a wide
+#: launch).  Anything else is an unbounded cache dimension.
 LADDER_PARAMS = frozenset(
-    {"batch", "bucket", "chunk", "window_bits", "n", "nblocks", "mesh"}
+    {"batch", "bucket", "chunk", "slices", "window_bits", "n", "nblocks",
+     "mesh"}
 )
 
 #: device-plane files subject to the host-sync check
