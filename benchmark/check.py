@@ -55,7 +55,8 @@ def compare(chain: gen.Chain, outcomes: list, checked: int, sample: int,
     for k, err in (odd + rest)[:max_scans]:
         it = items[k]
         msgs = [gen.sign_bytes(it, i) for i in range(checked)]
-        ref = reference.first_bad(chain.pubs, msgs, it.sigs, checked)
+        ref = reference.first_bad(gen.signers(chain, it)[1], msgs, it.sigs,
+                                  checked)
         scanned += 1
         if (ref is not None) != (err is not None):
             verdicts += 1
@@ -67,7 +68,7 @@ def compare(chain: gen.Chain, outcomes: list, checked: int, sample: int,
         it = items[rng.choice(accepted)]
         i = rng.randrange(checked)
         if not reference.verify_zip215(
-            chain.pubs[i], gen.sign_bytes(it, i), it.sigs[i]
+            gen.signers(chain, it)[1][i], gen.sign_bytes(it, i), it.sigs[i]
         ):
             invalid += 1
 

@@ -66,7 +66,14 @@ def control(st: State) -> None:
     common.swap_entry(st, one_third)
 
 
-def _step(st: State, blocks: list, k: int, prefetched: int,
+def _block(st: State, k: int) -> tuple:
+    """Block ``k`` of the sync, the warm-up's counted: read where it is
+    kept, so that no second list holds what the window has let go."""
+    n = len(st.warm)
+    return st.warm[k] if k < n else st.commits[k - n]
+
+
+def _step(st: State, k: int, prefetched: int,
           parts: dict | None = None) -> tuple:
     """-> (rejection text or None, index of the highest block now
     submitted to the prefetch lane); the seconds of the step's three
@@ -74,7 +81,8 @@ def _step(st: State, blocks: list, k: int, prefetched: int,
     from cometbft_tpu.blocksync.reactor import commit_prefetch_items
     from cometbft_tpu.crypto import verify_queue as vq
 
-    bid, commit = blocks[k]
+    n_blocks = len(st.warm) + len(st.commits)
+    bid, commit = _block(st, k)
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("entry.verify_commit_light"):
         err = common.run_verify(st.entry, st.vals, bid, commit)
@@ -82,9 +90,10 @@ def _step(st: State, blocks: list, k: int, prefetched: int,
     q = vq._installed()
     with jax.profiler.TraceAnnotation("entry.prefetch_submit"):
         items = []
-        hi = min(k + st.depth, len(blocks) - 1)
+        hi = min(k + st.depth, n_blocks - 1)
         for j in range(max(prefetched, k) + 1, hi + 1):
-            got = commit_prefetch_items(gen.CHAIN_ID, st.vals, blocks[j][1])
+            got = commit_prefetch_items(gen.CHAIN_ID, st.vals,
+                                        _block(st, j)[1])
             if got is None:
                 raise RuntimeError("validator set does not line up")
             items.extend(got)
@@ -118,25 +127,27 @@ def warm(st: State) -> None:
     of them into the window's first blocks — the one 8-block burst a
     sync begins with (bucket 8192) is set-up, and the window opens on
     the steady state, one block submitted a step."""
-    blocks = st.warm + st.commits
     for k in range(len(st.warm)):
-        err, st.prefetched = _step(st, blocks, k, st.prefetched)
+        err, st.prefetched = _step(st, k, st.prefetched)
         common.expect_warm(st.chain.warm[k], err)
 
 
 def run(st: State, seconds: float) -> common.Window:
     win = common.Window()
-    blocks = st.warm + st.commits
     t0 = time.perf_counter()
     deadline = t0 + seconds
     while st.cursor < len(st.commits) and time.perf_counter() < deadline:
         k = st.cursor
         st.cursor += 1
         t = time.perf_counter()
-        err, st.prefetched = _step(st, blocks, len(st.warm) + k,
+        err, st.prefetched = _step(st, len(st.warm) + k,
                                    st.prefetched, win.parts)
         win.latencies.append(time.perf_counter() - t)
         win.outcomes.append((k, err))
+        # the step that checked block k is over and the queue has
+        # drained: the prefetch of k ran eight steps ago, its check
+        # just now, and nothing asks for it again
+        st.consumed(k)
     win.elapsed = time.perf_counter() - t0
     win.ran_out = st.cursor >= len(st.commits)
     return win

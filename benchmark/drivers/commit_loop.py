@@ -52,6 +52,7 @@ def run(st: common.State, seconds: float) -> common.Window:
             err = common.run_verify(st.entry, st.vals, bid, commit)
         win.latencies.append(time.perf_counter() - t)
         win.outcomes.append((k, err))
+        st.consumed(k)
     win.elapsed = time.perf_counter() - t0
     win.ran_out = st.cursor >= len(st.commits)
     return win

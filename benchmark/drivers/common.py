@@ -21,6 +21,8 @@ class State:
     checked: int
     #: signatures the program verifies for each item of this traffic
     sigs_per_item: int
+    #: (BlockID, Commit) of each of the chain's items, in the program's
+    #: types; None once the window is done with it (``consumed``)
     commits: list = field(default_factory=list)
     warm: list = field(default_factory=list)
     cursor: int = 0
@@ -29,6 +31,17 @@ class State:
         self.warm = [gen.commit_of(self.chain, it) for it in self.chain.warm]
         self.commits = [gen.commit_of(self.chain, it)
                         for it in self.chain.items]
+
+    def consumed(self, k: int) -> None:
+        """Let go of item ``k``'s program objects: its verdict is in
+        the window's outcomes and the program will not be handed them
+        again.  A node drops a block once it is applied; a generator
+        that kept its chain alive made every window retain tens of MB
+        of commits and the sign-bytes they memoise (PR 31), which no
+        node holds.  The frees happen here, inside the window, as they
+        do in a node.  ``chain.items`` (plain data, what
+        ``check.compare`` reads) stays whole."""
+        self.commits[k] = None
 
 
 def plan(config: dict, params: dict, seed: int, n_items: int) -> gen.Chain:

@@ -35,12 +35,27 @@ The first warm-up item is the client's trusted root; the rest of the
 warm-up are targets (the last tampered), walked by the same iterator the
 window goes on with, so the look-ahead is already running ahead of the
 window's first target when it opens, as it is all through a catch-up.
+
+**What the providers stop serving** (PR 35).  A full node prunes, and a
+generator that kept 10,000 light blocks alive made the window retain
+what no deployment holds.  Once a target's verdict is in the window's
+outcomes the driver lets go of its own ``(BlockID, Commit)``; once a
+target is ACCEPTED, the providers (primary and witness serve one dict)
+drop every light block BELOW it.  What stays served, because a step
+after a rejection can still ask for it: the last trusted header's own
+height and everything above it — the targets fetched ahead, and a
+rejected target's height, on which the pivot of a bisection from the
+last trusted header to the next target lands (the heights are evenly
+spaced).  Nothing below the last trusted header can be asked for: the
+client reads its anchor from its store, goes forward only, and compares
+with the witness at the height it is verifying.
 """
 
 from __future__ import annotations
 
 import hashlib
 import time
+from collections import deque
 from dataclasses import dataclass
 
 import jax
@@ -114,6 +129,11 @@ class State(common.State):
     client: object = None
     #: the catch-up's iterator: warm-up targets, then the window's
     walk: object = None
+    #: height -> light block: what both providers serve
+    served: dict = None
+    #: the heights still served, ascending (a dict popped from its
+    #: front again and again walks its dead slots: the deque does not)
+    serving: deque = None
 
 
 def program_header(h: reference_light.Header):
@@ -190,6 +210,7 @@ def prepare(chain: gen.Chain, config: dict, params: dict) -> State:
         )
         for _, commit in st.warm + st.commits
     }
+    st.served, st.serving = blocks, deque(sorted(blocks))
     root = blocks[chain.warm[0].height]
     st.client = Client(
         gen.CHAIN_ID,
@@ -231,6 +252,11 @@ def _next_verdict(st: State, item: gen.Item, pair: tuple) -> str | None:
     return None if err is None else f"{type(err).__name__}: {err}"
 
 
+def _stop_serving_below(st: State, trusted_height: int) -> None:
+    while st.serving and st.serving[0] < trusted_height:
+        del st.served[st.serving.popleft()]
+
+
 def warm(st: State) -> None:
     """The catch-up's start: the look-ahead fills with full batches,
     their shape compiles, and the last warm-up target is rejected."""
@@ -250,6 +276,9 @@ def run(st: State, seconds: float) -> common.Window:
             err = _next_verdict(st, st.chain.items[k], st.commits[k])
         win.latencies.append(time.perf_counter() - t)
         win.outcomes.append((k, err))
+        st.consumed(k)
+        if err is None:
+            _stop_serving_below(st, st.chain.items[k].height)
     win.elapsed = time.perf_counter() - t0
     win.ran_out = st.cursor >= len(st.commits)
     return win

@@ -13,13 +13,21 @@ own sign-bytes, never the program's signer or encoder; ``sign_items`` is
 the worker function of the signing pool and imports neither JAX nor
 ``cometbft_tpu``.  Only ``validator_set`` / ``commit_of`` touch the
 program, to hand it its inputs in its own types.
+
+What an item may be (PR 35; each a default that leaves a chain of plain
+commits byte for byte what it was): it carries the total of its block's
+part set (``parts_total``: a real block of two parts or more is signed
+over that id) and names the signer set of its height (``epoch``, an index
+into ``Chain.epochs``).  Whoever needs an item's keys asks ``signers``.
+A driver whose blocks depend on the signatures before them signs in
+order itself (``run.py``: ``build``), with ``sign_item``.
 """
 
 from __future__ import annotations
 
 import hashlib
 import random
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 from benchmark import reference
 
@@ -39,13 +47,19 @@ class Item:
     parts_hash: bytes
     bad: tuple[int, ...] = ()
     sigs: list[bytes] = field(default_factory=list)
+    #: parts of the block's part set, as its id and every vote name it
+    parts_total: int = 1
+    #: which of ``Chain.epochs`` signs this height
+    epoch: int = 0
 
 
 @dataclass
 class Chain:
     """Validators (canonical order: by address, powers being equal) and
     the items to verify.  ``warm`` items are signed like the rest (the
-    last of them tampered); the window never sees them."""
+    last of them tampered); the window never sees them.  ``key_seeds``
+    and ``pubs`` are epoch 0's; a chain whose set changes lists every
+    set, in ``epochs``, as ``(key_seeds, pubs)``."""
 
     seed: int
     key_seeds: list[bytes]
@@ -53,6 +67,13 @@ class Chain:
     warm: list[Item]
     items: list[Item]
     sign_bytes_total: int = 0
+    epochs: list[tuple[list[bytes], list[bytes]]] = field(
+        default_factory=list
+    )
+
+    def __post_init__(self) -> None:
+        if not self.epochs:
+            self.epochs = [(self.key_seeds, self.pubs)]
 
     @property
     def n_vals(self) -> int:
@@ -61,8 +82,8 @@ class Chain:
     @property
     def sign_bytes_mean(self) -> float:
         """Mean length of one vote's sign-bytes, once signed."""
-        return self.sign_bytes_total / (
-            self.n_vals * (len(self.items) + len(self.warm))
+        return self.sign_bytes_total / sum(
+            len(it.sigs) for it in self.warm + self.items
         )
 
 
@@ -74,10 +95,16 @@ def vote_time(height: int, index: int) -> int:
     return TS0 + height * HEIGHT_NS + index
 
 
+def signers(chain: Chain, item: Item) -> tuple[list[bytes], list[bytes]]:
+    """-> (key seeds, public keys) of the set that signs ``item``, in
+    the commit's order."""
+    return chain.epochs[item.epoch]
+
+
 def sign_bytes(item: Item, index: int) -> bytes:
     return reference.vote_sign_bytes(
-        CHAIN_ID, item.height, 0, item.block_hash, 1, item.parts_hash,
-        vote_time(item.height, index),
+        CHAIN_ID, item.height, 0, item.block_hash, item.parts_total,
+        item.parts_hash, vote_time(item.height, index),
     )
 
 
@@ -142,36 +169,49 @@ def plan(seed: int, n_vals: int, n_items: int, n_warm: int, stride: int,
     )
 
 
-def sign_items(job) -> tuple[list[list[bytes]], int]:
-    """Pool worker: every validator's signature over each item's
-    canonical precommit, the ``bad`` ones with one bit flipped.
-    -> (signatures per item, total sign-bytes length)."""
+def private_keys(key_seeds: list[bytes]) -> list:
     from cryptography.hazmat.primitives.asymmetric.ed25519 import (
         Ed25519PrivateKey,
     )
 
-    key_seeds, items = job
-    keys = [Ed25519PrivateKey.from_private_bytes(ks) for ks in key_seeds]
+    return [Ed25519PrivateKey.from_private_bytes(ks) for ks in key_seeds]
+
+
+def sign_item(keys: list, item: Item) -> tuple[list[bytes], int]:
+    """Every key's signature over ``item``'s canonical precommit, the
+    ``bad`` ones with one bit flipped.  -> (signatures, total
+    sign-bytes length)."""
+    sigs, total = [], 0
+    for i, key in enumerate(keys):
+        msg = sign_bytes(item, i)
+        total += len(msg)
+        sig = key.sign(msg)
+        sigs.append(tamper(sig) if i in item.bad else sig)
+    return sigs, total
+
+
+def sign_items(job) -> tuple[list[list[bytes]], int]:
+    """Pool worker: ``sign_item`` over a job's items, each by the set
+    of its epoch.  -> (signatures per item, total sign-bytes length)."""
+    chain, items = job
+    keys: dict[int, list] = {}
     out, total = [], 0
     for it in items:
-        sigs = []
-        for i, key in enumerate(keys):
-            msg = sign_bytes(it, i)
-            total += len(msg)
-            sig = key.sign(msg)
-            sigs.append(tamper(sig) if i in it.bad else sig)
+        if it.epoch not in keys:
+            keys[it.epoch] = private_keys(signers(chain, it)[0])
+        sigs, n = sign_item(keys[it.epoch], it)
         out.append(sigs)
+        total += n
     return out, total
 
 
 def sign_jobs(chain: Chain, n_jobs: int) -> list:
-    """The chain's items (warm first) cut into ``n_jobs`` pool jobs."""
+    """The chain's items (warm first) cut into ``n_jobs`` pool jobs,
+    each with the chain's sets and none of its other items."""
     todo = chain.warm + chain.items
     size = -(-len(todo) // max(1, n_jobs))
-    return [
-        (chain.key_seeds, todo[k:k + size])
-        for k in range(0, len(todo), size)
-    ]
+    sets = replace(chain, warm=[], items=[])
+    return [(sets, todo[k:k + size]) for k in range(0, len(todo), size)]
 
 
 def attach(chain: Chain, results: list) -> None:
@@ -190,15 +230,14 @@ def attach(chain: Chain, results: list) -> None:
 # -- the program's own types, for its inputs -------------------------------
 
 
-def validator_set(chain: Chain):
+def validator_set(chain: Chain, epoch: int = 0):
     from cometbft_tpu.crypto.ed25519 import Ed25519PubKey
     from cometbft_tpu.types.validator import Validator, ValidatorSet
 
-    vals = ValidatorSet(
-        [Validator(Ed25519PubKey(p), POWER) for p in chain.pubs]
-    )
+    pubs = chain.epochs[epoch][1]
+    vals = ValidatorSet([Validator(Ed25519PubKey(p), POWER) for p in pubs])
     got = [v.pub_key.bytes() for v in vals.validators]
-    if got != chain.pubs:
+    if got != pubs:
         raise RuntimeError(
             "the program orders the validator set otherwise than by "
             "address; the generator's indices would not be the commit's"
@@ -218,7 +257,8 @@ def commit_of(chain: Chain, item: Item):
 
     bid = BlockID(
         hash=item.block_hash,
-        part_set_header=PartSetHeader(total=1, hash=item.parts_hash),
+        part_set_header=PartSetHeader(total=item.parts_total,
+                                      hash=item.parts_hash),
     )
     sigs = tuple(
         CommitSig(
@@ -227,7 +267,8 @@ def commit_of(chain: Chain, item: Item):
             timestamp_ns=vote_time(item.height, i),
             signature=sig,
         )
-        for i, (pub, sig) in enumerate(zip(chain.pubs, item.sigs))
+        for i, (pub, sig) in enumerate(zip(signers(chain, item)[1],
+                                           item.sigs))
     )
     return bid, Commit(height=item.height, round=0, block_id=bid,
                        signatures=sigs)

@@ -114,6 +114,9 @@ def counters() -> dict:
             for k in ("launched_sigs", "launched_batches",
                       "cache_resolved", "failed_batches")
         },
+        # the same launches by the lane they left from (PR 28)
+        "queue_lane_sigs": dict(qs.get("launched_sigs_by_lane", {})),
+        "queue_lane_batches": dict(qs.get("launched_batches_by_lane", {})),
         "transitions": len(dispatch.LADDER.snapshot()["transitions"]),
     }
 

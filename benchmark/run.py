@@ -21,6 +21,27 @@ Everything that belongs to one cell is data found by name:
 ``BENCHMARK.json`` -> ``traffic/<cell>.json`` -> ``configs/<config>.json``,
 ``drivers/<driver>.py``; per-layer metrics -> ``layer_metrics/<metric>.json``
 -> ``readers/<reader>.py``.
+
+What a driver may define beyond ``plan`` / ``prepare`` / ``warm`` / ``run``
+/ ``metrics`` / ``control`` (PR 35):
+
+- ``build(chain, workers)``, for a chain that has to be made in order (a
+  real block's header holds the hash of the commit before it, so its
+  hash cannot be fixed before that commit is signed).  ``plan_chain``
+  starts it in ``Signing``'s place and ``run_cell`` calls the same
+  ``finish()`` / ``close()`` on what it returns (and reads its
+  ``.chain``).  The contract: the work runs in processes that import
+  neither JAX nor ``cometbft_tpu`` (``gen.sign_item`` and the references
+  are there for them), it starts at once, before the backend does, and
+  ``finish()`` returns with ``item.sigs``, ``item.block_hash``,
+  ``item.parts_hash``, ``item.parts_total`` of every item, and
+  ``chain.sign_bytes_total``, filled in; ``close()`` stops whatever
+  still runs, in any state.
+- ``compare(state, win)`` -> ``{name: {"value", "limit"}}``: exact counts
+  of the guarantees the deployment adds (what was stored, applied, read
+  back), merged into ``compared`` after ``check.compare``'s six, so they
+  print on standard error and decide ``correct`` like those.  A name
+  that is already there is an error.
 """
 
 from __future__ import annotations
@@ -46,8 +67,10 @@ if ROOT not in sys.path:
 from benchmark import check, gen  # noqa: E402 — neither imports JAX
 
 #: signing pool size: enough to hide signing behind JAX's own start-up
-#: on the chip's host, few enough to leave it cores
-SIGN_WORKERS = 6
+#: on the chip's host (13 cores), few enough to leave it cores.  6 hid
+#: 345k signatures; the commit cell's 900k read 4.6-6.3 s of
+#: ``sign_wait_s`` with 6 and 0.0-0.5 with 10 (PR 32, same call)
+SIGN_WORKERS = 10
 
 
 class NoChip(Exception):
@@ -202,14 +225,17 @@ def read_layers(cell: dict, ctx: dict) -> dict:
 
 
 def plan_chain(cell: dict, seed: int, sign_workers: int = SIGN_WORKERS):
-    """-> the cell's chain, being signed.  Called before the backend
-    starts, so that signing runs meanwhile."""
-    chain = cell["driver"].plan(cell["config"], cell["traffic"]["params"],
-                                seed)
+    """-> the cell's chain, being signed: by the driver's ``build``
+    where it has one (a chain made in order), by ``Signing`` where not.
+    Called before the backend starts, so that signing runs meanwhile."""
+    driver = cell["driver"]
+    chain = driver.plan(cell["config"], cell["traffic"]["params"], seed)
+    if hasattr(driver, "build"):
+        return driver.build(chain, sign_workers)
     return Signing(chain, sign_workers)
 
 
-def run_cell(cell: dict, signing: Signing, seconds: float, trace: bool,
+def run_cell(cell: dict, signing, seconds: float, trace: bool,
              devices, after_warm=None) -> dict:
     """Everything after the look for a chip.  -> the result line.
     ``after_warm(state)`` is the control's and the tests' seam: it
@@ -244,10 +270,23 @@ def run_cell(cell: dict, signing: Signing, seconds: float, trace: bool,
             "chain": {"validators": chain.n_vals, "items": len(chain.items),
                       "warm": len(chain.warm)},
         })
-        t_sign = time.perf_counter()
-        signing.finish()
-        sign_wait_s = time.perf_counter() - t_sign
-        state = driver.prepare(chain, config, traffic["params"])
+        # The program's objects of the chain (a million ``CommitSig``s)
+        # are made with the collector off: they are frozen out of its
+        # sight below, and its passes over the growing heap took half
+        # of the time of making them.  They are made AFTER the signing,
+        # not as the signatures arrive: with this thread busy the
+        # pool's results came in four to five times slower (PR 32,
+        # light cell: ``sign_wait_s`` 8-10 -> 40-51 s).
+        collecting = gc.isenabled()
+        gc.disable()
+        try:
+            t_sign = time.perf_counter()
+            signing.finish()
+            sign_wait_s = time.perf_counter() - t_sign
+            state = driver.prepare(chain, config, traffic["params"])
+        finally:
+            if collecting:
+                gc.enable()
         t_warm = time.perf_counter()
         driver.warm(state)
         warm_s = time.perf_counter() - t_warm
@@ -316,6 +355,14 @@ def run_cell(cell: dict, signing: Signing, seconds: float, trace: bool,
         max_scans=int(traffic.get("reference_scans", 12)),
         rng=random.Random(seed ^ 0x5EED),
     )
+    if hasattr(driver, "compare"):
+        for key, v in driver.compare(state, win).items():
+            if key in compared:
+                raise RuntimeError(
+                    f"{traffic['driver']}.compare returned {key!r}, which "
+                    "check.compare already counts"
+                )
+            compared[key] = {"value": v["value"], "limit": v["limit"]}
     reference_s = time.perf_counter() - t_ref
     attempted = len(win.outcomes)
     failed = compared["missing_verdicts"]["value"]

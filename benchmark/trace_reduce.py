@@ -7,20 +7,24 @@ a trace written out by hand.  On a TPU the device planes are named
 one event per launch, named ``jit_<program>(<fingerprint>)``) and a
 line of single operations ("XLA Ops").  A verify program runs some
 50,000 operations a launch (PR 25: 5.7 million events in a 2-second
-slice, three minutes to walk in Python), so of the operation line only
-the first ``MAX_OP_EVENTS`` are read — enough for the breakdown's "which
-operations take the time" — and busy time is the union of the program
+slice, three minutes to walk in Python with their names), nearly all of
+them inside a ``while``: of the operation line ``load`` keeps the
+top-level events alone (``_top_level``: an event inside the one kept
+before it is passed over with its name unread), ~3,100 a launch, so
+the slice is read whole; ``MAX_OP_EVENTS`` caps what is KEPT, against a
+line that nests nothing.  Busy time is the union of the program
 events' intervals (of the operation events' where a plane has no program
 line), averaged over the device planes.  On the slice read in full the
 two unions differed by 0.03% (PR 25): a program occupies the core from
-its first operation to its last.  The window is the span the host's
-annotations cover.
+its first operation to its last.  The window is the span the harness's
+own annotations (``entry.`` / ``gen.``) cover; the program's spans, which
+stand in the same host plane since PR 26, only NAME the idle time.
 """
 
 from __future__ import annotations
 
 import glob
-import itertools
+import heapq
 import os
 import re
 
@@ -28,7 +32,14 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 MODULE_LINES = ("XLA Modules",)
 OP_LINES = ("XLA Ops",)
 HOST_PLANE = "/host:CPU"
-MAX_OP_EVENTS = 200_000
+#: top-level operation events KEPT of a line.  The commit cell's 0.5 s
+#: slice holds 162,188 (52 launches of ~3,100; PR 35): room for a
+#: commit six times shorter
+MAX_OP_EVENTS = 1_000_000
+#: the program's span names (``cometbft_tpu/utils/trace.py``), by prefix:
+#: what an idle gap is named by, beside the harness's own annotations
+PROGRAM_SPANS = ("verify_commit", "verify_queue/", "verify/", "batch_verify",
+                 "device_", "light/", "blocksync/", "table_build")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -40,9 +51,33 @@ def find_xplane(trace_dir: str) -> str:
     return found[-1]
 
 
+def _top_level(events, cap: int) -> list:
+    """The events of an operation line that do not lie inside the one
+    kept before them, as ``(name, start_ns, duration_ns)``, at most
+    ``cap``.  A loop's event comes before what runs inside it, so on a
+    line in time order this keeps exactly the top level; on any other
+    it keeps more, never less (``top_level_times`` sorts and decides).
+    The name — an operation's whole HLO text — is read only of an event
+    that is kept."""
+    out: list[tuple] = []
+    names: dict[str, str] = {}  # one string a distinct operation
+    lo = hi = None
+    for ev in events:
+        start = float(ev.start_ns)
+        end = start + float(ev.duration_ns)
+        if hi is not None and lo <= start and end <= hi:
+            continue
+        name = ev.name
+        out.append((names.setdefault(name, name), start, end - start))
+        lo, hi = start, end
+        if len(out) >= cap:
+            break
+    return out
+
+
 def load(path: str, serialized: bytes | None = None) -> list[dict]:
     """-> [{"name", "lines": [{"name", "events": [(name, start_ns,
-    duration_ns)]}]}]"""
+    duration_ns)]}]}]; of an operation line the top-level events."""
     from jax.profiler import ProfileData
 
     data = (ProfileData.from_serialized_xspace(serialized)
@@ -53,13 +88,12 @@ def load(path: str, serialized: bytes | None = None) -> list[dict]:
             "lines": [
                 {
                     "name": line.name,
-                    "events": [
-                        (ev.name, float(ev.start_ns), float(ev.duration_ns))
-                        for ev in itertools.islice(
-                            line.events,
-                            MAX_OP_EVENTS if line.name in OP_LINES else None,
-                        )
-                    ],
+                    "events": (
+                        _top_level(line.events, MAX_OP_EVENTS)
+                        if line.name in OP_LINES else
+                        [(ev.name, float(ev.start_ns), float(ev.duration_ns))
+                         for ev in line.events]
+                    ),
                 }
                 for line in plane.lines
             ],
@@ -124,20 +158,48 @@ def host_spans(planes: list[dict], prefixes: tuple) -> list[tuple]:
     return sorted(out, key=lambda ev: ev[1])
 
 
+def top_level_times(events: list) -> dict[str, float]:
+    """Seconds by operation, each instant counted ONCE.  On a device's
+    operation line a ``while`` event holds the events of what runs
+    inside it; summed by name alone, nested or not, they read twice the
+    launch (PR 27).  Here an event that lies inside another of the same
+    line is LEFT OUT and a top-level event keeps its whole time, so the
+    names of one launch add up to no more than its program's time.
+    (The other way — ``parent/child`` names with the child's time taken
+    off the parent's — was tried on the chip, PR 32: a loop's time
+    scatters over hundreds of fusion names and 84% of a commit launch
+    landed under ``other``.)  ``events``: [(name, start_ns,
+    duration_ns)] of ONE line."""
+    out: dict[str, float] = {}
+    end = None
+    for name, start, dur in sorted(events, key=lambda ev: (ev[1], -ev[2])):
+        if end is not None and start < end:
+            continue  # inside the top-level event that began before it
+        end = start + dur
+        name = op_name(name)
+        out[name] = out.get(name, 0.0) + dur / 1e9
+    return out
+
+
 def reduce(planes: list[dict], annotations: tuple = ("entry.", "gen."),
-           top: int = 10) -> dict:
+           naming: tuple = PROGRAM_SPANS, top: int = 10) -> dict:
     """-> {"devices", "window_s", "busy_s", "programs": {name:
     {"launches", "seconds"}}, "device_ops": [[name, seconds]],
     "idle_gaps": [[name, seconds]]}.
 
-    The window runs from the first annotation's start to the last one's
-    end; device events are clipped to it.  ``busy_s`` is averaged over
-    the device planes.  An idle gap is a stretch of the window in which
-    no program ran on the first device; its seconds go to the
-    annotations that overlap it, each by its overlap (``host`` for what
-    none covers).  ``device_ops``
-    are the operations of the launches read (see MAX_OP_EVENTS), by
-    their time there."""
+    The window runs from the first of the ``annotations`` to the end of
+    the last; device events are clipped to it.  ``busy_s`` is averaged
+    over the device planes.  An idle gap is a stretch of the window in
+    which no program ran on the first device; every instant of it goes
+    to ONE host annotation — of ``annotations`` or ``naming`` — the one
+    that started last among those covering it, on any thread (``host``
+    where none does), so the names' seconds add up to the gaps'.
+    ``device_ops`` are the top-level operations of the window by their
+    time there (``top_level_times``: what lies inside a loop is the
+    loop's, each instant once), averaged over the device planes as
+    ``busy_s`` is.  Both lists are cut to
+    ``top`` entries, the last of them ``other`` with what the cut left
+    out, so each list still adds up."""
     spans = host_spans(planes, annotations)
     lo = spans[0][1] if spans else None
     hi = max(s + d for _, s, d in spans) if spans else None
@@ -159,33 +221,41 @@ def reduce(planes: list[dict], annotations: tuple = ("entry.", "gen."),
                                     {"launches": 0, "seconds": 0.0})
             p["launches"] += 1
             p["seconds"] += dur / 1e9
-        for name, _, dur in op_events:
-            name = op_name(name)
-            ops[name] = ops.get(name, 0.0) + dur / 1e9
+        for name, sec in top_level_times(op_events).items():
+            ops[name] = ops.get(name, 0.0) + sec / len(devices)
     if lo is None and first_intervals:
         lo = min(s for s, _ in first_intervals)
         hi = max(e for _, e in first_intervals)
     window_s = (hi - lo) / 1e9 if lo is not None else 0.0
     by_time = ops or {k: v["seconds"] for k, v in programs.items()}
+    named = host_spans(planes, annotations + naming)
     return {
         "devices": len(devices),
         "window_s": window_s,
         "busy_s": sum(busy) / len(busy) if busy else 0.0,
         "programs": programs,
-        "device_ops": [
-            [k, v] for k, v in
-            sorted(by_time.items(), key=lambda kv: -kv[1])[:top]
-        ],
-        "idle_gaps": _idle_gaps(first_intervals, spans, lo, hi, top),
+        "device_ops": _top(by_time, top),
+        "idle_gaps": _top(_idle_gaps(first_intervals, named, lo, hi), top),
     }
 
 
-def _idle_gaps(intervals, spans, lo, hi, top: int) -> list:
-    """Idle seconds of the window by what the host was doing: each gap
-    shared out among the annotations that overlap it, what none covers
-    under ``host``."""
+def _top(by_name: dict[str, float], top: int) -> list:
+    """The ``top`` largest as [[name, seconds]]; where more were found,
+    the last entry is ``other``: the sum of what is not listed."""
+    ranked = sorted(by_name.items(), key=lambda kv: -kv[1])
+    if len(ranked) > top:
+        rest = sum(v for _, v in ranked[top - 1:])
+        ranked = ranked[:top - 1] + [("other", rest)]
+    return [[k, v] for k, v in ranked]
+
+
+def _idle_gaps(intervals, spans, lo, hi) -> dict[str, float]:
+    """Idle seconds of the window by what the host was doing: each
+    instant of a gap under the annotation that started last among those
+    covering it (the innermost, and across threads the most recent),
+    what none covers under ``host``.  ``spans`` sorted by start."""
     if lo is None:
-        return []
+        return {}
     gaps, end = [], lo
     for s, e in sorted(intervals):
         if s > end:
@@ -195,20 +265,29 @@ def _idle_gaps(intervals, spans, lo, hi, top: int) -> list:
         gaps.append((end, hi))
     by_name: dict[str, float] = {}
     for g_lo, g_hi in gaps:
-        left = g_hi - g_lo
+        over = []
         for name, s, d in spans:
             if s >= g_hi:
                 break
-            cover = min(g_hi, s + d) - max(g_lo, s)
-            if cover > 0:
-                by_name[name] = by_name.get(name, 0.0) + cover / 1e9
-                left -= cover
-        if left > 0:
-            by_name["host"] = by_name.get("host", 0.0) + left / 1e9
-    return [
-        [k, v] for k, v in
-        sorted(by_name.items(), key=lambda kv: -kv[1])[:top]
-    ]
+            if s + d > g_lo:
+                over.append((s, s + d, name))
+        cuts = sorted({g_lo, g_hi} | {
+            t for s, e, _ in over for t in (s, e) if g_lo < t < g_hi
+        })
+        # a sweep: the open spans in a heap, on top the latest start
+        # (of two that start together, the shorter)
+        open_spans: list[tuple] = []
+        nxt = 0
+        for a, b in zip(cuts, cuts[1:]):
+            while nxt < len(over) and over[nxt][0] <= a:
+                s, e, name = over[nxt]
+                heapq.heappush(open_spans, (-s, e, name))
+                nxt += 1
+            while open_spans and open_spans[0][1] <= a:
+                heapq.heappop(open_spans)
+            name = open_spans[0][2] if open_spans else "host"
+            by_name[name] = by_name.get(name, 0.0) + (b - a) / 1e9
+    return by_name
 
 
 def describe(planes: list[dict]) -> list[dict]:

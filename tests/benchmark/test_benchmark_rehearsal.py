@@ -53,7 +53,6 @@ def cpu_stands_in():
     from cometbft_tpu.ops import precompute as PR
 
     mp = pytest.MonkeyPatch()
-    mp.setenv("CMT_TPU_ROUTE", "0")
     mp.setenv("CMT_TPU_DEVICE_MIN_BATCH", "2")
     mp.setenv("CMT_TPU_DISABLE_MESH_VERIFY", "1")
     mp.setenv("CMT_TPU_VERIFY_PREFETCH", "1")
@@ -75,19 +74,33 @@ def tiny_cell(name: str) -> dict:
     return cell
 
 
+def keeping(seen: dict | None, after_warm=None):
+    """An ``after_warm`` that also keeps the driver's state, in
+    ``seen["state"]``."""
+    def keep(state):
+        if seen is not None:
+            seen["state"] = state
+        if after_warm is not None:
+            after_warm(state)
+
+    return keep
+
+
 def drive(name: str, trace: bool = False, after_warm=None,
-          seconds: float = 60.0) -> dict:
+          seconds: float = 60.0, seen: dict | None = None) -> dict:
     """One run past the look for a chip; the window ends with the
-    chain, so the counts below are exact."""
+    chain, so the counts below are exact.  ``seen["state"]``: the
+    driver's state, for a look at it after the window."""
     cell = tiny_cell(name)
     return run.run_cell(cell, run.plan_chain(cell, SEED, sign_workers=1),
                         seconds, trace, jax.devices()[:1],
-                        after_warm=after_warm)
+                        after_warm=keeping(seen, after_warm))
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_a_run_end_to_end(cpu_stands_in, name, capfd):
-    line = drive(name)
+    seen = {}
+    line = drive(name, seen=seen)
     assert list(line) == ["correct", "attempted", "failed", "metrics",
                           "device", "compared"]
     assert line["correct"] is True
@@ -107,8 +120,18 @@ def test_a_run_end_to_end(cpu_stands_in, name, capfd):
     assert window["rejected"] == 3 == window["reference_scans"]
     assert window["compiles_in_window"]["count"] == 0
     assert set(window["counters"]["batches"]) == {"keyed/16"}
+    # the queue's launches by lane: the commit loop bypasses the queue
+    lanes = window["counters"]["queue_lane_sigs"]
+    assert lanes == ({"prefetch": window["counters"]["queue"]["launched_sigs"]}
+                     if name == "blocksync1k.replay" else {})
     assert err.rstrip().splitlines()[-1].startswith("compared ")
     json.dumps(line)
+    # every item with an outcome was let go of inside the window, and
+    # the comparison still had the whole chain's plain data to read
+    state = seen["state"]
+    assert state.commits == [None] * 12
+    assert len(state.chain.items) == 12
+    assert all(len(it.sigs) == N_VALS for it in state.chain.items)
 
 
 def test_a_traced_run_reports_the_layers(cpu_stands_in):
@@ -123,7 +146,14 @@ def test_a_traced_run_reports_the_layers(cpu_stands_in):
     assert not any(k.startswith("keyed_kernel") for k in line["metrics"])
     assert line["device"]["busy_s"] == 0.0 and line["device"]["window_s"] > 0
     assert line["breakdown"]["device_ops"] == []
-    assert line["breakdown"]["idle_gaps"][0][0].startswith("entry.")
+    # no device plane: the whole window is one gap, named by the
+    # program's spans where one covers an instant, by the harness's
+    # ``entry.`` annotations only where none does
+    gaps = dict(line["breakdown"]["idle_gaps"])
+    assert sum(gaps.values()) == pytest.approx(line["device"]["window_s"])
+    program = {k for k in gaps if k.startswith(trace_reduce.PROGRAM_SPANS)}
+    assert len(program) >= 6 and "blocksync/prefetch_items" in program
+    assert sum(gaps[k] for k in program) > 0.5 * sum(gaps.values())
     assert list(line)[-1] == "compared"
 
 
@@ -219,7 +249,7 @@ planes {
   }
   lines { id: 2 name: "XLA Ops" timestamp_ns: 1000
     events { metadata_id: 2 offset_ps: 0 duration_ps: 1000000 }
-    events { metadata_id: 3 offset_ps: 500000 duration_ps: 2000000 }
+    events { metadata_id: 3 offset_ps: 1500000 duration_ps: 2000000 }
     events { metadata_id: 2 offset_ps: 10000000 duration_ps: 3000000 }
     events { metadata_id: 2 offset_ps: 30000000 duration_ps: 3000000 }
   }
@@ -268,6 +298,155 @@ def test_trace_reduce_finds_a_known_busy_union():
     assert got["busy_s"] + sum(s for _, s in got["idle_gaps"]) == (
         pytest.approx(got["window_s"])
     )
+
+
+def test_device_ops_count_each_instant_once():
+    """A ``while`` event holds the fusions that run inside it: they are
+    left out and the loop keeps its whole time, so one launch's
+    operations add up to no more than its program's time (summed by
+    name alone, nested or not, the first launch read 13 of 10 us)."""
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                ("jit_verify_keyed_w8_b128(1)", 0.0, 10e3),
+                ("jit_verify_keyed_w8_b128(1)", 20e3, 10e3),
+            ]},
+            {"name": "XLA Ops", "events": [
+                # the loop first or last in the line: the order is by time
+                ("%fusion.2 = s32[4] fusion(%b)", 5e3, 2e3),
+                ("%while.67 = (u32[]) while(%t)", 1e3, 8e3),
+                ("%fusion.1 = s32[4] fusion(%a)", 2e3, 2e3),
+                ("%copy.9 = s32[4] copy(%c)", 9e3, 1e3),
+                ("%while.67 = (u32[]) while(%t)", 21e3, 8e3),
+                ("%fusion.1 = s32[4] fusion(%a)", 22e3, 3e3),
+                ("%fusion.2 = s32[4] fusion(%b)", 25e3, 4e3),
+            ]},
+        ]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "python", "events": [("entry.verify_commit", 0.0, 30e3)]},
+        ]},
+    ]
+    got = trace_reduce.reduce(planes)
+    assert got["device_ops"] == [["while.67", pytest.approx(16e-6)],
+                                 ["copy.9", pytest.approx(1e-6)]]
+    assert sum(s for _, s in got["device_ops"]) <= got["busy_s"]
+    assert got["busy_s"] == pytest.approx(20e-6)
+    assert got["programs"]["verify_keyed_w8_b128"]["launches"] == 2
+    # a loop inside a loop is the outer loop's
+    assert trace_reduce.top_level_times([
+        ("%while.1 = while()", 0.0, 10e3), ("%while.2 = while()", 1e3, 6e3),
+        ("%fusion.3 = fusion()", 2e3, 4e3), ("%fusion.3 = fusion()", 10e3, 1e3),
+    ]) == {"while.1": pytest.approx(10e-6), "fusion.3": pytest.approx(1e-6)}
+
+
+def test_load_reads_the_top_level_of_an_operation_line_before_the_cap(
+    monkeypatch
+):
+    """Four launches, each a ``while`` holding two fusions and a copy
+    after it.  ``load`` passes over what lies inside a loop before it
+    counts against ``MAX_OP_EVENTS``: capped at 6 KEPT events it reads
+    three launches whole, where the first 6 events of the line are one
+    launch and a half (the mega cell's slice was read so: two of its
+    four launches, PR 33)."""
+    from jax.profiler import ProfileData
+
+    launches = "".join(
+        f"""
+    events {{ metadata_id: 1 offset_ps: {t}000000 duration_ps: 8000000 }}
+    events {{ metadata_id: 2 offset_ps: {t + 1}000000 duration_ps: 3000000 }}
+    events {{ metadata_id: 2 offset_ps: {t + 4}000000 duration_ps: 3000000 }}
+    events {{ metadata_id: 3 offset_ps: {t + 8}000000 duration_ps: 1000000 }}"""
+        for t in (0, 20, 40, 60)
+    )
+    trace = f"""
+planes {{
+  id: 1 name: "/device:TPU:0"
+  lines {{ id: 2 name: "XLA Ops" timestamp_ns: 1000{launches}
+  }}
+  event_metadata {{ key: 1 value {{ id: 1 name: "%while.67 = (u32[]) while(%t)" }} }}
+  event_metadata {{ key: 2 value {{ id: 2 name: "%fusion.1 = s32[4] fusion(%a)" }} }}
+  event_metadata {{ key: 3 value {{ id: 3 name: "%copy.9 = s32[4] copy(%c)" }} }}
+}}
+"""
+    serialized = ProfileData.text_proto_to_serialized_xspace(trace)
+    whole = trace_reduce.load("", serialized=serialized)
+    events = whole[0]["lines"][0]["events"]
+    assert [trace_reduce.op_name(n) for n, _, _ in events] == (
+        ["while.67", "copy.9"] * 4
+    )
+    got = trace_reduce.reduce(whole)
+    assert got["device_ops"] == [["while.67", pytest.approx(32e-6)],
+                                 ["copy.9", pytest.approx(4e-6)]]
+    assert got["busy_s"] == pytest.approx(36e-6)  # no program line: the ops'
+    monkeypatch.setattr(trace_reduce, "MAX_OP_EVENTS", 6)
+    capped = trace_reduce.load("", serialized=serialized)
+    assert len(capped[0]["lines"][0]["events"]) == 6
+    assert trace_reduce.reduce(capped)["device_ops"] == [
+        ["while.67", pytest.approx(24e-6)], ["copy.9", pytest.approx(3e-6)],
+    ]
+
+
+def test_idle_gaps_are_named_by_one_annotation_an_instant():
+    """Nested and cross-thread annotations over two gaps whose shares
+    are known: an instant goes to the annotation that started last
+    among those covering it; the names' seconds add up to the gaps'."""
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                ("jit_verify_keyed_w8_b128(1)", 10e3, 10e3),
+                ("jit_verify_keyed_w8_b128(1)", 60e3, 10e3),
+            ]},
+        ]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "caller", "events": [
+                ("entry.verify_commit", 0.0, 50e3),
+                ("verify_commit", 2e3, 40e3),
+                ("verify_commit/sign_bytes", 4e3, 4e3),
+                ("gen.next", 50e3, 2e3),
+                ("entry.verify_commit", 52e3, 48e3),
+                ("not_a_program_span", 55e3, 40e3),
+            ]},
+            {"name": "launcher", "events": [
+                ("verify_queue/launch", 30e3, 25e3),
+                ("device_fetch", 90e3, 5e3),
+            ]},
+        ]},
+    ]
+    got = trace_reduce.reduce(planes)
+    assert got["window_s"] == pytest.approx(100e-6)
+    assert got["busy_s"] == pytest.approx(20e-6)
+    # gaps: 0-10, 20-60, 70-100 us
+    assert dict(got["idle_gaps"]) == {
+        # 0-2; 52-60 (it started after the launcher's span, which
+        # still runs to 55); 70-90; 95-100
+        "entry.verify_commit": pytest.approx((2 + 8 + 20 + 5) * 1e-6),
+        "verify_commit": pytest.approx((2 + 2 + 10) * 1e-6),  # 2-4, 8-10, 20-30
+        "verify_commit/sign_bytes": pytest.approx(4e-6),  # 4-8
+        # 30-50: the other thread's, begun later than the caller's two
+        "verify_queue/launch": pytest.approx(20e-6),
+        "gen.next": pytest.approx(2e-6),  # 50-52
+        "device_fetch": pytest.approx(5e-6),  # 90-95
+    }
+    assert sum(s for _, s in got["idle_gaps"]) + got["busy_s"] == (
+        pytest.approx(got["window_s"])
+    )
+    # the harness's prefixes alone: the old names, the same total
+    old = trace_reduce.reduce(planes, naming=())
+    assert dict(old["idle_gaps"]) == {
+        "entry.verify_commit": pytest.approx(78e-6),
+        "gen.next": pytest.approx(2e-6),
+    }
+    assert (old["window_s"], old["busy_s"]) == (got["window_s"],
+                                                got["busy_s"])
+
+
+def test_a_cut_list_ends_in_other_and_still_adds_up():
+    by_name = {f"op.{i}": float(20 - i) for i in range(14)}
+    got = trace_reduce._top(by_name, 10)
+    assert len(got) == 10 and got[0] == ["op.0", 20.0]
+    assert got[-1] == ["other", sum(20.0 - i for i in range(9, 14))]
+    assert sum(v for _, v in got) == sum(by_name.values())
+    assert trace_reduce._top({"a": 1.0}, 10) == [["a", 1.0]]
 
 
 def test_trace_reduce_without_a_device_plane_reports_no_busy_time():

@@ -25,9 +25,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from benchmark import run  # noqa: E402
+from benchmark import run, trace_reduce  # noqa: E402
 from tests.benchmark.test_benchmark_rehearsal import (  # noqa: E402,F401
     cpu_stands_in,
+    keeping,
 )
 
 CELL = "lightsync10k.stride100"
@@ -42,7 +43,7 @@ TINY = {
 LAYERS = {
     "device_sig_pct.light", "light_fetch_ms.light",
     "light_verify_ahead_ms.light", "light_witness_store_ms.light",
-    "commit_sign_bytes_ms.light",
+    "light_ahead_wait_ms.light", "commit_sign_bytes_ms.light",
     "commit_spec_lookup_ms.light", "queue_prehash_ms.light",
     "queue_resolve_ms.light", "fetch_wait_ms.light",
     "light_span_coverage_pct.light", "queue_batch_sigs.light",
@@ -63,9 +64,11 @@ def light_batch_16(cpu_stands_in):
     mp.undo()
 
 
-def drive(trace: bool = False, after_warm=None) -> dict:
+def drive(trace: bool = False, after_warm=None,
+          seen: dict | None = None) -> dict:
     """One run past the look for a chip; the window ends with the
-    chain, so the counts below are exact."""
+    chain, so the counts below are exact.  ``seen["state"]``: the
+    driver's state, for a look at it after the window."""
     cell = run.load_cell(CELL)
     cell["config"] = dict(cell["config"], validators=N_VALS)
     cell["traffic"] = copy.deepcopy(cell["traffic"])
@@ -73,11 +76,12 @@ def drive(trace: bool = False, after_warm=None) -> dict:
     cell["traffic"].update(reference_sample=8, trace_seconds=60.0)
     return run.run_cell(cell, run.plan_chain(cell, SEED, sign_workers=1),
                         60.0, trace, jax.devices()[:1],
-                        after_warm=after_warm)
+                        after_warm=keeping(seen, after_warm))
 
 
 def test_the_cell_end_to_end(light_batch_16, capfd):
-    line = drive()
+    seen = {}
+    line = drive(seen=seen)
     assert line["correct"] is True
     assert line["attempted"] == 40 and line["failed"] == 0
     assert set(line["metrics"]) == {"replay_blocks_per_s", "setup_s"}
@@ -99,6 +103,27 @@ def test_the_cell_end_to_end(light_batch_16, capfd):
     assert set(window["counters"]["batches"]) == {"keyed/16"}
     queue = window["counters"]["queue"]
     assert queue["launched_sigs"] == 16 * queue["launched_batches"]
+    assert window["counters"]["queue_lane_sigs"] == {
+        "light_client": queue["launched_sigs"]
+    }
+    assert window["counters"]["queue_lane_batches"] == {
+        "light_client": queue["launched_batches"]
+    }
+    # the driver let go of every target with a verdict, the providers
+    # of every block below the last trusted header; the comparison
+    # still had the whole chain's plain data
+    state = seen["state"]
+    assert state.commits == [None] * 40
+    items = state.chain.items
+    trusted = state.client.latest_trusted().height
+    assert trusted == max(
+        items[k].height for k in range(40)
+        if not any(i < state.checked for i in items[k].bad)
+    )
+    assert sorted(state.served) == list(state.serving) == [
+        it.height for it in items if it.height >= trusted
+    ]
+    assert len(items) == 40 and all(len(it.sigs) == N_VALS for it in items)
 
 
 def test_a_traced_rehearsal_reports_the_layers(light_batch_16):
@@ -110,7 +135,13 @@ def test_a_traced_rehearsal_reports_the_layers(light_batch_16):
     assert line["metrics"]["queue_batch_sigs.light"]["value"] == 16
     assert line["metrics"]["device_sig_pct.light"]["value"] == 100.0
     assert line["metrics"]["light_span_coverage_pct.light"]["value"] > 50
-    assert line["breakdown"]["idle_gaps"][0][0] == "entry.light_verify"
+    assert line["metrics"]["light_ahead_wait_ms.light"]["value"] > 0
+    # the idle time under the program's own span names, adding up
+    gaps = dict(line["breakdown"]["idle_gaps"])
+    assert sum(gaps.values()) == pytest.approx(line["device"]["window_s"])
+    program = {k for k in gaps if k.startswith(trace_reduce.PROGRAM_SPANS)}
+    assert len(program) >= 6 and any(k.startswith("light/") for k in program)
+    assert gaps.get("entry.light_verify", 0.0) < 0.5 * sum(gaps.values())
 
 
 def test_the_control_comes_out_not_correct(light_batch_16):

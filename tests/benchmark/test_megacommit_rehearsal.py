@@ -6,8 +6,9 @@ play the chip; ``run.py`` has no such switch).  What the cell is for is
 kept at the small size: 4-bit tables built in three chunks of 8 keys
 (the cell: ten of 1,024), and a commit wider than one launch slice —
 24 signatures pad to 32 lanes and run as two slices of 16 (the cell:
-16,384 lanes, two of 8,192), with the tampered signatures at the first
-lanes, across the seam and at the last lanes.  No CPU number here is a
+10,240 lanes in five slices of 2,048, lane 8,192 a seam), with the
+tampered signatures at the first lanes, across the seam and at the last
+lanes.  No CPU number here is a
 device number.
 """
 
@@ -32,6 +33,7 @@ from benchmark import run  # noqa: E402
 from tests.benchmark.test_benchmark_rehearsal import (  # noqa: E402,F401
     _break_verifier,
     cpu_stands_in,
+    keeping,
 )
 
 CELL = "megacommit10k.commit"
@@ -71,9 +73,11 @@ def mega_shapes(cpu_stands_in):
     mp.undo()
 
 
-def drive(trace: bool = False, after_warm=None, **params) -> dict:
+def drive(trace: bool = False, after_warm=None, seen: dict | None = None,
+          **params) -> dict:
     """One run past the look for a chip; the window ends with the
-    chain, so the counts below are exact."""
+    chain, so the counts below are exact.  ``seen["state"]``: the
+    driver's state, for a look at it after the window."""
     from cometbft_tpu.ops import precompute as PR
     from cometbft_tpu.utils.trace import TRACER
 
@@ -86,11 +90,12 @@ def drive(trace: bool = False, after_warm=None, **params) -> dict:
     cell["traffic"].update(reference_sample=8, trace_seconds=60.0)
     return run.run_cell(cell, run.plan_chain(cell, SEED, sign_workers=1),
                         60.0, trace, jax.devices()[:1],
-                        after_warm=after_warm)
+                        after_warm=keeping(seen, after_warm))
 
 
 def test_the_cell_end_to_end(mega_shapes, capfd):
-    line = drive()
+    seen = {}
+    line = drive(seen=seen)
     assert line["correct"] is True
     assert line["attempted"] == 6 and line["failed"] == 0
     # p50 and set-up, and not the 95th percentile of forty samples
@@ -113,6 +118,12 @@ def test_the_cell_end_to_end(mega_shapes, capfd):
     # all of it on the keyed tier, 24 signatures a batch, never demoted
     assert set(window["counters"]["batches"]) == {"keyed/32"}
     assert window["counters"]["transitions"] == 0
+    assert window["counters"]["queue_lane_sigs"] == {}  # no queue lane
+    # each commit let go of once its verdict was in; the comparison
+    # still had the whole chain's plain data
+    state = seen["state"]
+    assert state.commits == [None] * 6
+    assert all(len(it.sigs) == N_VALS for it in state.chain.items)
 
 
 def test_a_traced_rehearsal_reports_the_layers(mega_shapes):
