@@ -241,26 +241,25 @@ def comb_mul_keyed(table, key_ids, windows, window_bits: int):
     (nwin, *batch) int32 LE digit decomposition of k.
     Returns [k](-A_key) per lane as an extended point.
 
-    Each scan step gathers its lanes' ROWS straight from the whole
-    table (a free reshape to 2-D), so no step slices or re-lays
-    anything table-sized, and the launch holds no temporary; only the
-    fetched (batch, ROW) block is turned limbs-major.  (One gather of
-    all windows' rows ahead of the scan is faster still on the v5e —
-    PERF.md §6, PR 27 — and is the next step once the benchmark's
-    commit chain is long enough to time it.)"""
+    The lanes' ROWS for every window are gathered straight from the
+    whole table (a free reshape to 2-D) in ONE gather ahead of the scan
+    — the v5e pays a gather's fixed cost however few rows it fetches,
+    so one per window was most of the comb — and the scan only adds;
+    nothing table-sized is sliced or re-laid, and the launch holds the
+    lane-sized block of rows, ``nwin x lanes x ROW`` int32 (4 MB at 256
+    lanes x 8 bits, 64 MB a 2,048-lane slice, 256 MB at the straight
+    8,192 lanes x 4 bits; docs/device_kernel_perf.md §2.1)."""
     batch = key_ids.shape
     rows2d = table.reshape(-1, ROW)
+    row_ids = lane_rows(key_ids, windows, window_bits)  # (nwin, *batch)
+    block = jnp.take(rows2d, row_ids, axis=0, mode="clip")  # (.., ROW)
 
-    def body(acc, row_ids):
-        e = jnp.take(rows2d, row_ids, axis=0, mode="clip")  # (*batch, ROW)
+    def body(acc, e):  # e (*batch, ROW): one window's entries
         e = jnp.moveaxis(e[..., :ENTRY_LIMBS], -1, 0)
         e = e.reshape((4, F.NLIMBS) + batch)
         return C.pt_add_pniels(acc, (e[0], e[1], e[2], e[3])), None
 
-    acc, _ = lax.scan(
-        body, C.identity(batch), lane_rows(key_ids, windows, window_bits)
-    )
-    return acc
+    return lax.scan(body, C.identity(batch), block)[0]
 
 
 # -- per-key incremental table cache ----------------------------------

@@ -33,9 +33,17 @@ from cometbft_tpu.ops import precompute as PR
 
 BUCKET = 128  # canonical precommit sign-bytes are ~115 bytes
 V5E_HBM_BYTES = 16 << 30
-#: scratch a keyed launch may hold beside its arguments; the window-major
-#: table's per-window relayout alone was 135 MB at 256 slots x 8 bits
+#: scratch a keyed launch may hold beside its arguments and its comb's
+#: block of gathered rows; the window-major table's per-window relayout
+#: alone was 135 MB at 256 slots x 8 bits
 KEYED_TEMP_BYTES = 64 << 20
+
+
+def keyed_temp_bound(lanes: int, window_bits: int) -> int:
+    """KEYED_TEMP_BYTES and the block of table rows the keyed comb
+    gathers for ``lanes`` lanes (one slice's, in a wide program) ahead
+    of its scan: ``nwin x lanes x ROW`` int32."""
+    return KEYED_TEMP_BYTES + (256 // window_bits) * lanes * PR.ROW * 4
 
 
 @pytest.fixture(scope="module")
@@ -92,10 +100,21 @@ _MOVER = re.compile(
 _ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]+)\]")
 
 
-def table_sized_movers(hlo_text: str, min_elems: int) -> list[str]:
+def lane_block_elems(lanes: int, window_bits: int) -> frozenset[int]:
+    """Element counts of the block of rows the keyed comb gathers for
+    ``lanes`` lanes, whole rows or cut to an entry's limbs: it grows
+    with the lanes, never with the table."""
+    rows = (256 // window_bits) * lanes
+    return frozenset({rows * PR.ROW, rows * PR.ENTRY_LIMBS})
+
+
+def table_sized_movers(
+    hlo_text: str, min_elems: int, lane_block: frozenset[int] = frozenset()
+) -> list[str]:
     """The ``copy``, ``dynamic-slice`` and fusion instructions of a
     compiled program whose output holds ``min_elems`` elements or more
-    — what a relayout or a slice of the key table shows up as."""
+    — what a relayout or a slice of the key table shows up as — other
+    than the comb's gathered block of rows (``lane_block``)."""
     found = []
     for line in hlo_text.splitlines():
         m = _MOVER.match(line)
@@ -104,7 +123,7 @@ def table_sized_movers(hlo_text: str, min_elems: int) -> list[str]:
         name, out_type, op = m.groups()
         for dims in _ARRAY.findall(out_type):
             elems = math.prod(int(d) for d in dims.split(","))
-            if elems >= min_elems:
+            if elems >= min_elems and elems not in lane_block:
                 found.append(f"{op} {name} [{dims}]")
     return found
 
@@ -127,6 +146,26 @@ def test_table_sized_movers_finds_the_window_major_relayout():
         "copy copy.3562 [1,4,26,65536]",
         "fusion constant_dynamic-slice_fusion.14 [1,4,26,65536]",
     ]
+
+
+def test_table_sized_movers_passes_over_the_lane_block_only():
+    """At 1,024 lanes over the 1,024-slot 4-bit pool the comb's block of
+    rows (64 x 1,024 of them, as the v5e compiler wrote its gather) is
+    larger than one window of the table, and is passed over; a copy of
+    one window, or of the table, is still found."""
+    window = 1024 * 16 * PR.ENTRY_LIMBS
+    text = """
+  %fusion.5 = s32[65536,128]{1,0:T(8,128)S(1)} fusion(%bitcast, %broadcast_clamp_fusion.1), kind=kCustom, calls=%fused_computation.5
+  %copy.7 = s32[16384,128]{1,0:T(8,128)} copy(%param_1.2)
+  %copy.8 = s32[1024,1024,128]{2,1,0:T(8,128)} copy(%param_1.3)
+"""
+    block = lane_block_elems(1024, 4)
+    assert 65536 * 128 in block
+    assert table_sized_movers(text, window, block) == [
+        "copy copy.7 [16384,128]",
+        "copy copy.8 [1024,1024,128]",
+    ]
+    assert len(table_sized_movers(text, window)) == 3
 
 
 @pytest.mark.parametrize(
@@ -153,8 +192,10 @@ def test_keyed_verify_compiles_for_one_v5e(
     # the launch reads rows where they lie: nothing as large as one
     # window's entries of the table is copied, sliced or fused out
     window = slots * (1 << window_bits) * PR.ENTRY_LIMBS
-    assert table_sized_movers(compiled.as_text(), window) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < KEYED_TEMP_BYTES
+    block = lane_block_elems(lanes, window_bits)
+    assert table_sized_movers(compiled.as_text(), window, block) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < keyed_temp_bound(lanes, window_bits)
 
 
 def test_mega_commit_shapes_fit_one_v5e(topo, no_persistent_cache):
@@ -180,10 +221,13 @@ def test_mega_commit_shapes_fit_one_v5e(topo, no_persistent_cache):
     ).compile()
     assert device_bytes(compiled) < V5E_HBM_BYTES
     window = slots * (1 << window_bits) * PR.ENTRY_LIMBS
-    assert table_sized_movers(compiled.as_text(), window) == []
-    # one slice's working set (37 MB at 2,048 lanes), not the launch's
-    # and not the table's: the bound of the straight programs holds
-    assert compiled.memory_analysis().temp_size_in_bytes < KEYED_TEMP_BYTES
+    block = lane_block_elems(WIDE_SLICE, window_bits)
+    assert table_sized_movers(compiled.as_text(), window, block) == []
+    # one slice's working set (its arithmetic and its 64 MB block of
+    # rows at 2,048 lanes), not the launch's and not the table's: the
+    # bound of a straight program of one slice's lanes holds
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < keyed_temp_bound(WIDE_SLICE, window_bits)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

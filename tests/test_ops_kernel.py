@@ -497,6 +497,71 @@ class TestPrecompute:
         assert bool(e3.valid.all())
         check(e3.table, e3.key_index)
 
+    @pytest.mark.parametrize(
+        "window_bits,pool,batch",
+        [
+            pytest.param(4, "built", (6,), id="4bit-built"),
+            pytest.param(8, "built", (6,), id="8bit-built"),
+            pytest.param(4, "compacted", (6,), id="4bit-compacted"),
+            pytest.param(8, "compacted", (6,), id="8bit-compacted"),
+            pytest.param(8, "built", (2, 3), id="8bit-built-2d-batch"),
+        ],
+    )
+    def test_keyed_comb_block_gather_vs_oracle(
+        self, rng, monkeypatch, window_bits, pool, batch
+    ):
+        """The comb's rows for every window, gathered in one block ahead
+        of its scan, give the oracle's [k](-A) over a freshly built table
+        or a pool after growth and compaction, for a flat batch of lanes
+        or a 2-D one; a lane whose key id lies past the pool reads the
+        clipped last row at every window."""
+        from cometbft_tpu.ops import precompute as PR
+
+        points = [
+            E.pt_mul(rng.randrange(1, E.L), E.B_POINT)
+            for _ in range(3 if pool == "built" else 7)
+        ]
+        pubs = [E.encode_point(p) for p in points]
+        if pool == "built":
+            pub = np.stack(
+                [np.frombuffer(p, dtype=np.uint8) for p in pubs], axis=-1
+            )
+            table, _ = jax.jit(
+                lambda p: PR.build_tables_kernel(p, window_bits)
+            )(jnp.asarray(pub))
+            slot_of = dict(zip(pubs, range(3)))
+        else:
+            monkeypatch.setattr(
+                PR, "KEY8_MAX", 256 if window_bits == 8 else 0
+            )
+            cache = PR.KeyTableCache()
+            cache.lookup_or_build(pubs[:2])
+            cache.lookup_or_build(pubs[:5])  # growth 2 -> 8 slots
+            monkeypatch.setattr(cache, "_cap", 1)
+            entry = cache.lookup_or_build(pubs[3:])  # evict, compact
+            assert cache.stats["keys_evicted"] == 3
+            table, slot_of = entry.table, entry.key_index
+        cap = table.shape[0]
+        last = next(p for p, s in slot_of.items() if s == cap - 1)
+        point_of = dict(zip(pubs, points))
+        lanes = [list(slot_of)[i % len(slot_of)] for i in range(5)]
+        ids = np.array([slot_of[p] for p in lanes] + [cap], dtype=np.int32)
+        ks = [rng.randrange(E.L) for _ in ids]
+        wins = comb_digits(ks, window_bits)
+        comb = jax.jit(PR.comb_mul_keyed, static_argnums=3)
+        out = comb(
+            table, jnp.asarray(ids.reshape(batch)),
+            jnp.asarray(wins.reshape((-1,) + batch)), window_bits,
+        )
+        nwin, nent = 256 // window_bits, 1 << window_bits
+        clipped = nwin * (nent - 1) << (window_bits * (nwin - 1))
+        expect = [
+            E.pt_mul(k, E.pt_neg(point_of[p])) for p, k in zip(lanes, ks)
+        ] + [E.pt_mul(clipped % E.L, E.pt_neg(point_of[last]))]
+        flat = [np.asarray(c).reshape(c.shape[0], -1) for c in out]
+        for lane, ref in enumerate(expect):
+            assert affine_eq(tuple(c[:, lane] for c in flat), ref)
+
     def test_invalid_key_encoding_masked(self, rng):
         from cometbft_tpu.ops import precompute as PR
 
