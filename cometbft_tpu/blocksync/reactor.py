@@ -351,7 +351,9 @@ class BlocksyncReactor(Reactor):
                     if self._maybe_switch_to_consensus():
                         return
                 if not made_progress:
-                    self._quit.wait(POOL_TICK)
+                    # no block pair ready: the node waits for its peers
+                    with TRACER.span("blocksync/wait", cat="blocksync"):
+                        self._quit.wait(POOL_TICK)
             except ApplyError as exc:
                 # a validated block that does not apply leaves the app
                 # and the stores where nothing can be trusted: stop
@@ -378,6 +380,9 @@ class BlocksyncReactor(Reactor):
         if first is None or second is None:
             return False
         t0 = time.perf_counter()
+        # the thread clock only where the step is recorded: a system
+        # call on some hosts (utils/trace.py)
+        c0 = time.thread_time() if TRACER.enabled else None
         height = first.header.height
         with TRACER.span("blocksync/block_id", cat="blocksync"):
             first_bytes = codec.encode_block(first)
@@ -482,6 +487,7 @@ class BlocksyncReactor(Reactor):
         TRACER.add_complete(
             "blocksync/step", t0, time.perf_counter() - t0,
             cat="blocksync", args={"height": height},
+            thread_s=None if c0 is None else time.thread_time() - c0,
         )
         return True
 

@@ -313,7 +313,7 @@ class BlsLadderVerifier(BatchVerifier):
         wd = None
         try:
             with _tracer.span(
-                "batch_verify", cat="crypto",
+                "batch_verify", cat="crypto", thread_clock=True,
                 kernel=f"bls_{plan.mode}", batch=plan.n,
             ) as sp:
                 with _health.WATCHDOG.watch(

@@ -1,10 +1,10 @@
-"""Device-health plane: tier probes, launch watchdog, utilization.
+"""Device-health plane: tier probes and the launch watchdog.
 
 Two of five bench rounds silently lost the accelerator mid-run (r03: a
 failed backend init; r04: two 600 s hung attempts) — nothing in the process
 noticed until a human read the driver's rc=124.  This module turns
 those failure modes into signals the dispatch ladder (ROADMAP item 5)
-and an operator can act on, three instruments in one plane:
+and an operator can act on, two instruments in one plane:
 
 - **LaunchWatchdog** — bounds every real device launch at the
   ``TpuBatchVerifier.verify`` seam.  A launch that outlives its budget
@@ -27,21 +27,16 @@ and an operator can act on, three instruments in one plane:
   (crypto/batch.init_device_plane ran) and is a real accelerator:
   probing the XLA-on-CPU path would measure a tier no dispatch ever
   chooses.
-- **DeviceUsage** — busy/idle accounting between launches
-  (``crypto_device_busy_seconds_total{device}`` /
-  ``crypto_device_idle_seconds_total{device}``, per chip on the mesh),
-  the queue-wait vs kernel-wall split
-  (``crypto_launch_queue_wait_seconds`` vs the existing
-  ``crypto_kernel_time_seconds``), and the host/device overlap ratio
-  (``crypto_host_device_overlap_ratio``) — the instrument that will
-  prove where verify-ahead pipelining (ROADMAP item 2) lands.
 
 Surfaces: ``/debug/perf`` on the metrics server and the ``debug/perf``
 JSON-RPC route (inspect mode included) serve ``debug_perf_payload()``
-— current tier health, last probe latencies, watchdog state,
-utilization, and the perf-ledger tail (CMT_TPU_PERF_LEDGER,
-tools/perfledger.py).  Documented in docs/observability.md
-("Device-health plane").
+— current tier health, last probe latencies, watchdog state, and the
+perf-ledger tail (CMT_TPU_PERF_LEDGER, tools/perfledger.py).  Where a
+launch's time goes is not here: the span ring (``utils/trace.py``,
+served at ``/trace``) has each launch's ``batch_verify`` and its steps,
+each with its wall time (``dur``), the root and its fetch also with
+their thread's CPU time (``tdur``).  Documented in
+docs/observability.md ("Device-health plane").
 """
 
 from __future__ import annotations
@@ -282,114 +277,6 @@ class LaunchWatchdog:
                 for e in self._active.values()
             ]
         return {"budget_s": self.budget_s, "active_launches": active}
-
-
-class DeviceUsage:
-    """Busy/idle accounting between launches + the queue-wait /
-    fetch-wait instrumentation (module docstring).  All methods are a
-    few float ops under one mutex — cheap enough for the per-batch hot
-    path; the fetch-wait accumulator is thread-local so concurrent
-    verifiers don't cross-charge each other's blocking fetches."""
-
-    def __init__(self):
-        self._mtx = cmtsync.Mutex()
-        self._tl = threading.local()
-        # guarded by _mtx: _covered_until is the high-water mark of
-        # wall time already accounted busy — concurrent verifies (a
-        # prober canary overlapping a production batch) contribute the
-        # UNION of their launch intervals, so busy+idle never exceeds
-        # wall time
-        self._covered_until: float | None = None
-        self._busy: dict[str, float] = {}
-        self._idle: dict[str, float] = {}
-        self._launches = 0
-        self._last_overlap: float | None = None
-        self._last_queue_wait: float | None = None
-        self._last_fetch_wait: float | None = None
-
-    # -- fetch-wait accumulator (hot fetch sites wrap device_get) --------
-
-    @contextmanager
-    def timed_fetch(self):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self._tl.fetch = getattr(self._tl, "fetch", 0.0) + dt
-
-    def fetch_wait(self) -> float:
-        """This thread's accumulated blocking-fetch seconds."""
-        return getattr(self._tl, "fetch", 0.0)
-
-    # -- per-launch accounting (TpuBatchVerifier.verify seam) ------------
-
-    def note_queue_wait(self, seconds: float) -> None:
-        _health_metrics().launch_queue_wait_seconds.observe(seconds)
-        with self._mtx:
-            self._last_queue_wait = seconds
-
-    def launch_end(
-        self, t_launch: float, ndev: int = 1, fetch_wait: float = 0.0
-    ) -> None:
-        """Account one finished launch: busy = the not-yet-covered
-        part of [t_launch, now) on each of ``ndev`` chips (union
-        semantics under concurrent launches), idle = the uncovered gap
-        before it, overlap = the share of the launch wall the host did
-        NOT spend blocked in the result fetch."""
-        now = time.perf_counter()
-        wall = max(now - t_launch, 0.0)
-        hm = _health_metrics()
-        with self._mtx:
-            prev = self._covered_until
-            idle = 0.0
-            if prev is None:
-                busy = wall
-            else:
-                idle = max(t_launch - prev, 0.0)
-                busy = max(now - max(t_launch, prev), 0.0)
-            self._covered_until = max(prev or now, now)
-            self._launches += 1
-            for d in range(max(ndev, 1)):
-                dev = str(d)
-                self._busy[dev] = self._busy.get(dev, 0.0) + busy
-                if idle:
-                    self._idle[dev] = self._idle.get(dev, 0.0) + idle
-            overlap = None
-            if wall > 0:
-                overlap = min(max(1.0 - fetch_wait / wall, 0.0), 1.0)
-                self._last_overlap = overlap
-            self._last_fetch_wait = fetch_wait
-        for d in range(max(ndev, 1)):
-            hm.device_busy_seconds_total.labels(device=str(d)).inc(busy)
-            if idle:
-                hm.device_idle_seconds_total.labels(device=str(d)).inc(
-                    idle
-                )
-        if overlap is not None:
-            hm.host_device_overlap_ratio.set(overlap)
-
-    def snapshot(self) -> dict:
-        with self._mtx:
-            busy = dict(self._busy)
-            idle = dict(self._idle)
-            total_busy = sum(busy.values())
-            total = total_busy + sum(idle.values())
-            return {
-                "launches": self._launches,
-                "busy_seconds": {
-                    d: round(v, 6) for d, v in sorted(busy.items())
-                },
-                "idle_seconds": {
-                    d: round(v, 6) for d, v in sorted(idle.items())
-                },
-                "occupancy": (
-                    round(total_busy / total, 4) if total > 0 else None
-                ),
-                "overlap_ratio": self._last_overlap,
-                "last_queue_wait_s": self._last_queue_wait,
-                "last_fetch_wait_s": self._last_fetch_wait,
-            }
 
 
 class HealthProber(BaseService):
@@ -769,11 +656,10 @@ def _probe_generic_mesh() -> bool:
     return bool(out.all())
 
 
-#: process-wide singletons — the verifier seam and probers all feed
-#: the same watchdog/usage state every surface reads (mirrors
+#: process-wide singleton — the verifier seam and probers all feed
+#: the same watchdog state every surface reads (mirrors
 #: utils/flight.FLIGHT)
 WATCHDOG = LaunchWatchdog()
-USAGE = DeviceUsage()
 
 
 # -- the /debug/perf payload ---------------------------------------------
@@ -802,8 +688,10 @@ def perf_ledger_tail(n: int = 10) -> list[dict]:
 
 def debug_perf_payload(ledger_tail_n: int = 10) -> dict:
     """Everything ``/debug/perf`` serves: tier health + last probe
-    latencies, watchdog state, utilization gauges, device-plane
-    status, and the perf-ledger tail."""
+    latencies, watchdog state, device-plane status, and the
+    perf-ledger tail.  A launch's timing is the span ring's
+    (``/trace``: ``batch_verify`` and its steps, ``dur``; the root
+    and its fetch also ``tdur``)."""
     from cometbft_tpu.crypto import batch as _batch
 
     prober = _ACTIVE_PROBER
@@ -815,7 +703,6 @@ def debug_perf_payload(ledger_tail_n: int = 10) -> dict:
             else {"running": False, "tiers": {}}
         ),
         "watchdog": WATCHDOG.snapshot(),
-        "utilization": USAGE.snapshot(),
         "ledger": {
             "path": perf_ledger_path(),
             "tail": perf_ledger_tail(ledger_tail_n),
@@ -827,9 +714,7 @@ __all__ = [
     "DEFAULT_HEALTH_INTERVAL_S",
     "DEFAULT_LAUNCH_BUDGET_S",
     "TIERS",
-    "USAGE",
     "WATCHDOG",
-    "DeviceUsage",
     "HealthProber",
     "LaunchWatchdog",
     "debug_perf_payload",

@@ -344,7 +344,9 @@ class Client:
             if len(heights) > 1 else None,
         )
         for _ in heights:
-            with _tracer.span("light/verify", cat="light") as root:
+            with _tracer.span(
+                "light/verify", cat="light", thread_clock=True,
+            ) as root:
                 height, got, trusted, future = ahead.next()
                 root.set(height=height)
                 lb = err = None
@@ -382,7 +384,9 @@ class Client:
         decides: it finds positive verdicts cached or verifies."""
         if future is None:
             return
-        with _tracer.span("light/ahead_wait", cat="light"):
+        with _tracer.span(
+            "light/ahead_wait", cat="light", thread_clock=True,
+        ):
             try:
                 future.result()
             except _vq.QueueUnavailable:

@@ -639,7 +639,6 @@ class CryptoMetrics:
     def __init__(self, reg: Registry | None = None):
         if reg is None:
             self.batch_verify_launches = _NOP
-            self.batch_verify_padded_lanes = _NOP
             self.batch_verify_batch_size = _NOP
             self.dispatch_decisions = _NOP
             self.dispatch_tier = _NOP
@@ -666,14 +665,6 @@ class CryptoMetrics:
             s, "batch_verify_launches",
             "Batch-verify launches by kernel "
             "(generic | keyed | host_rlc).",
-            labels=("kernel",),
-        )
-        self.batch_verify_padded_lanes = reg.counter(
-            s, "batch_verify_padded_lanes",
-            "Lanes of device launches that carried no signature "
-            "(lanes - signatures per launch), by kernel "
-            "(generic | keyed): over batch_verify_launches it is the "
-            "padding a launch pays for.",
             labels=("kernel",),
         )
         self.batch_verify_batch_size = reg.histogram(
@@ -839,13 +830,13 @@ class HealthMetrics:
     CryptoMetrics measures what the device path DID (launches, bytes,
     tiers); this family measures whether it is healthy enough to keep
     doing it: per-tier canary-probe latency and health, hang-watchdog
-    trips, busy/idle occupancy between launches, and the host/device
-    overlap the pipelined paths are supposed to buy.  No metricsgen
-    analog — the reference has no accelerator to lose mid-run (two of
-    five bench rounds did).  Same ``crypto`` subsystem prefix as
+    trips, and the host/device overlap the verify queue's pipelining
+    is supposed to buy.  No metricsgen analog — the reference has no
+    accelerator to lose mid-run (two of five bench rounds did).  Same ``crypto`` subsystem prefix as
     CryptoMetrics so the series sit next to the dispatch ladder they
     explain; updated through the process-wide health sink
-    (``health_metrics()``) by cometbft_tpu/crypto/health.py.
+    (``health_metrics()``) by cometbft_tpu/crypto/health.py and, for
+    the overlap ratio, crypto/verify_queue.py.
     """
 
     def __init__(self, reg: Registry | None = None):
@@ -853,9 +844,6 @@ class HealthMetrics:
             self.tier_probe_seconds = self.tier_healthy = _NOP
             self.tier_probe_failures_total = _NOP
             self.device_hangs_total = _NOP
-            self.device_busy_seconds_total = _NOP
-            self.device_idle_seconds_total = _NOP
-            self.launch_queue_wait_seconds = _NOP
             self.host_device_overlap_ratio = _NOP
             return
         s = "crypto"
@@ -888,35 +876,13 @@ class HealthMetrics:
             "counter + a flight-recorder event instead of a silent "
             "stall.",
         )
-        self.device_busy_seconds_total = reg.counter(
-            s, "device_busy_seconds_total",
-            "Wall seconds the device spent inside batch-verify "
-            "launches (dispatch through result fetch), per chip "
-            "(device label is the mesh position; \"0\" single-chip).",
-            labels=("device",),
-        )
-        self.device_idle_seconds_total = reg.counter(
-            s, "device_idle_seconds_total",
-            "Wall seconds the device sat idle BETWEEN batch-verify "
-            "launches, per chip — busy/(busy+idle) is the occupancy "
-            "the verify-ahead pipelining (ROADMAP item 2) must raise.",
-            labels=("device",),
-        )
-        self.launch_queue_wait_seconds = reg.histogram(
-            s, "launch_queue_wait_seconds",
-            "Host-side seconds a batch spent between entering "
-            "TpuBatchVerifier.verify and its device dispatch (table "
-            "lookup + packing + routing) — the queue-wait half of the "
-            "queue-wait vs kernel-wall split (the kernel half is "
-            "crypto_kernel_time_seconds).",
-            buckets=DEFAULT_TIME_BUCKETS,
-        )
         self.host_device_overlap_ratio = reg.gauge(
             s, "host_device_overlap_ratio",
-            "Fraction of the last launch's device wall time the host "
-            "spent NOT blocked in the result fetch (1 - fetch_wait / "
-            "launch_wall): ~0 means lockstep sync dispatch, ->1 means "
-            "host work fully overlaps device compute.",
+            "Share of the verify queue's launch wall time covered by "
+            "its collector preparing the next batch "
+            "(crypto/verify_queue.py): ~0 means host prep and launches "
+            "run in lockstep, ->1 means host prep fully overlaps the "
+            "launches.",
         )
 
 

@@ -167,7 +167,7 @@ class Node(BaseService):
             # the WAN-emulation plane (p2p/conn/netem.py stages are
             # constructed per peer with no node handle) — same sink
             install_netem_metrics(self.metrics.netem)
-            # the device-health plane (watchdog, prober, utilization —
+            # the device-health plane (watchdog, prober —
             # crypto/health.py) shares the singleton-sink pattern
             install_health_metrics(self.metrics.health)
             # the light serving plane (header cache + request surface,

@@ -39,6 +39,7 @@ Batch shaping (TPU-first):
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 
@@ -337,13 +338,13 @@ def _launch_span(kernel: str, packed: np.ndarray, sigs: int, slices: int,
                  bucket: int, **args):
     """One launch's counters and its ``device_launch`` span: ``batch``
     lanes carrying ``sigs`` signatures in ``slices`` slices, so a ring
-    gives a launch's occupancy as sigs / batch.  The span covers the
-    (async) dispatch, not device compute — the synchronous wall time
-    is the kernel_time_seconds histogram."""
+    gives a launch's occupancy as sigs / batch and its padding as
+    batch - sigs.  The span covers the (async) dispatch, not device
+    compute — the synchronous wall time is the kernel_time_seconds
+    histogram."""
     lanes = packed.shape[-1]
     cm = _crypto_metrics()
     cm.batch_verify_launches.labels(kernel=kernel).inc()
-    cm.batch_verify_padded_lanes.labels(kernel=kernel).inc(lanes - sigs)
     cm.bytes_transferred.labels(direction="h2d").inc(packed.nbytes)
     return _tracer.span(
         "device_launch", cat="device", kernel=kernel, batch=lanes,
@@ -469,21 +470,20 @@ def _finish(parts) -> np.ndarray:
     transfers — recognizes it as the audited fetch)."""
     if len(parts) == 1:
         p, k = parts[0]
-        # timed_fetch: the blocking-fetch seconds feed the host/device
-        # overlap ratio (crypto/health.py DeviceUsage); the device_fetch
-        # span is the same wait in a profiler session's host plane,
-        # beside the program it waits for
+        # the device_fetch span is the wait in a profiler session's
+        # host plane, beside the program it waits for
         with _tracer.span(
-            "device_fetch", cat="device", batch=k,
-        ), _health.USAGE.timed_fetch():
+            "device_fetch", cat="device", thread_clock=True, batch=k,
+        ):
             out = jax.device_get(p)  # host sync: the one audited per-batch result fetch
         _crypto_metrics().bytes_transferred.labels(
             direction="d2h"
         ).inc(out.nbytes)
         return out[:k]
     with _tracer.span(
-        "device_fetch", cat="device", batch=sum(k for _, k in parts),
-    ), _health.USAGE.timed_fetch():
+        "device_fetch", cat="device", thread_clock=True,
+        batch=sum(k for _, k in parts),
+    ):
         combined = jax.device_get(  # host sync: single combined fetch for all parts
             jnp.concatenate([p for p, _ in parts])
         )
@@ -635,7 +635,7 @@ class _VerifyPlan:
 
     __slots__ = (
         "n", "route", "reason", "entry", "key_ids", "pub", "sig",
-        "msgs", "pubs", "sigs", "t_plan", "tiers",
+        "msgs", "pubs", "sigs", "tiers",
     )
 
     def __init__(self) -> None:
@@ -649,7 +649,6 @@ class _VerifyPlan:
         self.msgs: list[bytes] = []
         self.pubs: list[bytes] = []
         self.sigs: list[bytes] = []
-        self.t_plan = 0.0
         #: ladder-admissible tiers for this batch, best first, always
         #: ending in the host/python floor (crypto/dispatch.py);
         #: execute() walks this list top-down
@@ -672,10 +671,6 @@ class TpuBatchVerifier(BatchVerifier):
         # the _run_* seam that executed (mesh subclasses report their
         # own tiers); verify() feeds it to crypto_dispatch_tier
         self._last_tier: str | None = None
-        # chips a launch occupies, for the per-device busy/idle
-        # accounting (crypto/health.py DeviceUsage); the mesh verifier
-        # overrides this with its device count
-        self._usage_ndev = 1
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         if pub_key.type() != _ed.KEY_TYPE:
@@ -714,7 +709,6 @@ class TpuBatchVerifier(BatchVerifier):
 
     def _plan(self) -> _VerifyPlan:
         plan = _VerifyPlan()
-        plan.t_plan = time.perf_counter()
         n = plan.n = len(self._pubs)
         if n == 0:
             return plan
@@ -819,7 +813,6 @@ class TpuBatchVerifier(BatchVerifier):
         ladder = _failover.LADDER
         n = plan.n
         self._last_tier = None
-        queue_wait_noted = False
         last_exc: BaseException | None = None
         tiers = plan.tiers or ["host", _failover.FLOOR_TIER]
         for pos, tier in enumerate(tiers):
@@ -835,14 +828,7 @@ class TpuBatchVerifier(BatchVerifier):
                     ok, results = self._run_host(plan)
                 else:
                     t0 = time.perf_counter()
-                    # flag BEFORE the launch: a faulting tier must not
-                    # make the fallback rung observe the queue wait
-                    # again, inflated by the failed launch's wall
-                    note_qw = not queue_wait_noted
-                    queue_wait_noted = True
-                    results = self._launch_tier(
-                        tier, plan, note_queue_wait=note_qw
-                    )
+                    results = self._launch_tier(tier, plan)
                     ok = all(results)
                     cm.kernel_time_seconds.observe(
                         time.perf_counter() - t0
@@ -882,52 +868,46 @@ class TpuBatchVerifier(BatchVerifier):
 
     # -- per-tier execution ----------------------------------------------
 
-    def _launch_tier(
-        self, tier: str, plan: _VerifyPlan, note_queue_wait: bool = True
-    ) -> list[bool]:
-        """One device-tier attempt: span + sealed-transfer window +
-        watchdog + busy/idle accounting around the tier's runner.
-        Returns the per-signature verdict list."""
+    def _launch_tier(self, tier: str, plan: _VerifyPlan) -> list[bool]:
+        """One device-tier attempt: the ``batch_verify`` span over the
+        sealed-transfer window, the watchdog and the tier's runner.
+        Its steps are spans of their own on this thread:
+        ``batch_verify/arm`` (the window entered, the watchdog armed,
+        the chaos hook), the runner's ``verify/pack``,
+        ``device_launch`` and ``device_fetch``, then
+        ``batch_verify/settle`` (the watchdog disarmed, the verdict
+        list).  Returns the per-signature verdict list."""
         n = plan.n
         wd = None
         try:
             with _tracer.span(
-                "batch_verify", cat="crypto", kernel=tier, batch=n,
-            ) as sp:
-                # steady-state window: once jitguard is armed and
-                # sealed, an implicit host<->device transfer anywhere
-                # in the dispatch raises at the offending line instead
-                # of silently paying the link RTT per batch
-                with _jitguard.transfer_window():
-                    # health seam: queue-wait (host prep + any time the
-                    # plan sat in the verify queue before dispatch),
-                    # the launch watchdog (a wedged launch becomes
+                "batch_verify", cat="crypto", thread_clock=True,
+                kernel=tier, batch=n,
+            ) as sp, contextlib.ExitStack() as armed:
+                with _tracer.span("batch_verify/arm", cat="crypto"):
+                    # steady-state window: once jitguard is armed and
+                    # sealed, an implicit host<->device transfer
+                    # anywhere in the dispatch raises at the offending
+                    # line instead of silently paying the link RTT per
+                    # batch
+                    armed.enter_context(_jitguard.transfer_window())
+                    # the launch watchdog: a wedged launch becomes
                     # crypto_device_hangs_total + a flight event inside
-                    # its budget, not a silent stall), and busy/idle +
-                    # overlap accounting over the launch wall
-                    t_launch = time.perf_counter()
-                    if note_queue_wait:
-                        _health.USAGE.note_queue_wait(
-                            t_launch - plan.t_plan
-                        )
-                    fetch0 = _health.USAGE.fetch_wait()
-                    with _health.WATCHDOG.watch(
-                        tier=tier, batch=n
-                    ) as wd:
-                        # chaos injects INSIDE the armed watchdog
-                        # window: a launch_hang fault sleeps past the
-                        # budget while the watchdog is watching, so
-                        # the overrun fires (counter + flight event +
-                        # ladder demotion) before the stalled "launch"
-                        # returns — the r04 signature, reproduced end
-                        # to end (crypto/dispatch.py)
-                        _failover.CHAOS.inject(tier)
-                        out = self._run_tier(tier, plan)
-                    _health.USAGE.launch_end(
-                        t_launch, ndev=self._tier_ndev(tier),
-                        fetch_wait=_health.USAGE.fetch_wait() - fetch0,
+                    # its budget, not a silent stall
+                    wd = armed.enter_context(
+                        _health.WATCHDOG.watch(tier=tier, batch=n)
                     )
-                results = [bool(v) for v in out]
+                    # chaos injects INSIDE the armed watchdog window: a
+                    # launch_hang fault sleeps past the budget while
+                    # the watchdog is watching, so the overrun fires
+                    # (counter + flight event + ladder demotion) before
+                    # the stalled "launch" returns — the r04 signature,
+                    # reproduced end to end (crypto/dispatch.py)
+                    _failover.CHAOS.inject(tier)
+                out = self._run_tier(tier, plan)
+                with _tracer.span("batch_verify/settle", cat="crypto"):
+                    armed.close()  # the watchdog, then the window
+                    results = [bool(v) for v in out]
                 sp.set(ok=all(results), tier=tier)
             return results
         except Exception as exc:
@@ -948,11 +928,6 @@ class TpuBatchVerifier(BatchVerifier):
         if tier == "generic":
             return self._run_generic(plan.pub, plan.sig, plan.msgs)
         raise _failover.TierUnavailable(tier, "no runner on this seam")
-
-    def _tier_ndev(self, tier: str) -> int:
-        """Chips one launch of ``tier`` occupies (busy/idle
-        accounting); mesh tiers override via _usage_ndev."""
-        return 1
 
     def _run_host(self, plan: _VerifyPlan) -> tuple[bool, list[bool]]:
         """The native host batch tier (Pippenger/RLC MSM with the

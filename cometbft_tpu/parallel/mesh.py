@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from cometbft_tpu.crypto import health as _health
 from cometbft_tpu.utils.env import flag_from_env
 from cometbft_tpu.metrics import crypto_metrics as _crypto_metrics
 from cometbft_tpu.ops import field as _field
@@ -247,8 +246,6 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
         super().__init__(**kwargs)
         self._mesh = mesh or flat_mesh()
         self._ndev = int(self._mesh.devices.size)
-        # per-chip busy/idle attribution (crypto/health.py DeviceUsage)
-        self._usage_ndev = self._ndev
 
     # -- ladder eligibility (crypto/dispatch.py owns admissibility) ------
 
@@ -282,11 +279,6 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
         # the single-device keyed/generic rungs (tables and batch on
         # the default device) come from the base seam
         return super()._run_tier(tier, plan)
-
-    def _tier_ndev(self, tier: str) -> int:
-        from cometbft_tpu.crypto.dispatch import MESH_TIERS
-
-        return self._usage_ndev if tier in MESH_TIERS else 1
 
     def _pad_cols(
         self, packed: np.ndarray, chunk: int | None = None
@@ -335,8 +327,8 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
                 jax.device_put(packed, self._sharding(None, DATA_AXIS))
             )
         with _tracer.span(
-            "device_fetch", cat="device", batch=n,
-        ), _health.USAGE.timed_fetch():
+            "device_fetch", cat="device", thread_clock=True, batch=n,
+        ):
             res = jax.device_get(out)  # host sync: single per-batch result gather off the mesh
         return res[:n]
 
@@ -408,8 +400,8 @@ class ShardedTpuBatchVerifier(TpuBatchVerifier):
                 valid,
             )
         with _tracer.span(
-            "device_fetch", cat="device", batch=n,
-        ), _health.USAGE.timed_fetch():
+            "device_fetch", cat="device", thread_clock=True, batch=n,
+        ):
             res = jax.device_get(out)  # host sync: single per-batch result gather off the mesh
         cm.bytes_transferred.labels(direction="d2h").inc(res.nbytes)
         return res[dest]  # unscatter to original lane order

@@ -437,8 +437,8 @@ class Environment:
     def debug_perf(self) -> dict:
         """Device-health/perf snapshot (crypto/health.py): per-tier
         canary health + last probe latencies, launch-watchdog state,
-        busy/idle utilization with the host/device overlap ratio, and
-        the perf-ledger tail.  Served on a live node AND in inspect
+        and the perf-ledger tail (a launch's timing is the span ring's,
+        ``trace``).  Served on a live node AND in inspect
         mode — a wedged accelerator is precisely when the node may not
         be running (docs/observability.md "Device-health plane")."""
         from cometbft_tpu.crypto.health import debug_perf_payload

@@ -279,7 +279,9 @@ DEBUG_ENDPOINTS = (
      "Always-on flight recorder: recent replication events."),
     ("/debug/perf", "metrics",
      "Device-health plane: tier health + probe latencies, launch "
-     "watchdog, busy/idle utilization, perf-ledger tail."),
+     "watchdog, perf-ledger tail (a launch's timing is /trace: "
+     "batch_verify and its steps, dur; tdur on the root and its "
+     "fetch)."),
     ("/debug/dispatch", "metrics",
      "Failover dispatch ladder: per-tier demotion/cool-down state, "
      "the recent transition trail, and the armed chaos plan."),

@@ -21,6 +21,18 @@ Design constraints, in order:
   most recent window, never an unbounded log.
 - **No dependencies**: stdlib only; importable from every plane
   (crypto, ops, consensus, tools) without dragging jax in.
+- **Waiting or working**: a span opened with ``thread_clock=True``
+  also reads its thread's CPU clock (``time.thread_time``) at both
+  ends and records it as ``tdur``, the Chrome trace-event field for a
+  slice's thread-clock duration.  ``dur - tdur`` is the span's off-CPU
+  time: the thread was ready or blocked, not running (the interpreter
+  lock, the OS's own preemption, I/O, or a device wait).  Only the
+  spans a metric reads ask for it: on some hosts the thread clock is a
+  system call of several microseconds that advances in scheduler ticks
+  (10 ms on the TPU v5e host), so one span's ``tdur`` may read 0 or a
+  whole tick more than ``dur``, and only a mean over many spans means
+  anything.  ``add_complete`` records it only where the caller passes
+  ``thread_s``.
 - **One clock with the device trace**: ``set_annotator`` takes a
   ``name -> context manager`` callable (``cometbft_tpu/ops`` installs
   ``jax.profiler.TraceAnnotation``); every lexical span then also
@@ -84,15 +96,19 @@ class _Span:
     """One in-flight span; records a complete ("ph": "X") event on exit."""
 
     __slots__ = (
-        "_tracer", "name", "cat", "args", "_t0", "_parent", "_annotation",
+        "_tracer", "name", "cat", "args", "_t0", "_c0", "_parent",
+        "_annotation",
     )
 
-    def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: "SpanTracer", name: str, cat: str,
+                 args: dict, thread_clock: bool):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
         self._annotation = None
+        #: the thread clock at entry; None: the span reads no thread clock
+        self._c0 = 0.0 if thread_clock else None
 
     def set(self, **args) -> None:
         """Attach result data discovered mid-span (e.g. batch verdict)."""
@@ -108,6 +124,9 @@ class _Span:
         # the GIL, and this is the lexical-span hot path.
         self._tracer._active[threading.get_ident()] = self.name
         self._t0 = time.perf_counter()
+        if self._c0 is not None:
+            # read inside the wall clock's interval at both ends
+            self._c0 = time.thread_time()
         # the annotation sits INSIDE the span's own interval (entered
         # after the start is taken, left before the end is), so the
         # ring's duration never reads shorter than the profiler's
@@ -120,6 +139,8 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
+        c0 = self._c0
+        thread_s = None if c0 is None else time.thread_time() - c0
         end = time.perf_counter()
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
@@ -133,7 +154,7 @@ class _Span:
             self.args["error"] = exc_type.__name__
         self._tracer._record(
             self.name, self.cat, self._t0, end - self._t0, self.args,
-            self._parent,
+            self._parent, thread_s,
         )
         return False
 
@@ -147,7 +168,8 @@ class SpanTracer:
     the fact from explicit perf_counter timestamps — used by the
     consensus state machine, whose steps begin and end at different
     call sites; such spans stay ring-only (an annotation cannot be
-    written after the fact).
+    written after the fact), and carry ``tdur`` only where the caller
+    read its own thread's clock (``thread_s``).
     """
 
     def __init__(
@@ -202,11 +224,15 @@ class SpanTracer:
             stack = self._tls.stack = []
         return stack
 
-    def span(self, name: str, cat: str = "app", **args):
-        """A context-manager span; the shared no-op when disabled."""
+    def span(self, name: str, cat: str = "app", thread_clock: bool = False,
+             **args):
+        """A context-manager span; the shared no-op when disabled.
+        ``thread_clock``: record the thread's CPU time as ``tdur`` too
+        (module docstring: for the spans a metric reads, not by
+        default)."""
         if not self.enabled:
             return _NOP_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self, name, cat, args, thread_clock)
 
     def add_complete(
         self,
@@ -215,12 +241,18 @@ class SpanTracer:
         duration_s: float,
         cat: str = "app",
         args: dict | None = None,
+        thread_s: float | None = None,
     ) -> None:
         """Record a span from explicit ``time.perf_counter()`` values
-        (``start`` in perf_counter time, not trace microseconds)."""
+        (``start`` in perf_counter time, not trace microseconds).
+        ``thread_s``: the recording thread's ``time.thread_time()``
+        over the same interval, written as ``tdur``; a span that waits
+        across threads has none."""
         if not self.enabled:
             return
-        self._record(name, cat, start, duration_s, args or {}, None)
+        self._record(
+            name, cat, start, duration_s, args or {}, None, thread_s
+        )
 
     def _record(
         self,
@@ -230,6 +262,7 @@ class SpanTracer:
         duration_s: float,
         args: dict,
         parent: str | None,
+        thread_s: float | None = None,
     ) -> None:
         if parent is not None:
             args = dict(args, parent=parent)
@@ -246,6 +279,8 @@ class SpanTracer:
             "tid": thread.ident,
             "args": args,
         }
+        if thread_s is not None:
+            event["tdur"] = int(max(thread_s, 0.0) * 1e7 + 0.5) / 10
         with self._mtx:
             if len(self._events) == self._events.maxlen:
                 self._dropped += 1

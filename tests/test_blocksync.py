@@ -292,3 +292,37 @@ class TestSyncRepair:
         finally:
             if st is not None:
                 node_peers.shutdown(st)
+
+
+class TestPoolRoutineSpans:
+    def test_blocksync_wait_recorded_when_no_pair_is_ready(self):
+        """A pool routine with no peers has no block pair to apply: each
+        pass that made no progress is a ``blocksync/wait`` span on its
+        own thread — the node waiting for its peers — and no step."""
+        import threading
+        from types import SimpleNamespace
+
+        from cometbft_tpu.blocksync import reactor as R
+        from cometbft_tpu.utils.trace import TRACER
+
+        r = R.BlocksyncReactor(
+            SimpleNamespace(initial_height=1), None,
+            SimpleNamespace(height=lambda: 0), block_sync=True,
+        )
+        r._maybe_switch_to_consensus = lambda: False
+        TRACER.clear()
+        routine = threading.Thread(target=r._pool_routine)
+        routine.start()
+        time.sleep(8 * R.POOL_TICK)
+        r._quit.set()
+        routine.join(5)
+        assert not routine.is_alive()
+        events = TRACER.events()
+        waits = [e for e in events if e["name"] == "blocksync/wait"]
+        assert len(waits) >= 3
+        assert {e["tid"] for e in waits} == {routine.ident}
+        # all but the last, cut short by the quit, wait out a tick
+        assert all(e["dur"] >= 0.8 * R.POOL_TICK * 1e6 for e in waits[:-1])
+        # read by span_ms alone: the wait reads no thread clock
+        assert not [e for e in waits if "tdur" in e]
+        assert not [e for e in events if e["name"] == "blocksync/step"]

@@ -699,6 +699,49 @@ class TestExecuteLadderWalk:
         # watchdog had already demoted this launch's tier
         assert snap["next_cooldown_s"] == 60.0
 
+    def test_launch_hang_sleeps_inside_batch_verify_arm(
+        self, cm, dispatch_env, verifier_cls, monkeypatch
+    ):
+        """The chaos hook runs inside ``batch_verify/arm``, under the
+        armed watchdog: the hang is that span's (asleep: off the
+        launch's thread's CPU), it trips the watchdog and the ladder,
+        and the failed attempt has no ``batch_verify/settle``."""
+        from cometbft_tpu.crypto import health as _health
+        from cometbft_tpu.metrics import health_metrics as _hm
+        from cometbft_tpu.utils.trace import TRACER
+
+        dispatch_env(
+            CMT_TPU_CHAOS="1",
+            CMT_TPU_CHAOS_PLAN="launch_hang@0-3600",
+            CMT_TPU_COOLDOWN_S="30",
+        )
+        monkeypatch.setattr(_health.WATCHDOG, "_budget", 0.1)
+        hangs0 = counter_value(_hm().device_hangs_total)
+        bv = _fill(verifier_cls(device_min_batch=1), 2)
+        monkeypatch.setattr(bv, "_run_tier", _fake_ok(bv))
+        TRACER.clear()
+        ok, _ = bv.verify()
+        assert ok and bv._last_tier == "host"
+        deadline = time.time() + 5
+        while time.time() < deadline and (
+            counter_value(_hm().device_hangs_total) == hangs0
+        ):
+            time.sleep(0.01)
+        assert counter_value(_hm().device_hangs_total) == hangs0 + 1
+        assert counter_value(
+            cm.dispatch_demotions_total,
+            **{"from": "generic", "to": "host", "reason": "watchdog"},
+        ) == 1
+        events = TRACER.events()
+        (arm,) = [e for e in events if e["name"] == "batch_verify/arm"]
+        (root,) = [e for e in events if e["name"] == "batch_verify"]
+        assert arm["args"]["error"] == root["args"]["error"] == "ChaosFault"
+        assert arm["args"]["parent"] == "batch_verify"
+        assert arm["tid"] == root["tid"]
+        assert arm["dur"] >= 0.1 * 1.25 * 1e6 - 1e3  # the injected sleep
+        assert root["tdur"] < 0.5 * root["dur"]
+        assert not [e for e in events if e["name"] == "batch_verify/settle"]
+
     def test_shard_loss_faults_only_mesh_tiers(
         self, cm, dispatch_env, verifier_cls, monkeypatch
     ):

@@ -1,6 +1,6 @@
 """Device-health plane tests (docs/observability.md "Device-health
-plane"): launch watchdog, tier prober, utilization accounting, the
-/debug index + /debug/perf surfaces, and the perf ledger + regression
+plane"): launch watchdog, tier prober, the steps of a launch in the
+span ring, the /debug index + /debug/perf surfaces, and the perf ledger + regression
 gate (tools/perfledger.py, tools/perfdiff.py).
 
 ``make health-smoke`` runs the TestHealthSmoke class standalone;
@@ -173,75 +173,6 @@ class TestLaunchWatchdog:
             assert wd.disarm(token) is False
         finally:
             wd.stop()
-
-
-class TestDeviceUsage:
-    def test_busy_idle_and_overlap(self, hm):
-        usage = H.DeviceUsage()
-        t0 = time.perf_counter()
-        time.sleep(0.02)
-        usage.launch_end(t0, ndev=2, fetch_wait=0.005)
-        busy0 = counter_value(hm.device_busy_seconds_total, device="0")
-        busy1 = counter_value(hm.device_busy_seconds_total, device="1")
-        assert busy0 >= 0.015 and busy1 == busy0
-        # second launch after a measurable gap accounts idle time
-        time.sleep(0.02)
-        t1 = time.perf_counter()
-        time.sleep(0.01)
-        usage.launch_end(t1, ndev=2, fetch_wait=0.0)
-        assert counter_value(
-            hm.device_idle_seconds_total, device="0"
-        ) >= 0.015
-        snap = usage.snapshot()
-        assert snap["launches"] == 2
-        assert 0.0 < snap["occupancy"] < 1.0
-        assert snap["overlap_ratio"] == 1.0  # second launch: no fetch wait
-        # gauge holds the LAST launch's overlap
-        assert hm.host_device_overlap_ratio.labels().get() == 1.0
-
-    def test_overlap_ratio_bounds(self, hm):
-        usage = H.DeviceUsage()
-        t0 = time.perf_counter()
-        time.sleep(0.01)
-        # fetch wait exceeding busy clamps to 0, never negative
-        usage.launch_end(t0, fetch_wait=10.0)
-        assert usage.snapshot()["overlap_ratio"] == 0.0
-
-    def test_timed_fetch_is_per_thread(self, hm):
-        usage = H.DeviceUsage()
-        with usage.timed_fetch():
-            time.sleep(0.02)
-        assert usage.fetch_wait() >= 0.015
-        other: list[float] = []
-
-        def peer():
-            other.append(usage.fetch_wait())
-
-        t = threading.Thread(target=peer)
-        t.start()
-        t.join()
-        assert other == [0.0]
-
-    def test_concurrent_launches_count_the_union(self, hm):
-        """Overlapping launches (a prober canary riding over a
-        production batch) must contribute the UNION of their wall
-        intervals, never double-count — busy+idle <= wall."""
-        usage = H.DeviceUsage()
-        t0 = time.perf_counter()
-        time.sleep(0.03)
-        # two fully-overlapping launches ending together
-        usage.launch_end(t0)
-        usage.launch_end(t0)
-        busy = counter_value(hm.device_busy_seconds_total, device="0")
-        wall = time.perf_counter() - t0
-        assert busy <= wall + 0.001, (busy, wall)
-        assert usage.snapshot()["launches"] == 2
-
-    def test_queue_wait_histogram(self, hm):
-        usage = H.DeviceUsage()
-        usage.note_queue_wait(0.003)
-        assert hist_count(hm.launch_queue_wait_seconds) == 1
-        assert usage.snapshot()["last_queue_wait_s"] == 0.003
 
 
 class TestHealthProber:
@@ -419,8 +350,6 @@ class TestHealthSmoke:
         prober.start()
         try:
             prober.probe_once()
-            usage_t0 = time.perf_counter()
-            H.USAGE.launch_end(usage_t0, ndev=1, fetch_wait=0.0)
             srv = MetricsServer(Registry(), "127.0.0.1:0")
             srv.start()
             try:
@@ -435,7 +364,11 @@ class TestHealthSmoke:
                 assert perf["prober"]["tiers"]["host"]["healthy"] is True
                 assert perf["prober"]["tiers"]["host"]["last_probe_s"] >= 0
                 assert "budget_s" in perf["watchdog"]
-                assert perf["utilization"]["launches"] >= 1
+                # a launch's timing is the span ring's (/trace), not a
+                # second bookkeeping here
+                assert "utilization" not in perf
+                assert set(perf) == {"device", "prober", "watchdog",
+                                     "ledger"}
                 assert perf["ledger"]["tail"][-1]["config"] == "keyed"
                 assert perf["device"]["status"] in (
                     "uninitialized", "ready", "failed"
@@ -462,12 +395,13 @@ class TestHealthSmoke:
 
         assert "debug/perf" in _INSPECT_ROUTES
         payload = Environment().routes()["debug/perf"]()
-        assert "watchdog" in payload and "utilization" in payload
+        assert "watchdog" in payload and "utilization" not in payload
+        assert {"device", "prober", "ledger"} <= set(payload)
 
 
 class TestVerifierHealthHooks:
-    """The TpuBatchVerifier.verify seam feeds the health plane: queue
-    wait, busy/idle, overlap — and a hung launch trips the watchdog
+    """The TpuBatchVerifier.verify seam: each step of a launch is a
+    span of its own in the ring, and a hung launch trips the watchdog
     without deadlocking the verifier."""
 
     def _verifier(self, run_generic):
@@ -487,20 +421,55 @@ class TestVerifierHealthHooks:
         return bv
 
     def test_verify_records_queue_wait_and_busy(self, hm, monkeypatch):
+        """One ``verify()`` on the generic tier (its program swapped for
+        an all-true one, so nothing compiles): ``batch_verify`` holds
+        its five steps, in order, on its own thread — the arm, the
+        pack, the launch, the fetch and the settle.  The root and its
+        fetch, the two spans a metric reads the thread's CPU time off,
+        carry it beside their wall time; the other steps read no
+        thread clock."""
+        import jax.numpy as jnp
+
+        from cometbft_tpu.ops import ed25519_verify as ev
+        from cometbft_tpu.utils.trace import TRACER
+
         monkeypatch.setenv("CMT_TPU_DISABLE_PRECOMPUTE", "1")
-
-        def fake_run(pub, sig, msgs):
-            time.sleep(0.01)
-            return np.ones(len(msgs), dtype=bool)
-
-        bv = self._verifier(fake_run)
+        monkeypatch.setattr(
+            ev, "_compiled",
+            lambda batch, bucket: lambda buf: jnp.ones(
+                buf.shape[-1], dtype=bool
+            ),
+        )
+        bv = self._verifier(
+            lambda pub, sig, msgs: ev._finish(
+                ev.verify_arrays_async(pub, sig, msgs)
+            )
+        )
+        TRACER.clear()
         ok, bits = bv.verify()
         assert ok and bits == [True, True]
-        assert hist_count(hm.launch_queue_wait_seconds) == 1
-        assert counter_value(
-            hm.device_busy_seconds_total, device="0"
-        ) >= 0.005
-        assert 0.0 <= hm.host_device_overlap_ratio.labels().get() <= 1.0
+        events = TRACER.events()
+        (root,) = [e for e in events if e["name"] == "batch_verify"]
+        steps = sorted(
+            (e for e in events
+             if e["args"].get("parent") == "batch_verify"),
+            key=lambda e: e["ts"],
+        )
+        assert [e["name"] for e in steps] == [
+            "batch_verify/arm", "verify/pack", "device_launch",
+            "device_fetch", "batch_verify/settle",
+        ]
+        end = root["ts"] + root["dur"]
+        for prev, step in zip([None] + steps, steps):
+            assert step["tid"] == root["tid"]
+            assert root["ts"] <= step["ts"]
+            assert step["ts"] + step["dur"] <= end + 0.2
+            if prev is not None:  # one after another, never nested
+                assert prev["ts"] + prev["dur"] <= step["ts"] + 0.2
+            assert ("tdur" in step) == (step["name"] == "device_fetch")
+        (fetch,) = [e for e in steps if e["name"] == "device_fetch"]
+        assert fetch["tdur"] >= 0 and root["tdur"] >= 0
+        assert root["args"]["ok"] is True
 
     def test_hung_verify_trips_watchdog_within_budget(
         self, hm, monkeypatch

@@ -1,13 +1,17 @@
 """Span tracer tests (utils/trace): Chrome trace-event export,
 thread-local parenting, bounded retention, the no-op disabled path,
-and the /trace surface on the metrics HTTP server."""
+each span's thread CPU time (``tdur``), and the /trace surface on the
+metrics HTTP server."""
 
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 import urllib.request
+
+import pytest
 
 from cometbft_tpu.utils import trace as trace_mod
 from cometbft_tpu.utils.trace import SpanTracer
@@ -113,6 +117,134 @@ class TestSpanTracer:
         assert "parent" not in t.events()[-1]["args"]
 
 
+def _spin_until(deadline: float) -> int:
+    """Pure-Python work until ``deadline`` on the perf_counter clock."""
+    n = 0
+    while time.perf_counter() < deadline:
+        n += 1
+    return n
+
+
+def _plain(t, clock):
+    with t.span("plain", cat="test", thread_clock=clock):
+        _spin_until(time.perf_counter() + 0.002)
+
+
+def _nested(t, clock):
+    with t.span("outer", cat="test", thread_clock=clock):
+        with t.span("inner", cat="test", thread_clock=clock):
+            _spin_until(time.perf_counter() + 0.001)
+        time.sleep(0.002)
+
+
+def _raising(t, clock):
+    with pytest.raises(ValueError):
+        with t.span("boom", cat="test", thread_clock=clock):
+            raise ValueError("x")
+
+
+def _annotated(t, clock):
+    t.set_annotator(lambda name: _Recorder()(name))
+    with t.span("annotated", cat="test", thread_clock=clock):
+        pass
+
+
+def _thread_clock_step_us() -> float:
+    """The smallest step this host's thread clock takes, in µs: under
+    a microsecond where it is read from the scheduler's own count, a
+    whole tick (10 ms on some hosts) where it is sampled."""
+    c0 = c = time.thread_time()
+    while c == c0:
+        c = time.thread_time()
+    return (c - c0) * 1e6
+
+
+class TestThreadTime:
+    """``tdur``: the thread's CPU time over a span, beside its wall
+    time ``dur``; ``dur - tdur`` is the time the thread was not
+    running."""
+
+    @pytest.mark.parametrize("shape", [_plain, _nested, _raising,
+                                       _annotated])
+    @pytest.mark.parametrize("clock", [True, False])
+    def test_a_span_carries_tdur_where_it_reads_the_thread_clock(
+        self, shape, clock
+    ):
+        t = SpanTracer(capacity=16, enabled=True)
+        shape(t, clock)
+        events = t.events()
+        assert events
+        if not clock:
+            assert not [e for e in events if "tdur" in e]
+            return
+        step = _thread_clock_step_us()
+        for e in events:
+            # read inside the wall clock's interval at both ends: over
+            # by no more than the two roundings to a tenth of a µs and
+            # one step of the thread clock
+            assert 0 <= e["tdur"] <= e["dur"] + 1 + step, e
+
+    def test_a_sleeping_span_is_off_cpu(self):
+        t = SpanTracer(capacity=8, enabled=True)
+        with t.span("sleep", cat="test", thread_clock=True):
+            time.sleep(0.05)
+        (e,) = t.events()
+        assert e["dur"] >= 45_000
+        assert e["tdur"] < 0.1 * e["dur"], e
+
+    def test_a_span_racing_a_spinning_thread_waits_for_the_lock(self):
+        """Two pure-Python threads share the interpreter lock at the
+        default 5 ms switch interval: a span of 50 ms of wall time on
+        one of them spends a good share of it waiting, off its CPU."""
+        t = SpanTracer(capacity=8, enabled=True)
+        stop = threading.Event()
+
+        def spin():
+            while not stop.is_set():
+                pass
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(0.005)
+        rival = threading.Thread(target=spin, daemon=True)
+        rival.start()
+        try:
+            time.sleep(0.02)  # the rival holds the lock by now
+            with t.span("contended", cat="test", thread_clock=True):
+                _spin_until(time.perf_counter() + 0.05)
+        finally:
+            stop.set()
+            rival.join(5)
+            sys.setswitchinterval(interval)
+        assert not rival.is_alive()
+        (e,) = t.events()
+        assert e["dur"] - e["tdur"] >= 0.2 * e["dur"], e
+
+    def test_a_disabled_tracer_reads_no_clock(self, monkeypatch):
+        class NoClock:
+            def __getattr__(self, name):
+                raise AssertionError(f"time.{name} read")
+
+        t = SpanTracer(capacity=8, enabled=False)
+        monkeypatch.setattr(trace_mod, "time", NoClock())
+        with t.span("hot", thread_clock=True, batch=4) as sp:
+            sp.set(ok=True)
+        t.add_complete("x", 0.0, 0.1, thread_s=0.05)
+        monkeypatch.undo()
+        assert t.events() == []
+
+    @pytest.mark.parametrize("thread_s, tdur", [
+        (0.004, 4000.0), (0.00000123, 1.2), (None, None),
+    ])
+    def test_add_complete_writes_tdur_only_when_given(self, thread_s,
+                                                      tdur):
+        t = SpanTracer(capacity=8, enabled=True)
+        t.add_complete("step", time.perf_counter(), 0.01, cat="test",
+                       thread_s=thread_s)
+        (e,) = t.events()
+        assert e.get("tdur") == tdur
+        assert e["dur"] == 10_000.0
+
+
 class TestTraceEndpoint:
     def test_metrics_server_serves_trace_next_to_metrics(self):
         from cometbft_tpu.utils.metrics import MetricsServer, Registry
@@ -193,9 +325,20 @@ class TestHeightPipeline:
         trace_mod.TRACER.clear()
         node = Node(cfg, app=KVStoreApp(), genesis=gen, priv_validator=pv)
         node.start()
+
+        def indexed_2() -> bool:
+            # the indexer runs on its own thread, behind the commit
+            return any(
+                e["name"] == "indexer/index_block"
+                and e["args"].get("height") == 2
+                for e in trace_mod.TRACER.events()
+            )
+
         try:
             deadline = time.time() + 30
-            while time.time() < deadline and node.height() < 3:
+            while time.time() < deadline and (
+                node.height() < 3 or not indexed_2()
+            ):
                 time.sleep(0.05)
             assert node.height() >= 3
         finally:
