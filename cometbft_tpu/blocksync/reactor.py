@@ -129,8 +129,8 @@ def commit_prefetch_items(chain_id: str, vals, commit) -> list | None:
         return None
     with TRACER.span(
         "blocksync/prefetch_items", cat="blocksync", height=commit.height,
-    ):
-        return commit_check_triples(chain_id, vals, commit)
+    ) as span:
+        return commit_check_triples(chain_id, vals, commit, span=span)
 
 
 class ApplyError(Exception):

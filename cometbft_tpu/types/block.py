@@ -215,22 +215,30 @@ class Commit:
     def size(self) -> int:
         return len(self.signatures)
 
-    # the sign-bytes this commit has encoded: ``(chain_id, slots)``,
-    # one slot a signature.  Not a field (no annotation): set on the
-    # instance with object.__setattr__, so replace(), ==, hash, repr,
-    # the codec and a public vars() dump never see it.  The commit is
-    # frozen, so a slot cannot go stale; two threads filling one slot
-    # write equal bytes, so there is no lock.
-    _sign_bytes = (None, ())
+    # the sign-bytes this commit has encoded: ``(chain_id, slots,
+    # template)``, one slot a signature, and the VoteTemplate that
+    # fills them.  Not a field (no annotation): set on the instance
+    # with object.__setattr__, so replace(), ==, hash, repr, the codec
+    # and a public vars() dump never see it.  The commit is frozen, so
+    # a slot cannot go stale; two threads filling one slot write equal
+    # bytes, so there is no lock.
+    _sign_bytes = (None, (), None)
 
-    def _sign_bytes_slots(self, chain_id: str) -> list:
-        """The slots kept for ``chain_id``: one chain id's at a time,
-        replaced when the id differs."""
-        memo_id, slots = self._sign_bytes
-        if memo_id != chain_id:
-            slots = [None] * len(self.signatures)
-            object.__setattr__(self, "_sign_bytes", (chain_id, slots))
-        return slots
+    def _sign_bytes_memo(self, chain_id: str) -> tuple:
+        """The slots and template kept for ``chain_id``: one chain id's
+        at a time, replaced (both in one write) when the id differs."""
+        memo = self._sign_bytes
+        if memo[0] != chain_id:
+            memo = (
+                chain_id,
+                [None] * len(self.signatures),
+                canonical.VoteTemplate(
+                    chain_id, canonical.PRECOMMIT_TYPE, self.height,
+                    self.round, self.block_id,
+                ),
+            )
+            object.__setattr__(self, "_sign_bytes", memo)
+        return memo
 
     def vote_sign_bytes(self, chain_id: str, idx: int) -> bytes:
         """Reconstruct the canonical sign-bytes of validator idx's
@@ -238,28 +246,36 @@ class Commit:
         message consumed by batch verification).  Encoded once a
         commit and chain id: a prefetch's encoding is what the check
         of the same object reads."""
-        memo_id, slots = self._sign_bytes
-        if memo_id != chain_id:  # else no call: a read costs a lookup
-            slots = self._sign_bytes_slots(chain_id)
+        memo = self._sign_bytes
+        if memo[0] != chain_id:  # else no call: a read costs a lookup
+            memo = self._sign_bytes_memo(chain_id)
+        _, slots, template = memo
         sb = slots[idx]
         if sb is None:
             cs = self.signatures[idx]
-            sb = slots[idx] = canonical.vote_sign_bytes(
-                chain_id,
-                canonical.PRECOMMIT_TYPE,
-                self.height,
-                self.round,
-                cs.block_id(self.block_id),
-                cs.timestamp_ns,
+            sb = slots[idx] = template.sign_bytes(
+                cs.block_id_flag == BLOCK_ID_FLAG_COMMIT, cs.timestamp_ns,
             )
         return sb
 
-    def sign_bytes_missing(self, chain_id: str, idxs) -> int:
-        """How many of the votes ``idxs`` this commit has not encoded
-        under ``chain_id`` yet: what a following ``vote_sign_bytes``
-        pass over them will have to encode."""
-        slots = self._sign_bytes_slots(chain_id)
-        return sum(1 for i in idxs if slots[i] is None)
+    def vote_sign_bytes_many(
+        self, chain_id: str, idxs
+    ) -> tuple[list[bytes], int, int]:
+        """:meth:`vote_sign_bytes` of each of ``idxs``, in order, with
+        how many of them this call encoded (the empty slots; 0 after a
+        prefetch of this object) and how many of those the template's
+        fast path did not cover."""
+        _, slots, template = self._sign_bytes_memo(chain_id)
+        missing = [i for i in idxs if slots[i] is None]
+        sigs = self.signatures
+        sbs, generic = template.sign_bytes_many(
+            (sigs[i].block_id_flag == BLOCK_ID_FLAG_COMMIT,
+             sigs[i].timestamp_ns)
+            for i in missing
+        )
+        for i, sb in zip(missing, sbs):
+            slots[i] = sb
+        return [slots[i] for i in idxs], len(missing), generic
 
     def aggregate_sign_bytes(self, chain_id: str) -> bytes:
         """The ONE canonical message every aggregate-covered precommit

@@ -222,6 +222,7 @@ def commit_check_triples(
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int | None = None,
+    span=None,
 ) -> list | None:
     """The ``(pub_key, sign_bytes, signature)`` triples a by-index check
     of ``commit`` against ``vals`` will look at, in commit order: its
@@ -234,10 +235,12 @@ def commit_check_triples(
     with ``vals`` (the set rotated, a malformed commit: never guess —
     the check itself reports the precise error).  Votes covered by a
     commit-level BLS aggregate carry no per-signature proof and are
-    skipped."""
+    skipped.  ``span``, where given, records ``encoded`` and
+    ``generic`` (``Commit.vote_sign_bytes_many``'s counts)."""
     if commit is None or commit.size() != len(vals):
         return None
-    items = []
+    idxs = []
+    pub_keys = []
     power = 0
     for i, cs in enumerate(commit.signatures):
         if not cs.is_commit() or commit.is_aggregated(i):
@@ -245,13 +248,19 @@ def commit_check_triples(
         val = vals.get_by_index(i)
         if val is None or val.address != cs.validator_address:
             return None
-        items.append((
-            val.pub_key, commit.vote_sign_bytes(chain_id, i), cs.signature,
-        ))
+        idxs.append(i)
+        pub_keys.append(val.pub_key)
         power += val.voting_power
         if voting_power_needed is not None and power > voting_power_needed:
             break
-    return items
+    sbs, encoded, generic = commit.vote_sign_bytes_many(chain_id, idxs)
+    if span is not None:
+        span.set(encoded=encoded, generic=generic)
+    sigs = commit.signatures
+    return [
+        (pk, sb, sigs[i].signature)
+        for pk, sb, i in zip(pub_keys, sbs, idxs)
+    ]
 
 
 def _crypto_pass(
@@ -330,11 +339,13 @@ def _crypto_pass(
         with _tracer.span(
             "verify_commit/sign_bytes", cat="crypto", sigs=len(group),
         ) as sb_span:
-            # encoded: how many of the group's sign-bytes this span has
-            # to encode (0 where a prefetch of this commit object did)
-            idxs = [e.idx for e in group]
-            sb_span.set(encoded=commit.sign_bytes_missing(chain_id, idxs))
-            sbs = [commit.vote_sign_bytes(chain_id, i) for i in idxs]
+            # encoded: how many of the group's sign-bytes this span had
+            # to encode (0 where a prefetch of this commit object did);
+            # generic: how many of those the template did not cover
+            sbs, encoded, generic = commit.vote_sign_bytes_many(
+                chain_id, [e.idx for e in group]
+            )
+            sb_span.set(encoded=encoded, generic=generic)
         pending = list(range(len(group)))
         keys: list[bytes] | None = None
         if _vq.speculation_active():

@@ -7,6 +7,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import Counter
+from dataclasses import replace
 
 import pytest
 
@@ -15,10 +16,16 @@ from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import dispatch
 from cometbft_tpu.crypto import ed25519 as ed
 from cometbft_tpu.crypto import verify_queue as vq
-from cometbft_tpu.types import validation
+from cometbft_tpu.types import PRECOMMIT_TYPE, VoteSet, validation
 from cometbft_tpu.utils.trace import TRACER
 
-from tests.helpers import CHAIN_ID, make_block_id, make_commit, make_val_set
+from tests.helpers import (
+    CHAIN_ID,
+    make_block_id,
+    make_commit,
+    make_val_set,
+    signed_vote,
+)
 
 #: what one device batch records, whoever asked for it
 LAUNCH = ["batch_verify", "verify/pack", "device_launch", "device_fetch"]
@@ -136,6 +143,38 @@ def test_the_sign_bytes_span_says_what_it_encoded(
     # a second check of the same object encodes nothing
     args = sign_bytes_args(validation.verify_commit_light)
     assert args["sigs"] == 5 and args["encoded"] == 0
+
+
+@pytest.mark.parametrize("negative", [0, 1], ids=["fresh", "negative_time"])
+def test_the_sign_bytes_spans_count_the_fallback(device_route, negative):
+    """``generic`` beside ``encoded``: how many of the encoded votes the
+    commit's template left to ``canonical.vote_sign_bytes`` — none on a
+    fresh commit, one where a vote was signed at a negative time, whose
+    verdict is the same (accepted) — on the check's span and on the
+    prefetch's."""
+    vals, keys = make_val_set(6)
+    bid = make_block_id(b"stage-generic")
+    times = [1_700_000_000_000_000_000 + i for i in range(6)]
+    if negative:
+        times[2] = -1_500_000_001
+    vs = VoteSet(CHAIN_ID, 1, 0, PRECOMMIT_TYPE, vals)
+    for i, key in enumerate(keys):
+        vs.add_vote(signed_vote(key, i, bid, time_ns=times[i]))
+    commit = vs.make_commit()
+    _, events = _spans_of(
+        lambda: validation.verify_commit(CHAIN_ID, vals, bid, 1, commit)
+    )
+    args = next(
+        e for e in events if e["name"] == "verify_commit/sign_bytes"
+    )["args"]
+    assert (args["sigs"], args["encoded"], args["generic"]) == (6, 6, negative)
+    _, events = _spans_of(
+        lambda: commit_prefetch_items(CHAIN_ID, vals, replace(commit))
+    )
+    args = next(
+        e for e in events if e["name"] == "blocksync/prefetch_items"
+    )["args"]
+    assert (args["encoded"], args["generic"]) == (6, negative)
 
 
 def test_queue_on_the_commit_consults_and_records(device_route):

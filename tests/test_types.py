@@ -1,5 +1,8 @@
 """Tests for the domain types layer."""
 
+import random
+import sys
+import threading
 from dataclasses import replace
 from fractions import Fraction
 
@@ -499,18 +502,149 @@ def _timed_commit(n: int = 6):
 @pytest.fixture
 def timed(monkeypatch):
     """``(vals, block_id, commit, encoded)``: a :func:`_timed_commit`
-    and the vote indices ``canonical.vote_sign_bytes`` is asked to
-    encode from here on, in order."""
+    and the vote indices a commit's encoder is asked to encode from
+    here on, in order — ``canonical.VoteTemplate._encode``, called once
+    for every vote ``Commit.vote_sign_bytes`` and
+    ``vote_sign_bytes_many`` encode, fast path and fallback alike."""
     vals, bid, commit = _timed_commit()
     encoded: list[int] = []
-    real = canonical.vote_sign_bytes
+    real = canonical.VoteTemplate._encode
 
-    def counting(chain_id, vote_type, height, round_, block_id, time_ns):
+    def counting(template, for_block, time_ns):
         encoded.append(time_ns - _T0)
-        return real(chain_id, vote_type, height, round_, block_id, time_ns)
+        return real(template, for_block, time_ns)
 
-    monkeypatch.setattr(canonical, "vote_sign_bytes", counting)
+    monkeypatch.setattr(canonical.VoteTemplate, "_encode", counting)
     return vals, bid, commit, encoded
+
+
+def _template_commit(
+    height: int = 3, round_: int = 1, part_total: int = 2,
+    timestamps=(_T0 + 1, _T0 + 2, _T0 + 3),
+) -> Commit:
+    """Votes at ``timestamps`` whose flags cycle COMMIT, NIL, ABSENT."""
+    bid = make_block_id(b"template")
+    bid = BlockID(bid.hash, replace(bid.part_set_header, total=part_total))
+    flags = (BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL, BLOCK_ID_FLAG_ABSENT)
+    return Commit(
+        height=height,
+        round=round_,
+        block_id=bid,
+        signatures=tuple(
+            CommitSig(flags[i % 3], bytes([i % 256]) * 20, ts, b"s" * 64)
+            for i, ts in enumerate(timestamps)
+        ),
+    )
+
+
+def _random_timestamps(n: int) -> tuple[int, ...]:
+    rng = random.Random(39)
+    return tuple(_T0 + rng.randrange(3_000_000_000) for _ in range(n))
+
+
+class TestVoteTemplate:
+    """A commit's sign-bytes from one prefix and one suffix
+    (``canonical.VoteTemplate``) are ``canonical.vote_sign_bytes``'s,
+    byte for byte, and a time outside the fast path takes the latter."""
+
+    @pytest.mark.parametrize("commit_kw, chain_id", [
+        ({"height": 0}, CHAIN_ID),
+        ({"height": 2**63 - 1}, CHAIN_ID),
+        ({"round_": 0}, CHAIN_ID),
+        ({"round_": 7}, CHAIN_ID),
+        ({"timestamps": (0, 0, 0)}, CHAIN_ID),
+        ({"timestamps": (5 * 10**9, 10**9, 2**62 // 10**9 * 10**9)}, CHAIN_ID),
+        ({"timestamps": (1, 127, 999_999_999)}, CHAIN_ID),
+        ({"timestamps": (-1, -(10**9), -(10**9) - 7)}, CHAIN_ID),
+        ({"timestamps": (2**63 - 1, _T0, -5)}, CHAIN_ID),
+        ({}, ""),
+        ({}, "c"),
+        ({}, "x" * 127),
+        ({}, "\u00e9" * 100),
+        ({"part_total": 128}, CHAIN_ID),
+        ({"part_total": 2**31}, CHAIN_ID),
+        ({"height": 2**63 - 1, "round_": 2**31}, "chain-" + "y" * 60),
+        # a body of 131 bytes at a full timestamp, 126 at whole seconds:
+        # one commit's votes straddle the one-byte length prefix
+        ({"timestamps": (_T0 + 123_456_789, _T0, _T0, _T0 + 10**9)},
+         "b" * 22),
+        ({"timestamps": _random_timestamps(10_000)}, CHAIN_ID),
+    ], ids=[
+        "height_0", "height_max", "round_0", "round_7", "time_0",
+        "whole_seconds", "nanos_only", "negative", "int64_edge_and_negative",
+        "chain_id_0_bytes", "chain_id_1_byte", "chain_id_127_bytes",
+        "chain_id_200_utf8_bytes", "part_total_128", "part_total_2_31",
+        "two_byte_length_prefix", "length_prefix_boundary",
+        "random_10000_votes",
+    ])
+    def test_bytes_are_vote_sign_bytes(self, commit_kw, chain_id):
+        commit = _template_commit(**commit_kw)
+        n = commit.size()
+        want = [_fresh_sign_bytes(commit, chain_id, i) for i in range(n)]
+        sbs, encoded, generic = commit.vote_sign_bytes_many(
+            chain_id, range(n)
+        )
+        assert sbs == want
+        assert encoded == n
+        # the fallback takes a negative time, and only that
+        assert generic == sum(
+            1 for cs in commit.signatures if cs.timestamp_ns < 0
+        )
+        # one at a time, on a copy that kept nothing, the same bytes
+        copy = replace(commit)
+        assert [copy.vote_sign_bytes(chain_id, i) for i in range(n)] == want
+
+    def test_threads_sharing_a_commit_read_the_same_bytes(self):
+        """Threads encoding one commit's votes at once (the groups of a
+        mixed commit run concurrently) fill its slots and its template's
+        seconds and heads without a lock: every read is the canonical
+        bytes."""
+        commit = _template_commit(timestamps=_random_timestamps(3_000))
+        want = [_fresh_sign_bytes(commit, CHAIN_ID, i) for i in range(3_000)]
+        reads = {
+            k: (list(range(k % 3, 3_000, 1 + k % 2)), range(k, 3_000, 7))
+            for k in range(16)
+        }
+        got: dict = {}
+
+        def read(k):
+            many, singles = reads[k]
+            got[k] = commit.vote_sign_bytes_many(CHAIN_ID, many)[0] + [
+                commit.vote_sign_bytes(CHAIN_ID, i) for i in singles
+            ]
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [
+                threading.Thread(target=read, args=(k,)) for k in reads
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        for k, (many, singles) in reads.items():
+            assert got[k] == [want[i] for i in [*many, *singles]], k
+        kept = [commit.vote_sign_bytes(CHAIN_ID, i) for i in range(3_000)]
+        assert kept == want
+
+    def test_the_fallback_is_vote_sign_bytes(self, monkeypatch):
+        """Only a negative time calls the one definition; the others
+        are the template's own."""
+        commit = _template_commit(timestamps=(_T0, -3, 0, _T0 + 10**9))
+        calls: list[int] = []
+        real = canonical.vote_sign_bytes
+
+        def spy(*args):
+            calls.append(args[-1])
+            return real(*args)
+
+        monkeypatch.setattr(canonical, "vote_sign_bytes", spy)
+        _, encoded, generic = commit.vote_sign_bytes_many(CHAIN_ID, range(4))
+        assert (encoded, generic, calls) == (4, 1, [-3])
 
 
 class TestCommitSignBytesMemo:
@@ -535,7 +669,7 @@ class TestCommitSignBytesMemo:
         again = commit.vote_sign_bytes(chain_id, idx)
         assert again == first and again is not first
         # the neighbours were not encoded along the way
-        assert commit.sign_bytes_missing(chain_id, range(4)) == 3
+        assert commit.vote_sign_bytes_many(chain_id, range(4))[1] == 3
 
     @pytest.mark.parametrize("change", [
         lambda c: {"signatures": tuple(
@@ -550,8 +684,8 @@ class TestCommitSignBytesMemo:
         old = [commit.vote_sign_bytes(CHAIN_ID, i) for i in range(4)]
         copy = replace(commit, **change(commit))
         assert "_sign_bytes" not in vars(copy)
-        assert copy.sign_bytes_missing(CHAIN_ID, range(4)) == 4
-        new = [copy.vote_sign_bytes(CHAIN_ID, i) for i in range(4)]
+        new, encoded, _ = copy.vote_sign_bytes_many(CHAIN_ID, range(4))
+        assert encoded == 4
         assert new == [_fresh_sign_bytes(copy, CHAIN_ID, i) for i in range(4)]
         assert new[0] != old[0] and new[3] != old[3]
         # and the original still answers with what it kept
@@ -604,6 +738,16 @@ class TestCommitSignBytesMemo:
         assert sorted(encoded) == [0, 1, 2]
         validation.verify_commit_light(CHAIN_ID, vals, bid, 1, commit)
         assert sorted(encoded) == [0, 1, 2, 3, 4]
+
+    def test_many_over_kept_indices_encodes_nothing(self, timed):
+        vals, bid, commit, encoded = timed
+        first, n, generic = commit.vote_sign_bytes_many(CHAIN_ID, [1, 3, 4])
+        assert (n, generic, encoded) == (3, 0, [1, 3, 4])
+        again, n, generic = commit.vote_sign_bytes_many(CHAIN_ID, [4, 1, 3])
+        assert (n, generic, encoded) == (0, 0, [1, 3, 4])
+        assert [id(b) for b in again] == [id(first[2]), id(first[0]),
+                                          id(first[1])]
+        assert commit.vote_sign_bytes(CHAIN_ID, 3) is first[1]
 
     @pytest.mark.parametrize("tamper", [
         lambda cs: replace(

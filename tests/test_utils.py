@@ -1,6 +1,7 @@
 """Tests for foundation utilities."""
 
 import io
+import random
 import threading
 
 import pytest
@@ -86,6 +87,28 @@ class TestProtoIO:
             enc = encode_uvarint(n)
             dec, off = decode_uvarint(enc)
             assert dec == n and off == len(enc)
+
+    @pytest.mark.parametrize("nbytes", range(1, 11))
+    def test_uvarint_is_seven_bits_a_byte(self, nbytes):
+        """Each width's edges, and random values between them, against
+        the plain loop: the unrolled two- to five-byte forms too."""
+
+        def plain(n):
+            out = bytearray()
+            while n > 0x7F:
+                out.append(n & 0x7F | 0x80)
+                n >>= 7
+            out.append(n)
+            return bytes(out)
+
+        lo = 0 if nbytes == 1 else 1 << 7 * (nbytes - 1)
+        hi = min(1 << 7 * nbytes, 1 << 64)
+        rng = random.Random(nbytes)
+        for n in [lo, lo + 1, hi - 2, hi - 1,
+                  *(rng.randrange(lo, hi) for _ in range(200))]:
+            enc = encode_uvarint(n)
+            assert enc == plain(n) and len(enc) == nbytes, n
+            assert decode_uvarint(enc) == (n, nbytes)
 
     def test_writer_reader_roundtrip(self):
         w = ProtoWriter()
